@@ -78,9 +78,10 @@ class DeviceConfig:
     # correctness).
     hbm_budget_mb: int = 4096
     # persistent XLA compilation cache directory: per-bucket re-traces hit
-    # disk across processes and runs. None = the platform-gated default
-    # (device/__init__.py); RW_COMPILE_CACHE_DIR overrides either ("" in
-    # the env disables). No-op on jax builds without the cache config.
+    # disk across processes and runs. None = the default placement
+    # (device/__init__.py: <checkout>/.jax_cache unless the process is
+    # pinned to the CPU). JAX_COMPILATION_CACHE_DIR in the environment
+    # wins over either: jax reads it itself and this knob is ignored.
     compile_cache_dir: Optional[str] = None
     # epoch-timeline profiler (utils/profile.py): per-epoch phase-split
     # spans (host-pack / dispatch / device-sync / commit), compile-event
@@ -91,9 +92,9 @@ class DeviceConfig:
     # fused epoch programs move off the barrier hot loop onto a
     # background worker pool — at CREATE time the plan's shapes (and,
     # once rates are observed, its predicted growth buckets) compile
-    # ahead while the interpreted path serves the first epochs, and the
-    # compiled executable swaps in at the next barrier. Off restores
-    # inline compiles on first dispatch (the pre-ISSUE-6 behavior).
+    # in parallel; a step whose executable is still pending waits for
+    # it. Off restores inline compiles on first dispatch (the
+    # pre-ISSUE-6 behavior).
     aot_compile: bool = True
     # max background pre-warm rounds per job for predicted growth-bucket
     # shapes (the capacity ladder ahead of observed need). 0 disables

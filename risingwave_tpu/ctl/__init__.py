@@ -615,7 +615,7 @@ def cmd_compile_status(args) -> int:
             print("no compile manifest (directory has no "
                   "compile_manifest.json mirror — the data dir predates "
                   "manifest mirroring, or never ran with aot_compile on; "
-                  "RW_COMPILE_CACHE_DIR names the cache-dir fallback)")
+                  "JAX_COMPILATION_CACHE_DIR names the cache-dir fallback)")
             return 1
         print(json.dumps(offline_report(m), indent=2, sort_keys=True))
         return 0

@@ -17,13 +17,12 @@ Three pillars:
   retrace.
 
 * **Background AOT** — `jax.jit(step).lower(avals).compile()` runs on a
-  small daemon worker pool. While an executable is pending, the epoch
-  step runs the INTERPRETED path (`jax.disable_jit()` — eager op-by-op,
-  exact, no compile), so a job comes online at the first barrier and
-  swaps in the compiled executable at the next barrier after the
-  background compile finishes. Input avals for shapes that have never
-  been dispatched (CREATE-time pre-warm, predicted growth buckets) come
-  from an abstract `jax.eval_shape` walk over a cloned node graph.
+  small daemon worker pool, a program's nodes in parallel. A step whose
+  executable is still pending WAITS for it (`_await`): there is one way
+  to run a node step, the compiled one, on every backend. Input avals
+  for shapes that have never been dispatched (CREATE-time pre-warm,
+  predicted growth buckets) come from an abstract `jax.eval_shape` walk
+  over a cloned node graph.
 
 * **Plan-shape-hash pre-warm** — a compile manifest next to the
   persistent XLA cache records which key digests (and which plan-shape
@@ -41,8 +40,10 @@ signature. `DeviceConfig.aot_compile=False` restores inline compiles.
 from __future__ import annotations
 
 import copy
+import ctypes
 import hashlib
 import json
+import logging
 import os
 import threading
 import time
@@ -53,7 +54,30 @@ __all__ = ["CompileService", "get_service", "shutdown", "read_manifest",
            "offline_report"]
 
 _WORKERS = max(1, min(4, (os.cpu_count() or 2) - 1))
+# longest a dispatcher waits on ONE pending compile before it raises
+# with the node label (the slowest TPU node step compiles in ~5 minutes;
+# a full queue ahead of it is a few dozen of those over the workers)
+AWAIT_LIMIT_S = 3600.0
 MANIFEST_FILE = "compile_manifest.json"
+_log = logging.getLogger(__name__)
+
+# A TPU compile of one node step allocates 4-5 GB of host scratch, and
+# glibc keeps what the compiler frees in its per-thread arenas: offline
+# compiles of q5's 15 programs, 4 at a time, left the process at 13.1 GB
+# of which `malloc_trim` gave 8.2 GB back to the OS while every executable
+# was still held (PR 22, CHANGES.md). Untrimmed, q5 + q7 + q8 outgrew the
+# one-chip host's 40 GiB. The trim walks every arena (seconds at 10 GB),
+# so only a compile that itself ran for seconds is followed by one.
+TRIM_AFTER_S = 5.0
+try:
+    _MALLOC_TRIM = ctypes.CDLL(None).malloc_trim
+except (OSError, AttributeError):        # not glibc: nothing to trim
+    _MALLOC_TRIM = None
+
+
+def _trim_heap(compile_s: float) -> None:
+    if _MALLOC_TRIM is not None and compile_s >= TRIM_AFTER_S:
+        _MALLOC_TRIM(0)
 
 
 def _data_shards(mesh) -> int:
@@ -264,15 +288,17 @@ class CompileService:
         self._stop = False
         self._inflight = 0
         # test/diagnostic hook: when set, workers block here before
-        # compiling (lets tests pin the interpreted-bridge window open)
+        # compiling (lets tests pin the pending window open)
         self.hold: Optional[threading.Event] = None
         # counters (bench warmup decomposition / compile-status)
         self.compiles_done = 0
         self.compiles_failed = 0
         self.cache_hits = 0
-        self.eager_steps = 0
-        self.inline_steps = 0
         self.compiled_steps = 0
+        # steps served by the inline-jit fallback of a FAILED entry
+        self.inline_steps = 0
+        # seconds the dispatcher spent waiting on pending compiles
+        self.await_s = 0.0
         self._manifest: Dict[str, Any] = {}
         self._manifest_loaded = False
         self._manifest_dirty = False
@@ -299,7 +325,7 @@ class CompileService:
                     self._cv.wait(1.0)
                 if self._stop:
                     return
-                task = self._queue.popleft()
+                _ent, task = self._queue.popleft()
                 self._inflight += 1
             try:
                 task()
@@ -313,7 +339,7 @@ class CompileService:
     def _submit(self, task) -> None:
         with self._cv:
             self._ensure_workers()
-            self._queue.append(task)
+            self._queue.append((None, task))
             self._cv.notify_all()
 
     def wait_idle(self, timeout: Optional[float] = None) -> bool:
@@ -338,6 +364,15 @@ class CompileService:
             self.wait_idle(timeout)
         with self._cv:
             self._stop = True
+            # an entry whose queued compile is dropped here would stay
+            # pending for good: forget it (the next request for its
+            # signature queues a fresh compile) and release any
+            # dispatcher waiting on it to the inline-jit fallback
+            for ent, _task in self._queue:
+                if ent is not None and ent.status == "pending":
+                    ent.status = "failed"
+                    ent.error = "dropped: service shut down"
+                    self._entries.pop(ent.key, None)
             self._queue.clear()
             workers, self._workers = self._workers, []
             self._cv.notify_all()
@@ -367,11 +402,8 @@ class CompileService:
                                epoch_events, meshfp, avals[1]))
 
     def _manifest_path(self) -> Optional[str]:
-        try:
-            import jax
-            d = jax.config.jax_compilation_cache_dir
-        except AttributeError:
-            return None
+        from . import compile_cache_dir
+        d = compile_cache_dir()
         return os.path.join(d, MANIFEST_FILE) if d else None
 
     def _load_manifest(self) -> None:
@@ -444,18 +476,17 @@ class CompileService:
         """The fused epoch step, compile-service-managed:
 
         ready  -> call the AOT executable (zero trace, zero compile)
-        pending-> serve this epoch on the interpreted path (disable_jit)
-                  while the background compile proceeds; the swap happens
-                  at the next barrier that finds the entry ready
-        failed -> permanent inline-jit fallback for this signature
+        pending-> WAIT for the background compile (`_await`), then as
+                  ready. The workers compile a program's nodes in
+                  parallel; the dispatcher takes them as they land
+        failed -> permanent inline-jit fallback for this signature,
+                  counted in `inline_steps` (the failure itself was
+                  logged and counted when it happened)
 
         `mesh` selects the shard_map'd step (device/shard_exec.py): the
         executable is lowered through `sharded_jit_step`, keyed apart by
-        the mesh fingerprint. Sharded signatures never take the
-        interpreted bridge — pending means the inline-jit step (one
-        blocking compile through the same trace the AOT worker lowers).
+        the mesh fingerprint, and served the same way.
         """
-        import jax
         key = self._key(node, epoch_events, state, ins, extra, mesh)
         with self._lock:
             ent = self._entries.get(key)
@@ -467,6 +498,8 @@ class CompileService:
                     kind=kind or "compile", mesh=mesh)
             elif job is not None and job not in ent.jobs:
                 ent.jobs[job] = False    # shared/cached for this job
+        if ent.status == "pending":
+            self._await(ent)
         if ent.status == "ready":
             try:
                 out = ent.compiled(state, ins, extra)
@@ -485,32 +518,33 @@ class CompileService:
                 # aval/placement drift: permanent fallback
                 ent.status = "failed"
                 ent.error = f"dispatch: {type(e).__name__}: {e}"
-        if ent.status == "failed":
-            if mesh is not None:
-                from .shard_exec import sharded_node_step
-                return sharded_node_step(mesh, node, epoch_events, state,
-                                         ins, extra)
-            from .fused import _node_step
-            return _node_step(node, epoch_events, state, ins, extra)
+        with self._lock:
+            self.inline_steps += 1
         if mesh is not None:
-            # No eager bridge for sharded signatures: op-by-op eager
-            # dispatch re-enters the shard_map machinery per PRIMITIVE
-            # (tens of seconds per epoch on an 8-way mesh — worse than
-            # any compile it would hide), so the non-blocking-warmup
-            # trade the bridge makes for single-chip programs is a loss
-            # here. Take the inline-jit step instead: it blocks ONCE on
-            # a compile of the same `sharded_jit_step` trace the AOT
-            # worker lowers through, and every later epoch of this
-            # signature hits that jit cache even before the swap.
-            with self._lock:
-                self.inline_steps += 1
             from .shard_exec import sharded_node_step
             return sharded_node_step(mesh, node, epoch_events, state,
                                      ins, extra)
-        with self._lock:
-            self.eager_steps += 1
-        with jax.disable_jit():
-            return node.apply(state, list(ins), extra, epoch_events)
+        from .fused import _node_step
+        return _node_step(node, epoch_events, state, ins, extra)
+
+    def _await(self, ent: CompileEntry) -> None:
+        """Block the dispatcher until `ent`'s background compile lands.
+        Running the step some other way meanwhile is a loss: measured on
+        a v5e (PR 22, CHANGES.md), an op-by-op first epoch of the 4-node
+        bid group-by took 280 s against a 130 s critical-path compile —
+        every eager primitive is its own compile, racing the AOT workers
+        for the same cores. Raises after `AWAIT_LIMIT_S` rather than
+        wait for good on a compile that never lands."""
+        t0 = time.perf_counter()
+        with self._cv:
+            while ent.status == "pending":
+                left = AWAIT_LIMIT_S - (time.perf_counter() - t0)
+                if left <= 0:
+                    raise TimeoutError(
+                        f"AOT compile of {ent.label} still pending after "
+                        f"{AWAIT_LIMIT_S:.0f}s")
+                self._cv.wait(min(0.5, left))
+            self.await_s += time.perf_counter() - t0
 
     def _request_locked(self, key, node, epoch_events, sds, *, label, job,
                         profiler, kind, mesh=None) -> CompileEntry:
@@ -522,7 +556,7 @@ class CompileService:
         if job is not None:
             ent.jobs[job] = True         # this job pays for the compile
         self._entries[key] = ent
-        self._queue.append(self._compile_task(ent))
+        self._queue.append((ent, self._compile_task(ent)))
         self._ensure_workers()
         self._cv.notify_all()
         return ent
@@ -552,9 +586,16 @@ class CompileService:
                 ent.status = "failed"
                 with self._lock:
                     self.compiles_failed += 1
+                # once per signature (a failed entry never re-queues):
+                # the inline-jit fallback it now takes must not be quiet
+                _log.warning("AOT compile of %s failed after %.1fs, "
+                             "falling back to inline jit: %s",
+                             ent.label, ent.seconds, ent.error)
+                _trim_heap(ent.seconds)
                 return
             ent.seconds = time.perf_counter() - t0
             ent.status = "ready"
+            del lowered
             with self._lock:
                 # counters are asserted on exactly (zero-compile warm
                 # starts); worker threads race, so never bare +=
@@ -575,6 +616,7 @@ class CompileService:
                 ent.profiler.compile_event(
                     ent.label, ent.seconds, kind=ent.kind, aot=True,
                     bucket=repr(ent.bucket), cache_hit=ent.cache_hit)
+            _trim_heap(ent.seconds)
         return task
 
     # ---- pre-warm -------------------------------------------------------
@@ -647,9 +689,9 @@ class CompileService:
                 "failed": self.compiles_failed,
                 "cache_hits": self.cache_hits,
                 "pending": pending,
-                "eager_steps": self.eager_steps,
                 "inline_steps": self.inline_steps,
-                "compiled_steps": self.compiled_steps}
+                "compiled_steps": self.compiled_steps,
+                "await_s": round(self.await_s, 1)}
 
 
 # ---------------------------------------------------------------------------
@@ -660,15 +702,16 @@ class CompileService:
 def read_manifest(data_dir: Optional[str] = None) -> Optional[Dict]:
     """Load a compile manifest WITHOUT a live process: prefer the data
     dir's mirror copy (written by `attach_dir` at every save), fall back
-    to the persistent-cache dir named by RW_COMPILE_CACHE_DIR. Returns
-    None when neither exists — the dir predates manifest mirroring or
-    never ran with AOT on."""
+    to the persistent-cache dir in force (`device.compile_cache_dir`).
+    Returns None when neither exists — the dir predates manifest
+    mirroring or never ran with AOT on."""
+    from . import compile_cache_dir
     candidates = []
     if data_dir:
         candidates.append(os.path.join(data_dir, MANIFEST_FILE))
-    env = os.environ.get("RW_COMPILE_CACHE_DIR")
-    if env:
-        candidates.append(os.path.join(env, MANIFEST_FILE))
+    cache = compile_cache_dir()
+    if cache:
+        candidates.append(os.path.join(cache, MANIFEST_FILE))
     for path in candidates:
         if not os.path.exists(path):
             continue

@@ -813,15 +813,17 @@ def _prune_ingest_columns(nodes, ingest_nodes) -> None:
 
 def _fused_mesh(device_cfg, epoch_events: int):
     """The 1-D device mesh a fused program shards over, or None for the
-    single-chip path. `DeviceConfig.mesh_shards` opts in; the platform
-    must actually have the devices (mesh.make_mesh falls back to virtual
-    CPU devices under --xla_force_host_platform_device_count, the tier-1
-    test substrate) — a device miss degrades silently to one chip.
-    An epoch cadence that does NOT divide the shard count no longer
-    degrades: each shard's contiguous event block is ceil-div sized and
-    the tail block is PADDED (the over-generated ids mask out inside the
-    traced step, `shard_exec.sharded_apply`), so all chips engage at any
-    cadence."""
+    single-chip path. `DeviceConfig.mesh_shards` opts in, and the default
+    platform must actually have the devices: `mesh_shards=n` on fewer
+    than n devices fails the CREATE MATERIALIZED VIEW (make_mesh raises)
+    rather than running on one chip without saying so. Only the serving
+    replicas degrade: a platform too small for the replica grid keeps
+    the data parallelism and drops the replica axis.
+    An epoch cadence that does NOT divide the shard count does not
+    degrade either: each shard's contiguous event block is ceil-div sized
+    and the tail block is PADDED (the over-generated ids mask out inside
+    the traced step, `shard_exec.sharded_apply`), so all chips engage at
+    any cadence."""
     import os
     n = max(1, int(getattr(device_cfg, "mesh_shards", 1) or 1))
     if n <= 1:
@@ -829,18 +831,12 @@ def _fused_mesh(device_cfg, epoch_events: int):
     r = os.environ.get("RW_MESH_REPLICAS")
     r = int(r) if r else max(1, int(getattr(device_cfg, "replicas", 1) or 1))
     from ..parallel.mesh import make_mesh
-    try:
-        return make_mesh(n, replicas=r)
-    except (ValueError, RuntimeError):
-        if r > 1:
-            # not enough devices for the replica grid: keep the data
-            # parallelism (correctness and capacity shapes key on it)
-            # and drop only the serving replicas
-            try:
-                return make_mesh(n)
-            except (ValueError, RuntimeError):
-                return None
-        return None
+    if r > 1:
+        try:
+            return make_mesh(n, replicas=r)
+        except ValueError:
+            pass            # no room for the replica grid: shards only
+    return make_mesh(n)
 
 
 def _exchange_row_width(node) -> int:

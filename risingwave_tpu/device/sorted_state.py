@@ -156,22 +156,22 @@ _CHEAP_COMPILE: Optional[bool] = None
 
 
 def cheap_compile() -> bool:
-    """Backend-keyed kernel policy. On CPU, XLA's compile time for
-    sorts grows ~18s PER OPERAND at bench shapes, cumsum costs ~50s and
-    searchsorted(method='sort') ~45s — while gathers/scans compile in
-    ~1s with equal CPU runtime, so the CPU (test-suite) build prefers
-    compile-cheap forms. On TPU the variadic sort / co-sorted
-    searchsorted are the RUNTIME-optimal forms (gather/scatter are the
-    chip's weakest primitives; its sort networks the strongest — r04
-    measurements) and compile acceptably, so they stay."""
+    """Kernel-form policy: ONE form on every backend, the compile-cheap
+    one. XLA's compile time for the variadic-sort forms (multi-operand
+    `lax.sort`, cumsum, searchsorted(method='sort')) falls off a cliff
+    between 4 K and 16 K state slots on the TPU compiler as on the CPU's
+    — five to six minutes for ONE node program at bench shapes, against
+    one to two for the gather/scan forms (PR 22 offline compiles for a
+    v5e, CHANGES.md) — and a fused database has a dozen such programs,
+    so the variadic forms do not start inside any serving budget.
+    r04/r05 measured them up to 3x faster at RUN time for a 2^20-slot
+    agg; `RW_TPU_CHEAP_COMPILE=0` keeps them reachable until a benchmark
+    settles that trade (ROADMAP D5)."""
     global _CHEAP_COMPILE
     if _CHEAP_COMPILE is None:
         import os
-        env = os.environ.get("RW_TPU_CHEAP_COMPILE")
-        if env is not None:
-            _CHEAP_COMPILE = env not in ("", "0", "false")
-        else:
-            _CHEAP_COMPILE = jax.default_backend() == "cpu"
+        _CHEAP_COMPILE = os.environ.get("RW_TPU_CHEAP_COMPILE", "1") \
+            not in ("", "0", "false")
     return _CHEAP_COMPILE
 
 
@@ -188,9 +188,9 @@ def running_sum(x: jax.Array) -> jax.Array:
 
 def sort_cols(keys: Sequence[jax.Array], cols: Sequence[jax.Array]
               ) -> Tuple[Tuple[jax.Array, ...], Tuple[jax.Array, ...]]:
-    """Stable sort of payload columns by key columns: one variadic
-    `lax.sort` on TPU (fastest runtime); rank-sort + gathers on CPU
-    beyond 2 payloads (fastest compile — see cheap_compile)."""
+    """Stable sort of payload columns by key columns: rank-sort + gathers
+    beyond 2 payloads (fastest compile — see cheap_compile), else one
+    variadic `lax.sort`."""
     nk = len(keys)
     if len(cols) <= 2 or not cheap_compile():
         out = jax.lax.sort(list(keys) + list(cols), num_keys=nk,

@@ -26,11 +26,7 @@ SHARD_AXIS = "shard"
 # which scopes them to the per-replica data group.
 REPLICA_AXIS = "replica"
 
-# jax moved shard_map out of experimental at 0.5; support both
-try:
-    shard_map = jax.shard_map
-except AttributeError:                     # jax < 0.5
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 
 def make_mesh(n_devices: Optional[int] = None,
@@ -44,26 +40,18 @@ def make_mesh(n_devices: Optional[int] = None,
     `n_devices * r` devices and shapes them `(n_devices, r)` with axes
     `(shard, replica)`: device [d, k] holds data-shard d of replica k.
 
-    When the default platform has fewer devices than requested (one real TPU
-    chip but an 8-shard dry run), fall back to the CPU backend, which serves
-    virtual devices under --xla_force_host_platform_device_count."""
+    The devices are the default platform's own: asking for more than it
+    has raises — a mesh never reaches for another platform's devices
+    (tier-1's default platform IS the 8 virtual CPU devices)."""
     replicas = max(1, int(replicas))
     want = None if n_devices is None else int(n_devices) * replicas
     if devices is None:
         devices = jax.devices()
         if want is not None:
             if len(devices) < want:
-                try:
-                    cpu = jax.devices("cpu")
-                except RuntimeError:
-                    cpu = []
-                if len(cpu) >= want:
-                    devices = cpu
-            if len(devices) < want:
                 raise ValueError(
-                    f"need {want} devices but only {len(devices)} exist "
-                    "(set XLA_FLAGS=--xla_force_host_platform_device_count=N "
-                    "before jax initializes to get virtual CPU devices)")
+                    f"need {want} devices but the default platform "
+                    f"({devices[0].platform}) has {len(devices)}")
             devices = devices[:want]
     devices = np.asarray(devices)
     if replicas == 1:

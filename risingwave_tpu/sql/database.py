@@ -753,8 +753,8 @@ class Database:
                     job.compile_service.attach_dir(self._data_dir)
                 job.recover()      # no-op unless the store has a committed
                 # CREATE-time AOT kickoff: the plan's shapes (post-
-                # presize) compile in the background while the
-                # interpreted path serves the first epochs; identically-
+                # presize) compile in parallel in the background and
+                # the first epoch takes them as they land; identically-
                 # shaped jobs and DROP+re-CREATE find every signature
                 # already compiled (zero-compile warm start)
                 job.prewarm()

@@ -9,6 +9,7 @@ prediction on, and (c) recover()/re-create with ZERO growth replays
 thanks to persisted high-water marks.
 """
 import json
+import os
 
 import pytest
 
@@ -258,25 +259,39 @@ def test_ctl_fused_stats_no_jobs(tmp_path, capsys):
 def test_compile_cache_knob(tmp_path, monkeypatch):
     import jax
 
-    from risingwave_tpu.device import configure_compile_cache
+    from risingwave_tpu import device
+    from risingwave_tpu.device import (compile_cache_dir,
+                                       configure_compile_cache)
     prev = jax.config.jax_compilation_cache_dir
     try:
-        monkeypatch.delenv("RW_COMPILE_CACHE_DIR", raising=False)
-        monkeypatch.delenv("RW_TPU_JAX_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        # no explicit directory: the fixed <checkout>/.jax_cache — never a
+        # temporary name (the path is part of the cache key)
+        assert configure_compile_cache() == device._DEFAULT_CACHE
+        assert device._DEFAULT_CACHE == os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
         want = str(tmp_path / "cc")
-        assert configure_compile_cache(want) is True
-        assert jax.config.jax_compilation_cache_dir == want
+        assert configure_compile_cache(want) == want
+        assert compile_cache_dir() == want
         # the DeviceConfig knob routes through resolve_device
         want2 = str(tmp_path / "cfg")
         resolve_device(DeviceConfig(compile_cache_dir=want2))
+        assert compile_cache_dir() == want2
+        # a cache placed from outside wins: jax read the variable itself
+        # at import, and nothing in the program writes the option
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "env"))
+        assert configure_compile_cache(want) == want2
+        resolve_device(DeviceConfig(compile_cache_dir=want))
         assert jax.config.jax_compilation_cache_dir == want2
-        # RW_COMPILE_CACHE_DIR overrides any explicit directory...
-        env = str(tmp_path / "env")
-        monkeypatch.setenv("RW_COMPILE_CACHE_DIR", env)
-        assert configure_compile_cache(want) is True
-        assert jax.config.jax_compilation_cache_dir == env
-        # ...and an empty override disables cleanly
-        monkeypatch.setenv("RW_COMPILE_CACHE_DIR", "")
-        assert configure_compile_cache(want) is False
+        # the compile manifest asks the same resolver
+        from risingwave_tpu.device.compile_service import (MANIFEST_FILE,
+                                                           get_service)
+        assert get_service()._manifest_path() == os.path.join(
+            want2, MANIFEST_FILE)
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache_dir() is None
+        assert get_service()._manifest_path() is None
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)

@@ -3,11 +3,11 @@ zero-compile warm starts.
 
 Contracts under test:
  * bit-identical MV results across a bucket-boundary growth with the
-   service on (the interpreted bridge and the compiled executables are
-   the same computation);
- * executable swap mid-job at a barrier: epochs served on the
-   interpreted path while compiles are pending, compiled dispatch after
-   they land, results unchanged across the swap;
+   service on;
+ * one way to run a node step: a pending signature WAITS for its
+   background compile (bounded — it raises with the label rather than
+   wait for good), a failed one takes the counted inline-jit fallback,
+   and a shutdown leaves no entry pending without a task;
  * zero-compile DROP + re-CREATE (and second identically-shaped job),
    asserted via profiler compile counts AND the service's fresh-compile
    counter;
@@ -158,15 +158,15 @@ def test_plan_shape_hash_stable_across_instances():
 
 
 # ---------------------------------------------------------------------------
-# background AOT: interpreted bridge, swap at a barrier, bucket growth
+# background AOT: the dispatcher waits for pending compiles, bucket growth
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.aot
-def test_interpreted_bridge_then_swap_bit_identical():
-    """With every background compile HELD, the job must come online and
-    serve correct epochs on the interpreted path; after the hold lifts,
-    compiled executables swap in at the next barrier and the final MV is
+def test_held_compiles_block_dispatch_then_bit_identical():
+    """With every background compile HELD, the first barrier must WAIT
+    (no other way to run a step exists); after the hold lifts it
+    completes on the compiled executables and the final MV is
     bit-identical to the host path — across a bucket-boundary growth
     (capacity=64 forces at least one). Uses a max.events no other test
     shares: the executable cache is process-global, and a plan another
@@ -187,22 +187,23 @@ def test_interpreted_bridge_then_swap_bit_identical():
         db.run(Q4.format(name="q4"))
         job = db._fused["q4"]
         assert job.compile_service is svc
-        eager0 = svc.eager_steps
-        db.tick()
-        assert svc.eager_steps > eager0, \
-            "held compiles must serve epochs on the interpreted bridge"
-        # mid-bridge queries are served (sync + pull works eagerly);
-        # only ONE tick before this so the bounded source still has
-        # epochs left for the post-swap drive below
-        assert db.query("SELECT count(*) FROM q4")
+        compiled0, inline0, await0 = (svc.compiled_steps, svc.inline_steps,
+                                      svc.await_s)
+        first = threading.Thread(target=db.tick, daemon=True)
+        first.start()
+        first.join(1.5)
+        assert first.is_alive(), \
+            "held compiles must hold the first barrier back"
+        assert svc.compiled_steps == compiled0
     finally:
         svc.hold = None
         hold.set()
-    assert svc.wait_idle(120), "background compiles must land"
-    compiled0 = svc.compiled_steps
-    drive(db, n=n)                 # swap happened at a barrier boundary
-    assert svc.compiled_steps > compiled0, \
-        "ready executables must take over dispatch after the swap"
+    first.join(120)
+    assert not first.is_alive(), "the barrier must finish once compiles land"
+    assert svc.await_s > await0, "the wait is accounted for"
+    drive(db, n=n)
+    assert svc.compiled_steps > compiled0
+    assert svc.inline_steps == inline0, "no step took the inline fallback"
     assert job.growth_replays >= 1, "test must cross a bucket boundary"
     assert sorted(db.query("SELECT * FROM q4")) == oracle
 
@@ -370,12 +371,172 @@ def test_ctl_compile_status(tmp_path, capsys, oracle):
     capsys.readouterr()
 
 
+def _source_step_args(n=N):
+    """(node, epoch_events, state, ins, extra) of a bid source's node
+    step — the smallest real program to hand a PRIVATE service (the
+    process-global one stays clean)."""
+    import jax.numpy as jnp
+    db = Database(device=DeviceConfig(capacity=256, aot_compile=False))
+    db.run(BID_SRC.format(n=n, c=CHUNK))
+    db.run(Q4.format(name="q4"))
+    job = db._fused["q4"]
+    node = job.program.nodes[0]
+    assert node.takes_event_lo
+    return node, job.program.epoch_events, job.states[0], (), jnp.int64(0)
+
+
+@pytest.mark.aot
+def test_await_raises_after_its_limit(monkeypatch):
+    """A compile that never lands must not hold the dispatcher for good:
+    past `AWAIT_LIMIT_S` the step raises, naming the node."""
+    import threading
+
+    from risingwave_tpu.device import compile_service as cs
+    monkeypatch.setattr(cs, "AWAIT_LIMIT_S", 0.3)
+    svc = cs.CompileService(workers=1)
+    svc.hold = hold = threading.Event()
+    try:
+        with pytest.raises(TimeoutError, match="0:TheNode"):
+            svc.node_step(*_source_step_args(), label="0:TheNode")
+    finally:
+        svc.hold = None
+        hold.set()
+    assert svc.wait_idle(60)
+    svc.shutdown()
+
+
+@pytest.mark.aot
+def test_shutdown_leaves_no_entry_pending_without_a_task():
+    """shutdown() drops queued compiles; their entries must not stay
+    `pending` (the next step on that signature would wait for good):
+    they are forgotten, and the next request compiles afresh."""
+    import threading
+
+    from risingwave_tpu.device.compile_service import CompileService
+    node, ee, state, ins, extra = _source_step_args()
+    svc = CompileService(workers=1)
+    svc.hold = hold = threading.Event()
+    got = {}
+
+    def step(name, epoch_events):
+        got[name] = svc.node_step(node, epoch_events, state, ins, extra,
+                                  label=name)
+
+    # the single worker holds signature A in flight; B stays queued
+    ta = threading.Thread(target=step, args=("a", ee), daemon=True)
+    ta.start()
+    while not svc._inflight:
+        time.sleep(0.01)
+    tb = threading.Thread(target=step, args=("b", 2 * ee), daemon=True)
+    tb.start()
+    while len(svc._entries) < 2:
+        time.sleep(0.01)
+    svc.shutdown(join=False, timeout=0.1)
+    tb.join(60)          # released to the inline fallback, not left waiting
+    assert not tb.is_alive() and got["b"] is not None
+    assert svc.inline_steps == 1
+    assert [e.label for e in svc._entries.values()] == ["a"]
+    svc.hold = None
+    hold.set()
+    ta.join(60)
+    assert not ta.is_alive() and got["a"] is not None
+    step("b", 2 * ee)    # a fresh entry: compiled, not inline
+    assert svc.summary()["pending"] == 0 and svc.inline_steps == 1
+    assert svc.compiled_steps == 2 and svc.summary()["failed"] == 0
+    svc.shutdown()
+
+
+@pytest.mark.aot
+def test_heap_is_trimmed_after_a_long_compile(monkeypatch):
+    """The compiler's freed scratch goes back to the OS after a compile
+    that ran for seconds (and only then: the trim walks every arena)."""
+    from risingwave_tpu.device import compile_service as cs
+    node, ee, state, ins, extra = _source_step_args()
+    trims = []
+    monkeypatch.setattr(cs, "_MALLOC_TRIM", trims.append)
+    svc = cs.CompileService(workers=1)
+    monkeypatch.setattr(cs, "TRIM_AFTER_S", float("inf"))
+    svc.node_step(node, ee, state, ins, extra, label="short")
+    assert trims == []
+    monkeypatch.setattr(cs, "TRIM_AFTER_S", 0.0)
+    svc.node_step(node, 2 * ee, state, ins, extra, label="long")
+    assert trims == [0] and svc.summary()["compiles"] == 2
+    svc.shutdown()
+
+
+@pytest.mark.aot
+def test_pending_signature_waits_for_its_compile(oracle):
+    """A pending signature WAITS for its background compile — the one
+    way a step runs on every backend: every step on a compiled
+    executable, none inline, same MV. Uses a capacity no other test in
+    this file shares, so the signatures start pending."""
+    svc = _svc()
+    inline0, compiled0, await0 = (svc.inline_steps, svc.compiled_steps,
+                                  svc.await_s)
+    db = Database(device=DeviceConfig(capacity=8192 + 4096,
+                                      aot_compile=True,
+                                      compile_buckets=0))
+    db.run(BID_SRC.format(n=N, c=CHUNK))
+    db.run(Q4.format(name="q4"))
+    drive(db)
+    assert svc.inline_steps == inline0
+    assert svc.compiled_steps > compiled0
+    assert svc.await_s > await0, "the dispatcher waited on the compiles"
+    assert sorted(db.query("SELECT * FROM q4")) == oracle
+
+
+@pytest.mark.aot
+def test_failed_aot_compile_is_loud_and_counted(monkeypatch, caplog):
+    """A failed background compile may fall back to inline jit, but not
+    quietly: one warning with the node label and the compiler's message,
+    and `summary()["failed"]` counts it (chip_smoke.py fails on that).
+    Runs on a PRIVATE service so the process-global one stays clean."""
+    import logging
+
+    from risingwave_tpu.device import fused
+    from risingwave_tpu.device.compile_service import CompileService
+
+    args = _source_step_args()
+    real = fused._jit_step()
+
+    class RefusingCompiler:
+        def lower(self, *a, **k):
+            raise RuntimeError("RESOURCE_EXHAUSTED: boom from the compiler")
+
+        def __call__(self, *a, **k):
+            return real(*a, **k)
+
+    monkeypatch.setattr(fused, "_jit_step", lambda: RefusingCompiler())
+    svc = CompileService(workers=1)
+    with caplog.at_level(logging.WARNING,
+                         logger="risingwave_tpu.device.compile_service"):
+        out = svc.node_step(*args, label="0:TheNode")   # failed -> inline
+    assert out is not None
+    assert svc.summary()["failed"] == 1
+    assert svc.summary()["inline_steps"] == 1
+    warned = [r.getMessage() for r in caplog.records
+              if "0:TheNode" in r.getMessage()]
+    assert len(warned) == 1 and "boom from the compiler" in warned[0]
+    svc.shutdown()
+
+
+def test_out_of_memory_is_not_a_recoverable_device_fault():
+    """HBM exhaustion arrives as the same XlaRuntimeError a transient
+    device fault does; replaying the same shapes exhausts it again, so
+    in-place recovery must not absorb it."""
+    from risingwave_tpu.device.fused import _is_device_fault
+    XlaRuntimeError = type("XlaRuntimeError", (RuntimeError,), {})
+    assert _is_device_fault(XlaRuntimeError("INTERNAL: core halted"))
+    assert not _is_device_fault(XlaRuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm"))
+
+
 @pytest.mark.aot
 def test_service_summary_counters():
     svc = _svc()
     s = svc.summary()
     assert set(s) >= {"compiles", "failed", "cache_hits", "pending",
-                      "eager_steps", "compiled_steps"}
+                      "inline_steps", "compiled_steps"}
     assert s["failed"] == 0, \
         f"background AOT compiles failed during this suite: {svc.status()}"
 

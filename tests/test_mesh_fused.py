@@ -288,7 +288,6 @@ def test_offline_compile_status_dead_dir(tmp_path, capsys, monkeypatch):
     """`risectl compile-status --offline` must answer from a dead data
     dir via the compile_manifest.json mirror — no Database, no rebuild,
     no recompiles (the PR 6 residual)."""
-    monkeypatch.delenv("RW_COMPILE_CACHE_DIR", raising=False)
     d = str(tmp_path / "data")
     _, job, db = _run(Q1_MV, "q1a", 8, aot=True, data_dir=d, keep=True)
     plan_hash = job.plan_hash
@@ -302,3 +301,22 @@ def test_offline_compile_status_dead_dir(tmp_path, capsys, monkeypatch):
     assert rc == 0
     assert plan_hash in out             # the plan shape is on record
     assert '"shards": 8' in out         # sharded executables are labeled
+
+
+@pytest.mark.mesh
+def test_too_few_devices_fails_loudly():
+    """No quiet road to fewer chips: a mesh larger than the default
+    platform raises, and so does the CREATE MATERIALIZED VIEW that asked
+    for it — the MV must not land on one chip (or the host) unsaid."""
+    import jax
+    from risingwave_tpu.parallel.mesh import make_mesh
+    n = 2 * len(jax.devices())           # 16 on the 8-device platform
+    with pytest.raises(ValueError, match=f"need {n} devices"):
+        make_mesh(n)
+    db = Database(device=DeviceConfig(capacity=512, mesh_shards=n,
+                                      aot_compile=False))
+    db.run(BID_SRC.format(n=N, c=CHUNK))
+    with pytest.raises(ValueError, match=f"need {n} devices"):
+        db.run(Q1_MV)
+    assert "q1a" not in db._fused
+
