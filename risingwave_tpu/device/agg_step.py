@@ -203,9 +203,10 @@ def _core_tail(spec: DeviceAggSpec, state: SortedState,
     (`epoch_core`) and the pre-combined path (`epoch_core_combined`),
     which arrive at the same unique-delta representation from different
     inputs."""
-    old_found, old_vals = lookup(state, ukeys)
-    new_state, needed = merge(state, ukeys, udeltas, spec.kinds)
-    new_found, new_vals = lookup(new_state, ukeys)
+    with jax.named_scope("agg.merge"):
+        old_found, old_vals = lookup(state, ukeys)
+        new_state, needed = merge(state, ukeys, udeltas, spec.kinds)
+        new_found, new_vals = lookup(new_state, ukeys)
     old_out, old_null = _outputs(spec, old_vals)
     new_out, new_null = _outputs(spec, new_vals)
     changes = {
@@ -226,8 +227,10 @@ def epoch_core(spec: DeviceAggSpec, state: SortedState,
                inputs: Tuple[Tuple[jax.Array, jax.Array], ...]):
     """The (un-jitted) epoch pipeline, shared by the single-chip step below
     and the shard-local body of parallel/sharded_agg.py."""
-    deltas = _row_deltas(spec, signs, mask, inputs)
-    ukeys, udeltas, ucount = batch_reduce(keys, mask, deltas, spec.kinds)
+    with jax.named_scope("agg.reduce_delta"):
+        deltas = _row_deltas(spec, signs, mask, inputs)
+        ukeys, udeltas, ucount = batch_reduce(keys, mask, deltas,
+                                              spec.kinds)
     return _core_tail(spec, state, ukeys, udeltas, ucount)
 
 
@@ -245,11 +248,12 @@ def precombine_core(spec: DeviceAggSpec,
     and re-combining after the exchange is bit-identical to merging raw
     rows — the caller guarantees integer-only SUM columns (float sums
     are order-sensitive) and no multiset side state."""
-    live = mask & (signs != 0)
-    deltas = _row_deltas(spec, signs, mask, inputs)
-    cnt = jnp.where(live, 1, 0).astype(jnp.int64)
-    ukeys, uvals, _ = batch_reduce(keys, live, [cnt] + list(deltas),
-                                   (ReduceKind.SUM,) + spec.kinds)
+    with jax.named_scope("agg.reduce_delta"):
+        live = mask & (signs != 0)
+        deltas = _row_deltas(spec, signs, mask, inputs)
+        cnt = jnp.where(live, 1, 0).astype(jnp.int64)
+        ukeys, uvals, _ = batch_reduce(keys, live, [cnt] + list(deltas),
+                                       (ReduceKind.SUM,) + spec.kinds)
     return ukeys, uvals[0], tuple(uvals[1:])
 
 
@@ -263,9 +267,10 @@ def epoch_core_combined(spec: DeviceAggSpec, state: SortedState,
     Returns (new_state, needed, changes) exactly like `epoch_core`, plus
     changes["rows_in"] = total raw rows behind the combined input (the
     flow stat the raw path would have counted)."""
-    ukeys, uvals, ucount = batch_reduce(
-        keys, mask, [counts.astype(jnp.int64)] + list(dvals),
-        (ReduceKind.SUM,) + spec.kinds)
+    with jax.named_scope("agg.reduce_delta"):
+        ukeys, uvals, ucount = batch_reduce(
+            keys, mask, [counts.astype(jnp.int64)] + list(dvals),
+            (ReduceKind.SUM,) + spec.kinds)
     new_state, needed, ch = _core_tail(spec, state, ukeys, uvals[1:],
                                        ucount)
     ch["rows_in"] = jnp.sum(uvals[0])

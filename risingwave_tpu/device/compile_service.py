@@ -297,7 +297,8 @@ class CompileService:
         self.compiled_steps = 0
         # steps served by the inline-jit fallback of a FAILED entry
         self.inline_steps = 0
-        # seconds the dispatcher spent waiting on pending compiles
+        # seconds the dispatcher spent waiting on pending compiles: the
+        # sum of its `rw:compile_wait` spans
         self.await_s = 0.0
         self._manifest: Dict[str, Any] = {}
         self._manifest_loaded = False
@@ -499,7 +500,7 @@ class CompileService:
             elif job is not None and job not in ent.jobs:
                 ent.jobs[job] = False    # shared/cached for this job
         if ent.status == "pending":
-            self._await(ent)
+            self._await(ent, profiler)
         if ent.status == "ready":
             try:
                 out = ent.compiled(state, ins, extra)
@@ -527,24 +528,33 @@ class CompileService:
         from .fused import _node_step
         return _node_step(node, epoch_events, state, ins, extra)
 
-    def _await(self, ent: CompileEntry) -> None:
-        """Block the dispatcher until `ent`'s background compile lands.
+    def _await(self, ent: CompileEntry, profiler=None) -> None:
+        """Block the dispatcher until `ent`'s background compile lands
+        (a `rw:compile_wait` span of the waiting job's profiler; timed
+        all the same when the job has none).
         Running the step some other way meanwhile is a loss: measured on
         a v5e (PR 22, CHANGES.md), an op-by-op first epoch of the 4-node
         bid group-by took 280 s against a 130 s critical-path compile —
         every eager primitive is its own compile, racing the AOT workers
         for the same cores. Raises after `AWAIT_LIMIT_S` rather than
         wait for good on a compile that never lands."""
+        from ..utils.profile import Span
+        attrs = {"node": ent.node.stable_name(), "cache_hit": ent.cache_hit}
+        wait = profiler.span("rw:compile_wait", **attrs) \
+            if profiler is not None and profiler.enabled \
+            else Span("rw:compile_wait", attrs, record=False)
         t0 = time.perf_counter()
-        with self._cv:
-            while ent.status == "pending":
-                left = AWAIT_LIMIT_S - (time.perf_counter() - t0)
-                if left <= 0:
-                    raise TimeoutError(
-                        f"AOT compile of {ent.label} still pending after "
-                        f"{AWAIT_LIMIT_S:.0f}s")
-                self._cv.wait(min(0.5, left))
-            self.await_s += time.perf_counter() - t0
+        with wait:
+            with self._cv:
+                while ent.status == "pending":
+                    left = AWAIT_LIMIT_S - (time.perf_counter() - t0)
+                    if left <= 0:
+                        raise TimeoutError(
+                            f"AOT compile of {ent.label} still pending "
+                            f"after {AWAIT_LIMIT_S:.0f}s")
+                    self._cv.wait(min(0.5, left))
+        with self._lock:
+            self.await_s += wait.seconds
 
     def _request_locked(self, key, node, epoch_events, sds, *, label, job,
                         profiler, kind, mesh=None) -> CompileEntry:
@@ -566,31 +576,39 @@ class CompileService:
             if self.hold is not None:
                 ent_hold = self.hold
                 ent_hold.wait()
-            import jax
+            from ..utils.profile import NULL_PROFILER
             from .fused import _jit_step
             state_s, ins_s, extra_s = ent.sds
             t0 = time.perf_counter()
             try:
-                if ent.mesh is not None:
-                    from .shard_exec import sharded_jit_step
-                    step = sharded_jit_step(ent.mesh)
-                else:
-                    step = _jit_step()
-                lowered = step.lower(
-                    state_s, ins_s, extra_s, node=ent.node,
-                    epoch_events=ent.epoch_events, salt=ent.salt)
-                ent.compiled = lowered.compile()
+                with (ent.profiler or NULL_PROFILER).span(
+                        "rw:compile", node=ent.node.stable_name(),
+                        bucket=repr(ent.bucket), cache_hit=ent.cache_hit,
+                        ok=False) as sp:
+                    if ent.mesh is not None:
+                        from .shard_exec import sharded_jit_step
+                        step = sharded_jit_step(ent.mesh, ent.node)
+                    else:
+                        step = _jit_step(ent.node)
+                    lowered = step.lower(
+                        state_s, ins_s, extra_s, node=ent.node,
+                        epoch_events=ent.epoch_events, salt=ent.salt)
+                    ent.compiled = lowered.compile()
+                    sp.set(ok=True)
             except Exception as e:
                 ent.seconds = time.perf_counter() - t0
                 ent.error = f"{type(e).__name__}: {e}"
-                ent.status = "failed"
                 with self._lock:
                     self.compiles_failed += 1
                 # once per signature (a failed entry never re-queues):
-                # the inline-jit fallback it now takes must not be quiet
+                # the inline-jit fallback it now takes must not be quiet.
+                # Counted and said BEFORE the status is published: a
+                # dispatcher that sees "failed" goes on at once, and
+                # whoever watches it must find the failure on record
                 _log.warning("AOT compile of %s failed after %.1fs, "
                              "falling back to inline jit: %s",
                              ent.label, ent.seconds, ent.error)
+                ent.status = "failed"
                 _trim_heap(ent.seconds)
                 return
             ent.seconds = time.perf_counter() - t0

@@ -38,6 +38,7 @@ import numpy as np
 from ..core import dtypes as T
 from ..core.dtypes import DataType, TypeKind
 from ..utils.failpoint import FailpointError, declare, failpoint
+from ..utils.profile import COMPILE_THRESHOLD_S, NULL_PROFILER
 
 # Fused device-path failure seams (fault-tolerance v3): each hook sits at
 # the point where a real device fault would surface — the async epoch
@@ -338,6 +339,38 @@ def _expr_sig(e) -> Tuple:
     return base + (kids,)
 
 
+_NAME_MAX = 48
+
+
+def _name_token(x) -> str:
+    """Letters, digits and `_` of `x` (names end up in XLA module names
+    and span attributes)."""
+    import re
+    return re.sub(r"[^A-Za-z0-9]+", "_", str(x)).strip("_")
+
+
+def _expr_tag(sig: Tuple) -> str:
+    """Short tag of an `_expr_sig`: `c<i>` for a column, else the
+    function's name (or the class's) over its arguments' tags."""
+    cls, _rtype, what, kids = sig[0], sig[1], sig[2], sig[-1]
+    if cls == "InputRef":
+        return f"c{what}"
+    head = _name_token(what if cls == "FunctionCall" else cls).lower()
+    return "_".join([head] + [_expr_tag(k) for k in kids])
+
+
+def _calls_tag(calls) -> str:
+    """`count_sum1_max2` of an agg's (kind, argument column) calls."""
+    return "_".join(f"{k}{'' if j is None else j}" for k, j in calls)
+
+
+def _named(fn, name: str):
+    """`fn` under `name`: jax names the XLA module of a jitted function
+    `jit_<its __name__>`."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 class Node:
     """Static stage config. `inputs` are node indices; state is one pytree
     slot per node (None when stateless).
@@ -512,6 +545,30 @@ class Node:
     def _sig(self) -> Tuple:
         return (id(self),)            # default: no structural sharing
 
+    def _name_parts(self) -> Tuple:
+        """What tells this node apart in its plan, from its STRUCTURE
+        only (never its program position, an MV's name or `hash()`): two
+        programs sharing a signature share the name, and with it the
+        XLA module and its compile."""
+        return ()
+
+    def stable_name(self) -> str:
+        """`<type>_<parts>` in letters, digits and `_`, the same in
+        every process: the node's name in XLA module names
+        (`jit_step_<name>`), span attributes and compile labels. A name
+        over `_NAME_MAX` characters is cut and closed with a digest of
+        the whole, so that two long names stay two names."""
+        name = self.__dict__.get("_stable_name")
+        if name is None:        # structure only: fixed at construction
+            kind = type(self).__name__.removesuffix("Node").lower()
+            name = "_".join([kind] + [t for t in map(
+                _name_token, self._name_parts()) if t])
+            if len(name) > _NAME_MAX:
+                from .compile_service import _stable_digest
+                name = f"{name[:_NAME_MAX - 7]}_{_stable_digest(name)[:6]}"
+            self._stable_name = name
+        return name
+
     def _mut_sig(self) -> Tuple:
         """Trace-shaping attributes that `grow` MUTATES (JoinNode.m).
         jit static arguments must be immutable — jax's dispatch fast path
@@ -527,27 +584,32 @@ class Node:
         return type(self) is type(other) and self._sig() == other._sig()
 
 
-def _jit_step():
-    """The shared jitted per-node step (lazy singleton). The compile
-    service AOT-lowers through the SAME function so an inline jit call
-    and a background `.lower().compile()` of one signature are the same
-    trace (and the same persistent-cache entry)."""
+def _jit_step(node: "Node"):
+    """The jitted per-node step, one per node NAME (lazy): the XLA
+    module is `jit_step_<node.stable_name()>`, so a device trace says
+    which node a module is. Nodes of one name share the function (the
+    node itself is a static argument). The compile service AOT-lowers
+    through the SAME accessor so an inline jit call and a background
+    `.lower().compile()` of one signature are the same trace (and the
+    same persistent-cache entry)."""
     import jax
-    global _JIT_STEP
-    if _JIT_STEP is None:
-        _JIT_STEP = jax.jit(
-            lambda state, ins, extra, *, node, epoch_events, salt:
-            node.apply(state, ins, extra, epoch_events),
+    name = node.stable_name()
+    fn = _JIT_STEPS.get(name)
+    if fn is None:
+        def step(state, ins, extra, *, node, epoch_events, salt):
+            return node.apply(state, ins, extra, epoch_events)
+        fn = _JIT_STEPS[name] = jax.jit(
+            _named(step, f"step_{name}"),
             static_argnames=("node", "epoch_events", "salt"))
-    return _JIT_STEP
+    return fn
 
 
 def _node_step(node: Node, epoch_events: int, state, ins, extra):
-    return _jit_step()(state, ins, extra, node=node,
-                       epoch_events=epoch_events, salt=node._mut_sig())
+    return _jit_step(node)(state, ins, extra, node=node,
+                           epoch_events=epoch_events, salt=node._mut_sig())
 
 
-_JIT_STEP = None
+_JIT_STEPS: Dict[str, Any] = {}
 _STACK_JIT = None
 _FOLD_JIT = None
 
@@ -559,7 +621,9 @@ def _stack_stats(stats: Tuple):
     import jax.numpy as jnp
     global _STACK_JIT
     if _STACK_JIT is None:
-        _STACK_JIT = jax.jit(lambda xs: jnp.stack(xs))
+        def stats_stack(xs):
+            return jnp.stack(xs)
+        _STACK_JIT = jax.jit(stats_stack)
     return _STACK_JIT(stats)
 
 
@@ -569,8 +633,9 @@ def _fold_stats(vec, acc, sum_mask):
     import jax.numpy as jnp
     global _FOLD_JIT
     if _FOLD_JIT is None:
-        _FOLD_JIT = jax.jit(
-            lambda v, a, m: jnp.where(m, a + v, jnp.maximum(a, v)))
+        def stats_fold(v, a, m):
+            return jnp.where(m, a + v, jnp.maximum(a, v))
+        _FOLD_JIT = jax.jit(stats_fold)
     return _FOLD_JIT(vec, acc, sum_mask)
 
 
@@ -611,6 +676,9 @@ class SourceNode(Node):
     def _sig(self):
         return (self.table, self.gencfg, tuple(self.col_names),
                 self.rowid_pos, self.max_events)
+
+    def _name_parts(self):
+        return (self.table,)
 
     def apply(self, state, ins, extra, epoch_events):
         import jax.numpy as jnp
@@ -687,6 +755,9 @@ class IngestNode(Node):
         return ("ingest", self.table, self.gencfg, tuple(self.col_names),
                 self.rowid_pos, self.max_events, self.live)
 
+    def _name_parts(self):
+        return (self.table,)
+
     def feed_sds(self, cap: int):
         """ShapeDtypeStruct mirror of one (per-shard) feed — what the
         compile service's abstract walks lower against."""
@@ -739,6 +810,9 @@ class MapNode(Node):
         # report the new trace as cached)
         return tuple(_expr_sig(e) for e in self.exprs) + ("rio",)
 
+    def _name_parts(self):
+        return tuple(_expr_tag(_expr_sig(e)) for e in self.exprs)
+
     def apply(self, state, ins, extra, epoch_events):
         d = ins[0]
         cols = [e.eval_device(d.cols)[0] for e in self.exprs]
@@ -759,6 +833,9 @@ class FilterNode(Node):
 
     def _sig(self):
         return (_expr_sig(self.pred), "rio")   # see MapNode._sig
+
+    def _name_parts(self):
+        return (_expr_tag(_expr_sig(self.pred)),)
 
     def apply(self, state, ins, extra, epoch_events):
         d = ins[0]
@@ -786,6 +863,9 @@ class HopNode(Node):
 
     def _sig(self):
         return (self.time_col, self.hop, self.size, "rio")  # see MapNode
+
+    def _name_parts(self):
+        return (f"c{self.time_col}", f"h{self.hop}", f"s{self.size}")
 
     def apply(self, state, ins, extra, epoch_events):
         import jax.numpy as jnp
@@ -823,6 +903,16 @@ class ChainNode(Node):
 
     def _sig(self):
         return tuple((type(n).__name__,) + n._sig() for n in self.chain)
+
+    def _name_parts(self):
+        # the members' kinds (a source with its table), then a digest of
+        # their full names: two chains of the same kinds stay two names
+        from .compile_service import _stable_digest
+        names = [n.stable_name() for n in self.chain]
+        kinds = [nm if isinstance(n, (SourceNode, IngestNode))
+                 else nm.split("_", 1)[0]
+                 for n, nm in zip(self.chain, names)]
+        return tuple(kinds) + (_stable_digest(names)[:6],)
 
     def apply(self, state, ins, extra, epoch_events):
         out = None
@@ -914,6 +1004,11 @@ class PrecombineNode(Node):
                 tuple((c.kind, c.arg.index if c.arg is not None else None)
                       for c in self.calls),
                 self.pack, self.spec)
+
+    def _name_parts(self):
+        return ("k" + "_".join(map(str, self.group_idx)),
+                _calls_tag((c.kind, c.arg.index if c.arg is not None
+                            else None) for c in self.calls))
 
     def apply(self, state, ins, extra, epoch_events):
         import jax.numpy as jnp
@@ -1178,6 +1273,11 @@ class AggNode(Node):
         if self.tier:
             sig = sig + ("tier",)
         return sig
+
+    def _name_parts(self):
+        return ("k" + "_".join(map(str, self.group_idx)),
+                _calls_tag((c.kind, c.arg.index if c.arg is not None
+                            else None) for c in self.calls))
 
     def _mut_sig(self):
         # grow mutates both; capacity shapes `bound`, exch the exchange.
@@ -1508,6 +1608,10 @@ class JoinNode(Node):
             sig = sig + ("tier",)
         return sig
 
+    def _name_parts(self):
+        return ("l" + "_".join(map(str, self.l_keys)),
+                "r" + "_".join(map(str, self.r_keys)))
+
     def _mut_sig(self):
         # grow mutates the pair capacity and the exchange bucket capacity
         # (exch=None single-chip keeps the pre-mesh salt shape — see AggNode)
@@ -1660,6 +1764,9 @@ class MVKeyedNode(Node):
     def _sig(self):
         return ("mvk",) + self.agg._sig()
 
+    def _name_parts(self):
+        return self.agg._name_parts()
+
     def apply(self, state, ins, extra, epoch_events):
         import jax.numpy as jnp
         from .materialize import mv_apply_changes
@@ -1719,14 +1826,19 @@ class MVPairNode(Node):
     def _sig(self):
         return (tuple(str(d) for d in self.val_dtypes),)
 
+    def _name_parts(self):
+        return (f"{len(self.val_dtypes)}c",)
+
     def apply(self, state, ins, extra, epoch_events):
+        import jax
         import jax.numpy as jnp
         from .join_step import merge_side
         d = ins[0]
         sign = jnp.where(d.mask, d.sign, 0)
         vals = tuple(c if jnp.issubdtype(c.dtype, jnp.floating)
                      else c.astype(jnp.int64) for c in d.cols)
-        state, needed = merge_side(state, d.pk, d.pk2, sign, vals)
+        with jax.named_scope("mv.apply"):      # HLO metadata only
+            state, needed = merge_side(state, d.pk, d.pk2, sign, vals)
         return state, None, [needed.astype(jnp.int64),
                              _nrows(sign != 0)], None
 
@@ -1752,10 +1864,17 @@ _CHAINABLE = (SourceNode, MapNode, FilterNode)
 _TIER_JITS: Dict[Any, Any] = {}
 
 
-def _tier_jit(name: str, fn, static=("node",)):
+def _tier_jit(name: Tuple[str, int], fn, static=("node",)):
+    """`fn` jitted once per (surgery, vmapped over shards?) as XLA
+    module `jit_tier_<surgery>[_sharded]`."""
     import jax
     if name not in _TIER_JITS:
-        _TIER_JITS[name] = jax.jit(fn, static_argnames=static)
+        def tier(*args, **statics):
+            return fn(*args, **statics)
+        op, vmapped = name
+        _TIER_JITS[name] = jax.jit(
+            _named(tier, f"tier_{op}" + ("_sharded" if vmapped else "")),
+            static_argnames=static)
     return _TIER_JITS[name]
 
 
@@ -1990,10 +2109,10 @@ class FusedProgram:
         # program. A cadence that does not divide the shard count is
         # fine: the tail event block pads (shard_exec.sharded_apply)
         self.mesh = mesh
-        # wall seconds the LAST epoch() spent dispatching exchange
-        # programs (the ICI shuffle stage) — FusedJob splits it out of
-        # the dispatch phase so ICI cost is attributable
-        self.last_exchange_s = 0.0
+        # each node's stable name (`rw:step` spans, module names) and
+        # its compile-event label
+        self.node_names = [n.stable_name() for n in self.nodes]
+        self._labels: Dict[int, str] = {}
         # vnode-block bounds the exchange routes by: None = the uniform
         # `vnode_block_bounds` layout; a rebalanced job carries the
         # custom bounds chosen at a checkpoint barrier. Routing-only
@@ -2051,56 +2170,61 @@ class FusedProgram:
         return node.cap_resize(state, caps)
 
     def _node_label(self, i: int) -> str:
-        """Compile-event label: program position + structural signature —
-        two programs sharing a node signature share its compile, and the
-        label makes that dedupe visible in the warmup decomposition."""
-        n = self.nodes[i]
-        return f"{i}:{type(n).__name__}:{hash(n) & 0xFFFFFFFF:08x}"
+        """Compile-event label `<position>:<stable name>:<digest of the
+        structural signature>` — two programs sharing a node signature
+        share its compile, and the label makes that dedupe visible in
+        the warmup decomposition. The same in every process (no
+        `hash()`), so compile events compare between runs."""
+        label = self._labels.get(i)
+        if label is None:
+            from .compile_service import _stable_digest
+            n = self.nodes[i]
+            sig = _stable_digest((type(n).__name__,) + n._sig())[:8]
+            label = self._labels[i] = f"{i}:{self.node_names[i]}:{sig}"
+        return label
 
     def epoch(self, states, event_lo, feeds=None):
-        """Host loop over per-node jitted steps: each call dispatches
+        """Host loop over per-node jitted steps -> (states', the epoch's
+        stat scalars): each call dispatches
         async; only device-array handles flow between nodes. With a live
-        profiler, each step is wall-timed: a step flagged as pending (cold
-        start / post-growth) or blocking past the compile threshold is
-        recorded as a compile/retrace event — dispatch is async, so a
-        blocking step call IS trace+compile time.
+        profiler, each step is a `rw:step` span: a step flagged as
+        pending (cold start / post-growth) or blocking past the compile
+        threshold is recorded as a compile/retrace event — dispatch is
+        async, so a blocking step call IS trace+compile time.
 
         `feeds` maps node index -> staged device feed for `takes_feed`
         (host-ingest) nodes; the owning FusedJob's HostIngest stager
         supplies one per dispatched epoch."""
         import jax.numpy as jnp
-        from ..utils.profile import COMPILE_THRESHOLD_S
-        import time as _time
         prof = self.profiler
-        if prof is not None and not prof.enabled:
+        if prof is None or not prof.enabled:
             prof = None
+        spans = prof or NULL_PROFILER
         svc = self.compile_service
         mesh = self.mesh
         outs: List[Optional[Delta]] = []
         auxes: List[Any] = []
         new_states = list(states)
         stats: List[Any] = []
-        exchange_s = 0.0
         for i, node in enumerate(self.nodes):
             ins = [outs[j] for j in node.inputs]
             exch_need = None
             if mesh is not None and node.exch is not None:
                 # in-program ICI shuffle: route each flagged input's rows
-                # to the shard owning their key's vnode block. Timed so
-                # the profiler can split "exchange" out of "dispatch"
-                # (dispatch is async — this wall is enqueue cost, the
-                # device-side ICI time lands in device_sync like all
-                # device compute)
+                # to the shard owning their key's vnode block. Its own
+                # span, so the profiler splits "exchange" out of
+                # "dispatch" (dispatch is async — this wall is enqueue
+                # cost, the device-side ICI time lands in device_sync
+                # like all device compute)
                 from .shard_exec import delta_sds, exchange_delta
-                t0x = _time.perf_counter()
-                for xi, ex in enumerate(node.shard_spec().exchanges):
-                    self._exch_sds[(i, xi)] = delta_sds(ins[ex.input])
-                    ins[ex.input], need = exchange_delta(
-                        mesh, node, xi, ins[ex.input],
-                        bounds=self.vnode_bounds)
-                    exch_need = need if exch_need is None \
-                        else jnp.maximum(exch_need, need)
-                exchange_s += _time.perf_counter() - t0x
+                with spans.span("rw:exchange"):
+                    for xi, ex in enumerate(node.shard_spec().exchanges):
+                        self._exch_sds[(i, xi)] = delta_sds(ins[ex.input])
+                        ins[ex.input], need = exchange_delta(
+                            mesh, node, xi, ins[ex.input],
+                            bounds=self.vnode_bounds)
+                        exch_need = need if exch_need is None \
+                            else jnp.maximum(exch_need, need)
             ins = tuple(ins)
             if node.takes_event_lo:
                 extra = jnp.int64(event_lo) if not hasattr(
@@ -2111,21 +2235,19 @@ class FusedProgram:
                 extra = auxes[node.inputs[0]]
             else:
                 extra = None
-            if prof is not None:
-                t0 = _time.perf_counter()
-            if svc is not None:
-                # compile-service path: ready executables dispatch with
-                # zero trace; a pending one is waited for (the service
-                # attributes the compile event, labeled, when it lands,
-                # and the wait to `await_s`)
-                kind = (self.profiler.pending_compile.pop(i, None)
-                        if self.profiler is not None else None)
-                st, out, s, aux = svc.node_step(
-                    node, self.epoch_events, states[i], ins, extra,
-                    label=self._node_label(i), job=self.job_name,
-                    profiler=prof, kind=kind, mesh=mesh)
-            else:
-                if mesh is not None:
+            with spans.span("rw:step", node=self.node_names[i], i=i) as sp:
+                if svc is not None:
+                    # compile-service path: ready executables dispatch
+                    # with zero trace; a pending one is waited for (the
+                    # service attributes the compile event, labeled,
+                    # when it lands, and the wait to `rw:compile_wait`)
+                    kind = (self.profiler.pending_compile.pop(i, None)
+                            if self.profiler is not None else None)
+                    st, out, s, aux = svc.node_step(
+                        node, self.epoch_events, states[i], ins, extra,
+                        label=self._node_label(i), job=self.job_name,
+                        profiler=prof, kind=kind, mesh=mesh)
+                elif mesh is not None:
                     from .shard_exec import sharded_node_step
                     st, out, s, aux = sharded_node_step(
                         mesh, node, self.epoch_events, states[i], ins,
@@ -2133,12 +2255,11 @@ class FusedProgram:
                 else:
                     st, out, s, aux = _node_step(node, self.epoch_events,
                                                  states[i], ins, extra)
-                if prof is not None:
-                    dt = _time.perf_counter() - t0
-                    kind = prof.pending_compile.pop(i, None)
-                    if kind is not None or dt > COMPILE_THRESHOLD_S:
-                        prof.compile_event(self._node_label(i), dt,
-                                           kind=kind or "retrace")
+            if svc is None and prof is not None:
+                kind = prof.pending_compile.pop(i, None)
+                if kind is not None or sp.seconds > COMPILE_THRESHOLD_S:
+                    prof.compile_event(self._node_label(i), sp.seconds,
+                                       kind=kind or "retrace")
             new_states[i] = st
             outs.append(out)
             auxes.append(aux)
@@ -2148,19 +2269,7 @@ class FusedProgram:
                 # the node's apply — splice it in here
                 s = list(s) + [exch_need]
             stats.extend(s)
-        self.last_exchange_s = exchange_s
-        # ONE jitted program stacks the stat scalars. The eager
-        # `jnp.stack` this replaces dispatched ~2 tiny programs PER
-        # SCALAR (expand_dims each, then concatenate) — on a sharded
-        # program those are dozens of per-epoch collective-bearing
-        # mini-programs whose rendezvous, in flight together with the
-        # node steps, can deadlock XLA:CPU's thread pool on small hosts
-        # (observed: skew-armed q5 at 8 virtual devices wedging in an
-        # AllReduce rendezvous); on any backend they are pure dispatch
-        # overhead
-        vec = _stack_stats(tuple(stats)) if stats \
-            else jnp.zeros((1,), jnp.int64)
-        return tuple(new_states), vec
+        return tuple(new_states), tuple(stats)
 
     def step_fn(self):
         """(states, event_lo, stats_acc) -> (states', combine(stats_acc,
@@ -2172,10 +2281,21 @@ class FusedProgram:
         sum_mask = jnp.asarray(self._sum_mask)
 
         def step(states, event_lo, stats_acc, feeds=None):
-            new_states, vec = self.epoch(states, event_lo, feeds=feeds)
-            # jitted fold (see the _stack_stats rationale): one program
-            # instead of three eager ops per epoch
-            acc = _fold_stats(vec, stats_acc, sum_mask)
+            new_states, stats = self.epoch(states, event_lo, feeds=feeds)
+            # ONE jitted program stacks the stat scalars and one folds
+            # them into the accumulator. The eager `jnp.stack` this
+            # replaces dispatched ~2 tiny programs PER SCALAR
+            # (expand_dims each, then concatenate) — on a sharded
+            # program those are dozens of per-epoch collective-bearing
+            # mini-programs whose rendezvous, in flight together with
+            # the node steps, can deadlock XLA:CPU's thread pool on
+            # small hosts (observed: skew-armed q5 at 8 virtual devices
+            # wedging in an AllReduce rendezvous); on any backend they
+            # are pure dispatch overhead
+            with (self.profiler or NULL_PROFILER).span("rw:stats_fold"):
+                vec = _stack_stats(stats) if stats \
+                    else jnp.zeros((1,), jnp.int64)
+                acc = _fold_stats(vec, stats_acc, sum_mask)
             return new_states, acc
 
         return step
@@ -2308,6 +2428,8 @@ class FusedJob:
         # source input is a pre-staged device buffer taken from it
         # instead of device-regenerated events; None = the datagen path
         self.ingest = ingest
+        if ingest is not None:
+            ingest.profiler = self.profiler     # the stager's spans
         # tiered state (device/tiering.py): per-node host cold stores +
         # demotion journal + Xor8 negative caches. Armed by the planner
         # (enable_tiering on the nodes, TierPlans derived from the
@@ -2451,7 +2573,8 @@ class FusedJob:
         if self.max_events is not None:
             planned = min(planned, max(0, self.max_events - self.counter))
         if prof is not None:
-            prof.begin_epoch(self.counter, planned or e)
+            prof.begin_epoch(self.counter, planned or e,
+                             epoch=barrier.epoch.curr)
         # fault-tolerance v3: a device-path failure anywhere in the
         # barrier's work (dispatch, sync, growth replay, commit — real
         # exception or armed fused.* failpoint) recovers IN PLACE and the
@@ -2505,56 +2628,49 @@ class FusedJob:
         import time as _time
         if failpoint("fused.dispatch"):
             raise FailpointError("fused.dispatch")
-        t0 = _time.perf_counter() if prof is not None else 0.0
+        spans = prof or NULL_PROFILER
         feeds = None
         events = self.program.epoch_events
-        h2d_s = 0.0
         ingest_ts = None
-        if self.ingest is not None:
-            # the staged window at the event counter: pre-packed,
-            # pre-transferred by the staging thread when the double
-            # buffer is warm — pack/h2d below then collapse to the lock
-            # wait, which is the whole point (the profiler's evidence
-            # surface for the overlap)
-            w, pack_s, h2d_s = self.ingest.take(self.counter)
-            if w.events <= 0:
-                if prof is not None:
-                    prof.phase("pack", _time.perf_counter() - t0)
-                return False
-            feeds, events, ingest_ts = w.feeds, w.events, w.ingest_ts
-        if self._window_ingest is None:
-            # first dispatch since the last checkpoint: freshness of the
-            # NEXT commit is measured against this moment — for ingest
-            # jobs the moment the window's rows came off the connector
-            self._window_ingest = ingest_ts if ingest_ts is not None \
-                else _time.time()
-        elif ingest_ts is not None:
-            self._window_ingest = min(self._window_ingest, ingest_ts)
-        lo = jnp.int64(self.counter)
-        if prof is not None:
-            t1 = _time.perf_counter()
-            prof.phase("pack", t1 - t0 - h2d_s)
-            if h2d_s > 0.0:
-                prof.phase("h2d", h2d_s)
-            t0 = t1
+        with spans.span("rw:pack") as pack:
+            if self.ingest is not None:
+                # the staged window at the event counter: pre-packed,
+                # pre-transferred by the staging thread when the double
+                # buffer is warm — pack/h2d then collapse to the lock
+                # wait, which is the whole point (the profiler's
+                # evidence surface for the overlap). The h2d wall is the
+                # stager's, handed over: it comes out of this span's
+                # seconds and goes to its own phase
+                w, _pack_s, h2d_s = self.ingest.take(self.counter)
+                if w.events <= 0:
+                    return False
+                if prof is not None and h2d_s > 0.0:
+                    pack.excluded += h2d_s
+                    prof.phase("h2d", h2d_s)
+                feeds, events, ingest_ts = w.feeds, w.events, w.ingest_ts
+            if self._window_ingest is None:
+                # first dispatch since the last checkpoint: freshness of
+                # the NEXT commit is measured against this moment — for
+                # ingest jobs the moment the window's rows came off the
+                # connector
+                self._window_ingest = ingest_ts if ingest_ts is not None \
+                    else _time.time()
+            elif ingest_ts is not None:
+                self._window_ingest = min(self._window_ingest, ingest_ts)
+            # a scalar host-to-device put: it blocks when the runtime's
+            # queue is full, so it has a span of its own
+            with spans.span("rw:event_lo"):
+                lo = jnp.int64(self.counter)
         if self.tiering is not None:
             # touch-promotion BEFORE the step: probe the window's keys
             # against the negative caches and restore any cold hits, so
             # the device step always sees a complete working set
             self._tier_promote(self.counter, events, prof)
-            if prof is not None:
-                t0 = _time.perf_counter()
-        self.states, self.stats_acc = self._step(
-            self.states, lo, self.stats_acc, feeds=feeds)
-        if prof is not None:
-            dt = _time.perf_counter() - t0
-            # the ICI shuffle's enqueue wall is its own phase so the
-            # exchange stage is attributable; it was measured inside
-            # the dispatch window, so subtract to keep phases disjoint
-            ex = min(self.program.last_exchange_s, dt)
-            if ex > 0.0:
-                prof.phase("exchange", ex)
-            prof.phase("dispatch", dt - ex)
+        # the ICI shuffle's enqueue wall is its own phase (`rw:exchange`
+        # inside, FusedProgram.epoch) and comes out of this one's seconds
+        with spans.span("rw:dispatch"):
+            self.states, self.stats_acc = self._step(
+                self.states, lo, self.stats_acc, feeds=feeds)
         self._epoch_log.append(self.counter, events)
         self.counter += events
         return True
@@ -2720,21 +2836,18 @@ class FusedJob:
         overflowed its static capacity. The blocking device_get is the
         epoch timeline's `device_sync` phase: it covers every epoch
         dispatched since the last sync (growth replays included)."""
-        import time as _time
-        prof = self.profiler if self.profiler.enabled else None
-        t_sync = _time.perf_counter() if prof is not None else 0.0
-        try:
+        with self.profiler.span("rw:device_sync"):
             self._sync_inner()
-        finally:
-            if prof is not None:
-                prof.phase("device_sync", _time.perf_counter() - t_sync)
 
     def _sync_inner(self) -> None:
         import jax
         while True:
             if failpoint("fused.device_sync"):
                 raise FailpointError("fused.device_sync")
-            vec = np.asarray(jax.device_get(self.stats_acc))
+            # the blocking call of a sync: every epoch enqueued since
+            # the last one has to finish before the vector is there
+            with self.profiler.span("rw:stats_pull"):
+                vec = np.asarray(jax.device_get(self.stats_acc))
             self._last_stats = vec
             for k, (ni, nm) in enumerate(self.program.stat_layout):
                 if nm == "packbad" and vec[k] != 0:
@@ -2768,42 +2881,55 @@ class FusedJob:
                 # executable instead of a retrace
                 self._prewarm_predicted(needs, needs_cum, needs_epoch)
                 return
-            targets = self._predict_caps(needs, needs_cum, needs_epoch)
-            snap_states, snap_counter = self.snapshot
-            new_states = []
-            for i, node in enumerate(self.program.nodes):
-                cur = node.cap_current()
-                want = targets.get(i) or {}
-                grown = {s: want[s] for s in want if want[s] > cur.get(s, 0)}
-                if grown:
-                    self.retraces += 1
-                    self.growths += len(grown)
-                    # the grown node's next step call re-traces: flag it so
-                    # the profiler attributes that wall to compile, not
-                    # steady-state dispatch
-                    self.profiler.pending_compile[i] = "retrace"
-                    new_states.append(self.program.resize_state(
-                        i, snap_states[i], grown))
-                else:
-                    new_states.append(snap_states[i])
-            self.growth_replays += 1
-            if failpoint("fused.growth_replay"):
-                raise FailpointError("fused.growth_replay")
-            target = self.counter
-            self.states = tuple(new_states)
-            self.snapshot = (self.states, snap_counter)
-            self.counter = snap_counter
-            self.stats_acc = self._zero_stats
-            if self.tiering is not None and self._cold_snapshot is not None:
-                # rewind the cold tier to the same commit point: window
-                # promotions popped rows out of the stores, and the
-                # replay below will promote them again. No journal
-                # re-enactment is due — demotions only happen at
-                # checkpoint commits, i.e. at snap_counter itself.
-                self.tiering.restore(self._cold_snapshot)
-            self._promo_need = {}
-            self._dispatch_range(snap_counter, target)
-            self.counter = target
+            with self.profiler.span("rw:growth") as growth:
+                self._grow_and_replay(
+                    self._predict_caps(needs, needs_cum, needs_epoch),
+                    growth)
+
+    def _grow_and_replay(self, targets, growth) -> None:
+        """The overflow branch of a sync: resize every node that has to
+        grow from the last snapshot and replay the window's epochs."""
+        snap_states, snap_counter = self.snapshot
+        new_states = []
+        grew = {}                      # node name -> (caps from, caps to)
+        for i, node in enumerate(self.program.nodes):
+            cur = node.cap_current()
+            want = targets.get(i) or {}
+            grown = {s: want[s] for s in want if want[s] > cur.get(s, 0)}
+            if grown:
+                self.retraces += 1
+                self.growths += len(grown)
+                grew[self.program.node_names[i]] = (
+                    {s: cur.get(s, 0) for s in grown}, grown)
+                # the grown node's next step call re-traces: flag it so
+                # the profiler attributes that wall to compile, not
+                # steady-state dispatch
+                self.profiler.pending_compile[i] = "retrace"
+                new_states.append(self.program.resize_state(
+                    i, snap_states[i], grown))
+            else:
+                new_states.append(snap_states[i])
+        growth.set(nodes=sorted(grew),
+                   **{"from": {n: f for n, (f, _t) in grew.items()},
+                      "to": {n: t for n, (_f, t) in grew.items()}})
+        self.growth_replays += 1
+        if failpoint("fused.growth_replay"):
+            raise FailpointError("fused.growth_replay")
+        target = self.counter
+        self.states = tuple(new_states)
+        self.snapshot = (self.states, snap_counter)
+        self.counter = snap_counter
+        self.stats_acc = self._zero_stats
+        if self.tiering is not None and self._cold_snapshot is not None:
+            # rewind the cold tier to the same commit point: window
+            # promotions popped rows out of the stores, and the
+            # replay below will promote them again. No journal
+            # re-enactment is due — demotions only happen at
+            # checkpoint commits, i.e. at snap_counter itself.
+            self.tiering.restore(self._cold_snapshot)
+        self._promo_need = {}
+        self._dispatch_range(snap_counter, target)
+        self.counter = target
 
     def _job_state_rows(self) -> List[Tuple[int, int]]:
         """Growth counters + per-node capacity high-water marks, in the
@@ -2882,33 +3008,30 @@ class FusedJob:
         bit-identical to the untiered run. Promotion is window-boundary
         independent (any window containing the key restores it first),
         so replays with a re-cut cadence stay exact."""
-        import time as _time
         tm = self.tiering
         if tm is None or self.ingest is None or not tm.any_cold():
             return
-        t0 = _time.perf_counter() if prof is not None else 0.0
-        per_source = None
-        for plan in tm.plans:
-            if not plan.recipes:
-                continue
-            if plan.kind == "agg":
-                if not len(tm.store(plan.node_idx, -1)):
+        with (prof or NULL_PROFILER).span("rw:promote_h2d"):
+            per_source = None
+            for plan in tm.plans:
+                if not plan.recipes:
                     continue
-            elif not len(tm.store(plan.node_idx, 0)) \
-                    and not len(tm.store(plan.node_idx, 1)):
-                continue
-            if per_source is None:
-                per_source = self.ingest.host_window(lo, events)
-            cand = np.unique(np.concatenate(
-                [r.keys_for(per_source) for r in plan.recipes]))
-            if not len(cand):
-                continue
-            if plan.kind == "agg":
-                self._promote_agg(plan, cand)
-            else:
-                self._promote_join(plan, cand)
-        if prof is not None:
-            prof.phase("promote_h2d", _time.perf_counter() - t0)
+                if plan.kind == "agg":
+                    if not len(tm.store(plan.node_idx, -1)):
+                        continue
+                elif not len(tm.store(plan.node_idx, 0)) \
+                        and not len(tm.store(plan.node_idx, 1)):
+                    continue
+                if per_source is None:
+                    per_source = self.ingest.host_window(lo, events)
+                cand = np.unique(np.concatenate(
+                    [r.keys_for(per_source) for r in plan.recipes]))
+                if not len(cand):
+                    continue
+                if plan.kind == "agg":
+                    self._promote_agg(plan, cand)
+                else:
+                    self._promote_join(plan, cand)
 
     def _promote_agg(self, plan, cand: np.ndarray) -> None:
         import jax
@@ -3042,76 +3165,75 @@ class FusedJob:
         dispatch), select + evict the cold keys it names, then ISSUE
         the next async pull for any node whose window residency
         high-water crossed the high-water fraction of capacity."""
-        import time as _time
         from .capacity import tier_waters
         from .skew_stats import SK_KEY_MASK, hot_key_set
         from .tiering import select_cold
         tm = self._tier_journal()
         if tm is None:
             return
-        t0 = _time.perf_counter() if prof is not None else 0.0
-        did = False
-        high, _low = tier_waters()
-        vec = np.maximum(self._stat_totals, self._last_stats) \
-            if len(self._stat_totals) == len(self._last_stats) \
-            else self._last_stats
-        for plan in tm.plans:
-            if not plan.recipes:
-                continue                   # demotion-inert (stats only)
-            i = plan.node_idx
-            node = self.program.nodes[i]
-            pend = tm.pending.pop(i, None)
-            if pend is not None:
-                did = True
-                hot = hot_key_set(self.program.node_stats(i, vec)) \
-                    if node.skew else ()
-                sel = []
-                if plan.kind == "agg":
-                    keys, touch, count = (self._lead(x) for x in pend)
-                    cap = keys.shape[1]
-                    for s in range(self.mesh_shards):
-                        d = select_cold(keys[s], touch[s],
-                                        int(count[s]), cap, hot,
-                                        SK_KEY_MASK)
-                        if d is not None:
-                            sel.append(d)
-                else:
-                    ka, ta, ca, kb, tb, cb = (self._lead(x)
-                                              for x in pend)
-                    for k, t, c in ((ka, ta, ca), (kb, tb, cb)):
-                        cap = k.shape[1]
+        with (prof or NULL_PROFILER).span("rw:demote_d2h") as sp:
+            did = False
+            high, _low = tier_waters()
+            vec = np.maximum(self._stat_totals, self._last_stats) \
+                if len(self._stat_totals) == len(self._last_stats) \
+                else self._last_stats
+            for plan in tm.plans:
+                if not plan.recipes:
+                    continue                   # demotion-inert (stats only)
+                i = plan.node_idx
+                node = self.program.nodes[i]
+                pend = tm.pending.pop(i, None)
+                if pend is not None:
+                    did = True
+                    hot = hot_key_set(self.program.node_stats(i, vec)) \
+                        if node.skew else ()
+                    sel = []
+                    if plan.kind == "agg":
+                        keys, touch, count = (self._lead(x) for x in pend)
+                        cap = keys.shape[1]
                         for s in range(self.mesh_shards):
-                            d = select_cold(k[s], t[s], int(c[s]), cap,
-                                            hot, SK_KEY_MASK)
+                            d = select_cold(keys[s], touch[s],
+                                            int(count[s]), cap, hot,
+                                            SK_KEY_MASK)
                             if d is not None:
                                 sel.append(d)
-                if sel:
-                    self._tier_demote_enact(
-                        plan, np.unique(np.concatenate(sel)),
-                        record=True)
-            # issue the NEXT pull when the window's residency
-            # high-water says pressure (stats already on host — the
-            # sync pulled them; no extra device round trip here, the
-            # copy below is async by construction)
-            st = self.program.node_stats(i, self._last_stats)
-            tres = int(st.get("tres", 0))
-            tstate = self.states[i]
-            if plan.kind == "agg":
-                pressure = tres > high * node.capacity
-                leaves = (tstate.inner.main.keys, tstate.touch,
-                          tstate.inner.main.count)
-            else:
-                pressure = tres > high * min(node.cap_a, node.cap_b)
-                a, b = tstate.inner
-                ta, tb = tstate.touch
-                leaves = (a.jk, ta, a.count, b.jk, tb, b.count)
-            if pressure:
-                did = True
-                for x in leaves:
-                    x.copy_to_host_async()
-                tm.pending[i] = leaves
-        if did and prof is not None:
-            prof.phase("demote_d2h", _time.perf_counter() - t0)
+                    else:
+                        ka, ta, ca, kb, tb, cb = (self._lead(x)
+                                                  for x in pend)
+                        for k, t, c in ((ka, ta, ca), (kb, tb, cb)):
+                            cap = k.shape[1]
+                            for s in range(self.mesh_shards):
+                                d = select_cold(k[s], t[s], int(c[s]), cap,
+                                                hot, SK_KEY_MASK)
+                                if d is not None:
+                                    sel.append(d)
+                    if sel:
+                        self._tier_demote_enact(
+                            plan, np.unique(np.concatenate(sel)),
+                            record=True)
+                # issue the NEXT pull when the window's residency
+                # high-water says pressure (stats already on host — the
+                # sync pulled them; no extra device round trip here, the
+                # copy below is async by construction)
+                st = self.program.node_stats(i, self._last_stats)
+                tres = int(st.get("tres", 0))
+                tstate = self.states[i]
+                if plan.kind == "agg":
+                    pressure = tres > high * node.capacity
+                    leaves = (tstate.inner.main.keys, tstate.touch,
+                              tstate.inner.main.count)
+                else:
+                    pressure = tres > high * min(node.cap_a, node.cap_b)
+                    a, b = tstate.inner
+                    ta, tb = tstate.touch
+                    leaves = (a.jk, ta, a.count, b.jk, tb, b.count)
+                if pressure:
+                    did = True
+                    for x in leaves:
+                        x.copy_to_host_async()
+                    tm.pending[i] = leaves
+            if not did:
+                sp.no_phase()      # nothing harvested, nothing issued
 
     def _tier_demote_enact(self, plan, keys: np.ndarray,
                            record: bool) -> None:
@@ -3297,32 +3419,37 @@ class FusedJob:
         # profiler off
         self._accum_totals(self._last_stats)
         prof = self.profiler if self.profiler.enabled else None
-        if prof is not None:
-            t0 = _time.perf_counter()
-        due = self.counter != self._last_persist and (
-            self.drained
-            or self.counter - max(0, self._last_persist)
-            >= self.persist_every * self.program.epoch_events)
-        if due:
-            self._persist_mv(epoch)
-            self._last_persist = self.counter
-        if failpoint("fused.checkpoint_commit"):
-            raise FailpointError("fused.checkpoint_commit")
-        if self.job_state_table is not None:
-            dirty = False
-            if self.committed != self.counter or self.committed == 0:
-                self.job_state_table.insert((_JS_COUNTER, self.counter))
-                dirty = True
-            for k, v in self._job_state_rows():
-                if self._js_written.get(k) != v:
-                    self.job_state_table.insert((k, v))
-                    self._js_written[k] = v
-                    dirty = True
-            if dirty:
-                self.job_state_table.commit(epoch)
-        if prof is not None:
-            self._export_hbm_gauges()
-            prof.phase("commit", _time.perf_counter() - t0)
+        spans = prof or NULL_PROFILER
+        # the end of this span is when events [seq_from, seq_to) became
+        # durable
+        with spans.span("rw:commit", seq_from=self.committed,
+                        seq_to=self.counter):
+            due = self.counter != self._last_persist and (
+                self.drained
+                or self.counter - max(0, self._last_persist)
+                >= self.persist_every * self.program.epoch_events)
+            if due:
+                self._persist_mv(epoch)
+                self._last_persist = self.counter
+            if failpoint("fused.checkpoint_commit"):
+                raise FailpointError("fused.checkpoint_commit")
+            if self.job_state_table is not None:
+                with spans.span("rw:commit.job_state"):
+                    dirty = False
+                    if self.committed != self.counter or self.committed == 0:
+                        self.job_state_table.insert(
+                            (_JS_COUNTER, self.counter))
+                        dirty = True
+                    for k, v in self._job_state_rows():
+                        if self._js_written.get(k) != v:
+                            self.job_state_table.insert((k, v))
+                            self._js_written[k] = v
+                            dirty = True
+                    if dirty:
+                        self.job_state_table.commit(epoch)
+            if prof is not None:
+                with prof.span("rw:commit.gauges"):
+                    self._export_hbm_gauges()
         if self.freshness is not None and self._window_ingest is not None:
             # end-to-end staleness of this commit: the oldest epoch in
             # the checkpoint window was dispatched (= its events came
@@ -3482,15 +3609,21 @@ class FusedJob:
         non-device readers + the recovery contract's committed view)."""
         if self.mv_state_table is None:
             return
-        rows = {r: None for r in self._pull_rows()}
-        for r in self._persisted:
-            if r not in rows:
-                self.mv_state_table.delete(r)
-        for r in rows:
-            if r not in self._persisted:
-                self.mv_state_table.insert(r)
-        self._persisted = rows
-        self.mv_state_table.commit(epoch)
+        span = self.profiler.span
+        with span("rw:commit.mirror") as mirror:
+            with span("rw:commit.mirror.pull"):
+                rows = {r: None for r in self._pull_rows()}
+            mirror.set(rows=len(rows))
+            with span("rw:commit.mirror.diff"):
+                for r in self._persisted:
+                    if r not in rows:
+                        self.mv_state_table.delete(r)
+                for r in rows:
+                    if r not in self._persisted:
+                        self.mv_state_table.insert(r)
+                self._persisted = rows
+            with span("rw:commit.mirror.table_commit"):
+                self.mv_state_table.commit(epoch)
 
     # ---- recovery -------------------------------------------------------
     def recover(self) -> None:

@@ -217,6 +217,11 @@ class HostIngest:
         self._staged: Optional[StagedWindow] = None
         self._inflight_lo: Optional[int] = None
         self._thread: Optional[threading.Thread] = None
+        # the owning FusedJob's profiler (it sets this): the stager's
+        # work as `rw:ingest.poll` / `.pack` / `.h2d` spans, the dispatch
+        # thread's wait for it as `rw:ingest.wait`
+        from ..utils.profile import NULL_PROFILER
+        self.profiler = NULL_PROFILER
         self._stop = False
         # cost accounting (bench/tests): total staging walls wherever
         # they ran, split by whether the dispatch thread had to wait
@@ -305,9 +310,19 @@ class HostIngest:
             return self._pack_feeds_locked(lo, events, per_source)
 
     def _pack_feeds_locked(self, lo: int, events: int, per_source):
+        span = self.profiler.span
+        with span("rw:ingest.pack", window=lo):
+            t0 = time.perf_counter()
+            host = self._pack_host(lo, per_source)
+            t1 = time.perf_counter()
+        with span("rw:ingest.h2d", window=lo):
+            feeds = self._put_feeds(host)
+            t2 = time.perf_counter()
+        return feeds, t1 - t0, t2 - t1
+
+    def _pack_host(self, lo: int, per_source) -> Dict[int, Tuple]:
+        """The window's rows in the next staging-buffer set."""
         import jax
-        import jax.numpy as jnp
-        t0 = time.perf_counter()
         if self._host_copy is None:
             self._host_copy = jax.default_backend() == "cpu"
         if self._host_copy:
@@ -355,7 +370,12 @@ class HostIngest:
                     for cb, c in zip(col_bufs, cols):
                         cb[s, :k] = c[a:b_]
             host[idx] = (counts, pk_buf, col_bufs)
-        t1 = time.perf_counter()
+        return host
+
+    def _put_feeds(self, host: Dict[int, Tuple]) -> Dict[int, Tuple]:
+        """The staging buffers on the device, transfer done."""
+        import jax
+        import jax.numpy as jnp
         feeds: Dict[int, Tuple] = {}
         if self.mesh is not None:
             from ..parallel.mesh import state_sharding
@@ -374,8 +394,7 @@ class HostIngest:
         # dispatch — this wall IS the measured h2d phase.
         for f in feeds.values():
             jax.block_until_ready(f)
-        t2 = time.perf_counter()
-        return feeds, t1 - t0, t2 - t1
+        return feeds
 
     def _stage(self, lo: int, prefetched: bool) -> StagedWindow:
         """Poll + pack + transfer one window at `lo` (any thread).
@@ -391,13 +410,14 @@ class HostIngest:
             return StagedWindow(lo, 0, {}, None, 0.0, 0.0, prefetched)
         ingest_ts = time.time()
         per_source = []
-        for idx, src in self.sources:
-            ids, cols = src.rows_for(lo, lo + events)
-            per_source.append((ids, cols))
-            b = self.buckets.get(src.name)
-            if b is not None:
-                b.note_admitted(len(ids))
-            self.source_rows[src.name] += len(ids)
+        with self.profiler.span("rw:ingest.poll", window=lo):
+            for idx, src in self.sources:
+                ids, cols = src.rows_for(lo, lo + events)
+                per_source.append((ids, cols))
+                b = self.buckets.get(src.name)
+                if b is not None:
+                    b.note_admitted(len(ids))
+                self.source_rows[src.name] += len(ids)
         feeds, pack_s, h2d_s = self._pack_feeds(lo, events, per_source)
         self._retained[lo] = (events, per_source, ingest_ts)
         self.stat["windows"] += 1
@@ -418,11 +438,14 @@ class HostIngest:
         next window before returning."""
         t0 = time.perf_counter()
         w: Optional[StagedWindow] = None
-        with self._cv:
-            while self._inflight_lo == lo:
-                self._cv.wait(0.05)
-            if self._staged is not None and self._staged.lo == lo:
-                w, self._staged = self._staged, None
+        # the dispatch thread blocked on the stager: on a busy device
+        # this is where a host-fed job's `pack` phase goes
+        with self.profiler.span("rw:ingest.wait", window=lo):
+            with self._cv:
+                while self._inflight_lo == lo:
+                    self._cv.wait(0.05)
+                if self._staged is not None and self._staged.lo == lo:
+                    w, self._staged = self._staged, None
         wait_s = time.perf_counter() - t0
         pack_s = wait_s
         h2d_s = 0.0

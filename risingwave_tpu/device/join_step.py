@@ -145,32 +145,39 @@ def join_core(a: JoinSide, b: JoinSide,
     plus both sides' pks so a payload-free (SQL) run can materialize rows
     host-side.
     """
-    dajk, dapk, dasign, davals = batch_reduce_rows(a_jk, a_pk, a_sign,
-                                                   a_mask, a_vals)
-    dbjk, dbpk, dbsign, dbvals = batch_reduce_rows(b_jk, b_pk, b_sign,
-                                                   b_mask, b_vals)
+    # (named scopes: HLO metadata only, see sorted_state.merge)
+    with jax.named_scope("join.reduce_delta"):
+        dajk, dapk, dasign, davals = batch_reduce_rows(a_jk, a_pk, a_sign,
+                                                       a_mask, a_vals)
+        dbjk, dbpk, dbsign, dbvals = batch_reduce_rows(b_jk, b_pk, b_sign,
+                                                       b_mask, b_vals)
     # dA >< B_old
-    r1, s1, m1, need1 = probe(b, dajk, dasign != 0, m)
-    out1 = {
-        "sign": jnp.where(m1, dasign[r1], 0),
-        "jk": dajk[r1],
-        "a_pk": dapk[r1], "b_pk": b.pk[s1],
-        "a_vals": tuple(v[r1] for v in davals),
-        "b_vals": tuple(v[s1] for v in b.vals),
-        "mask": m1,
-    }
-    new_a, needed_a = merge_side(a, dajk, dapk, dasign, davals)
-    new_b, needed_b = merge_side(b, dbjk, dbpk, dbsign, dbvals)
+    with jax.named_scope("join.probe"):
+        r1, s1, m1, need1 = probe(b, dajk, dasign != 0, m)
+    with jax.named_scope("join.emit"):
+        out1 = {
+            "sign": jnp.where(m1, dasign[r1], 0),
+            "jk": dajk[r1],
+            "a_pk": dapk[r1], "b_pk": b.pk[s1],
+            "a_vals": tuple(v[r1] for v in davals),
+            "b_vals": tuple(v[s1] for v in b.vals),
+            "mask": m1,
+        }
+    with jax.named_scope("join.merge"):
+        new_a, needed_a = merge_side(a, dajk, dapk, dasign, davals)
+        new_b, needed_b = merge_side(b, dbjk, dbpk, dbsign, dbvals)
     # A_new >< dB
-    r2, s2, m2, need2 = probe(new_a, dbjk, dbsign != 0, m)
-    out2 = {
-        "sign": jnp.where(m2, dbsign[r2], 0),
-        "jk": dbjk[r2],
-        "a_pk": new_a.pk[s2], "b_pk": dbpk[r2],
-        "a_vals": tuple(v[s2] for v in new_a.vals),
-        "b_vals": tuple(v[r2] for v in dbvals),
-        "mask": m2,
-    }
+    with jax.named_scope("join.probe"):
+        r2, s2, m2, need2 = probe(new_a, dbjk, dbsign != 0, m)
+    with jax.named_scope("join.emit"):
+        out2 = {
+            "sign": jnp.where(m2, dbsign[r2], 0),
+            "jk": dbjk[r2],
+            "a_pk": new_a.pk[s2], "b_pk": dbpk[r2],
+            "a_vals": tuple(v[s2] for v in new_a.vals),
+            "b_vals": tuple(v[r2] for v in dbvals),
+            "mask": m2,
+        }
     needed = {"a": needed_a, "b": needed_b,
               "pairs": jnp.maximum(need1, need2)}
     return new_a, new_b, out1, out2, needed

@@ -46,13 +46,15 @@ def mv_apply_changes(state: SortedState, keys: jax.Array,
     no-ops (key forced to EMPTY so they drop out of the merge).
     """
     kinds = mv_kinds(len(cols))
-    touched = upsert | delete
-    dkeys = jnp.where(touched, keys, EMPTY_KEY)
-    live = upsert.astype(jnp.int32)  # delete -> 0 -> compacted away
-    dvals = [live]
-    for c, nl in zip(cols, nulls):
-        dvals += [c.astype(state.vals[len(dvals)].dtype), nl]
-    return merge(state, dkeys, dvals, kinds, drop_dead=True, dead_col=0)
+    with jax.named_scope("mv.apply"):
+        touched = upsert | delete
+        dkeys = jnp.where(touched, keys, EMPTY_KEY)
+        live = upsert.astype(jnp.int32)  # delete -> 0 -> compacted away
+        dvals = [live]
+        for c, nl in zip(cols, nulls):
+            dvals += [c.astype(state.vals[len(dvals)].dtype), nl]
+        return merge(state, dkeys, dvals, kinds, drop_dead=True,
+                     dead_col=0)
 
 
 def mv_rows(state: SortedState, col_dtypes: Sequence) -> Tuple[np.ndarray, ...]:

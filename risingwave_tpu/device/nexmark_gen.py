@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Dict, NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -155,6 +156,11 @@ def gen_table(cfg: GenCfg, table: str, event_ids) -> Dict[str, jnp.ndarray]:
     with `event_kinds(ids) == kind`. String columns are surrogates (see
     SURROGATE) decoded host-side by `decode_column`.
     """
+    with jax.named_scope("source.gen"):    # HLO metadata only
+        return _gen_table(cfg, table, event_ids)
+
+
+def _gen_table(cfg: GenCfg, table: str, event_ids) -> Dict[str, jnp.ndarray]:
     ts = _timestamps(cfg, event_ids)
     if table == "person":
         ids = (FIRST_PERSON_ID + _person_count_before(event_ids)

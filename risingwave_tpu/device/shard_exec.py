@@ -343,17 +343,22 @@ def _exch_key(mesh, node, xi: int, salt, delta_tree) -> Tuple:
             avals, str(treedef))
 
 
-def _exchange_jit(mesh):
+def _exchange_jit(mesh, node):
+    """The jitted exchange stage of `node` over `mesh`: XLA module
+    `jit_exchange_<node.stable_name()>` (one function per mesh and
+    node name, as `fused._jit_step`)."""
     import jax
-    fn = _EXCH_JIT.get(mesh)
+    from .fused import _named
+    name = node.stable_name()
+    fn = _EXCH_JIT.get((mesh, name))
     if fn is None:
-        fn = jax.jit(
-            lambda delta, *, node, xi, salt, bounds, hot_keys, hot_side:
-            exchange_apply(mesh, node, xi, delta, bounds=bounds,
-                           hot_keys=hot_keys, hot_side=hot_side),
+        def exchange(delta, *, node, xi, salt, bounds, hot_keys, hot_side):
+            return exchange_apply(mesh, node, xi, delta, bounds=bounds,
+                                  hot_keys=hot_keys, hot_side=hot_side)
+        fn = _EXCH_JIT[(mesh, name)] = jax.jit(
+            _named(exchange, f"exchange_{name}"),
             static_argnames=("node", "xi", "salt", "bounds", "hot_keys",
                              "hot_side"))
-        _EXCH_JIT[mesh] = fn
     return fn
 
 
@@ -378,10 +383,10 @@ def exchange_delta(mesh, node, xi: int, delta,
         EXCH_STATS["aot_hits"] += 1
         return compiled(delta)
     _EXCH_INLINE.add(key)
-    return _exchange_jit(mesh)(delta, node=node, xi=xi,
-                               salt=node._mut_sig(), bounds=bounds,
-                               hot_keys=node.hot_keys,
-                               hot_side=node.hot_rep_side)
+    return _exchange_jit(mesh, node)(delta, node=node, xi=xi,
+                                     salt=node._mut_sig(), bounds=bounds,
+                                     hot_keys=node.hot_keys,
+                                     hot_side=node.hot_rep_side)
 
 
 def prewarm_exchange(mesh, node, xi: int, sds_delta,
@@ -398,7 +403,7 @@ def prewarm_exchange(mesh, node, xi: int, sds_delta,
     key = _exch_key(mesh, node, xi, salt, sds_delta)
     if key in _EXCH_AOT:
         return
-    fn = _exchange_jit(mesh)
+    fn = _exchange_jit(mesh, node)
     lowered = fn.lower(sds_delta, node=node, xi=xi, salt=node._mut_sig(),
                        bounds=bounds, hot_keys=tuple(hot_keys),
                        hot_side=int(hot_rep_side))
@@ -539,26 +544,30 @@ def sharded_apply(mesh, node, epoch_events: int, state, ins, extra,
 _STEP_JIT = {}
 
 
-def sharded_jit_step(mesh):
-    """The shared jitted sharded per-node step, one per mesh (the exact
-    analog of fused._jit_step): the compile service AOT-lowers through
-    the SAME function, so inline dispatch and background
-    `.lower().compile()` of one signature share a trace."""
+def sharded_jit_step(mesh, node):
+    """The jitted sharded per-node step, one per mesh and node name (the
+    exact analog of fused._jit_step, XLA module `jit_step_<name>` too):
+    the compile service AOT-lowers through the SAME function, so inline
+    dispatch and background `.lower().compile()` of one signature share
+    a trace."""
     import jax
-    fn = _STEP_JIT.get(mesh)
+    from .fused import _named
+    name = node.stable_name()
+    fn = _STEP_JIT.get((mesh, name))
     if fn is None:
-        fn = jax.jit(
-            lambda state, ins, extra, *, node, epoch_events, salt:
-            sharded_apply(mesh, node, epoch_events, state, ins, extra),
+        def step(state, ins, extra, *, node, epoch_events, salt):
+            return sharded_apply(mesh, node, epoch_events, state, ins,
+                                 extra)
+        fn = _STEP_JIT[(mesh, name)] = jax.jit(
+            _named(step, f"step_{name}"),
             static_argnames=("node", "epoch_events", "salt"))
-        _STEP_JIT[mesh] = fn
     return fn
 
 
 def sharded_node_step(mesh, node, epoch_events: int, state, ins, extra):
-    return sharded_jit_step(mesh)(state, ins, extra, node=node,
-                                  epoch_events=epoch_events,
-                                  salt=node._mut_sig())
+    return sharded_jit_step(mesh, node)(state, ins, extra, node=node,
+                                        epoch_events=epoch_events,
+                                        salt=node._mut_sig())
 
 
 # ---------------------------------------------------------------------------
@@ -665,7 +674,8 @@ def _gather_jit(mesh, kind: str, nc: int, m: int):
                 [jk, pk], [v.reshape(-1) for v in side.vals])
             return (jnp.sum(side.count), [v[:m] for v in vals])
 
-    fn = jax.jit(gather, out_shardings=rep)
+    from .fused import _named
+    fn = jax.jit(_named(gather, f"gather_{kind}"), out_shardings=rep)
     _GATHER_JIT[key] = fn
     return fn
 

@@ -241,10 +241,13 @@ def merge(state: SortedState, dkeys: jax.Array,
     truncated and must be retried on a grown state.
     """
     c = state.capacity
-    keys = jnp.concatenate([state.keys, dkeys])
-    vals = [jnp.concatenate([sv, dv.astype(sv.dtype)])
-            for sv, dv in zip(state.vals, dvals)]
-    (keys,), vals = sort_cols([keys], vals)
+    # named scopes are HLO metadata only: they put these stages' device
+    # time under a name in a profiler trace and change no instruction
+    with jax.named_scope("merge.sort"):
+        keys = jnp.concatenate([state.keys, dkeys])
+        vals = [jnp.concatenate([sv, dv.astype(sv.dtype)])
+                for sv, dv in zip(state.vals, dvals)]
+        (keys,), vals = sort_cols([keys], vals)
     same_next = jnp.concatenate([keys[:-1] == keys[1:], jnp.zeros((1,), bool)])
     same_prev = jnp.concatenate([jnp.zeros((1,), bool), keys[1:] == keys[:-1]])
     merged = []
@@ -255,9 +258,10 @@ def merge(state: SortedState, dkeys: jax.Array,
     if drop_dead:
         alive &= merged[dead_col] != 0
     needed = jnp.sum(alive).astype(jnp.int32)
-    out = compact_rows(alive, [keys], merged, c,
-                       [EMPTY_KEY] + [_neutral(k, v.dtype)
-                                      for v, k in zip(merged, kinds)])
+    with jax.named_scope("merge.compact"):
+        out = compact_rows(alive, [keys], merged, c,
+                           [EMPTY_KEY] + [_neutral(k, v.dtype)
+                                          for v, k in zip(merged, kinds)])
     new_count = jnp.minimum(needed, c)
     return SortedState(out[0], new_count, tuple(out[1:])), needed
 
@@ -266,7 +270,8 @@ def lookup(state: SortedState, qkeys: jax.Array
            ) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
     """Binary-search gather. Returns (found[B], vals at match — neutral-ish
     garbage where not found; gate on `found`)."""
-    idx = jnp.searchsorted(state.keys, qkeys, method=search_method())
-    idx = jnp.minimum(idx, state.capacity - 1)
-    found = (state.keys[idx] == qkeys) & (qkeys != EMPTY_KEY)
-    return found, tuple(v[idx] for v in state.vals)
+    with jax.named_scope("lookup"):
+        idx = jnp.searchsorted(state.keys, qkeys, method=search_method())
+        idx = jnp.minimum(idx, state.capacity - 1)
+        found = (state.keys[idx] == qkeys) & (qkeys != EMPTY_KEY)
+        return found, tuple(v[idx] for v in state.vals)

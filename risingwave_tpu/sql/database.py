@@ -99,6 +99,17 @@ class _Backfill(Executor):
         yield from self.port.execute()
 
 
+def _stmt_kind(stmt: Any) -> str:
+    """`create_source`, `create_mv`, `select`, ...: the statement's class
+    in snake case, for the `kind` of its `rw:sql` span."""
+    import re
+    if isinstance(stmt, A.CreateTable) and stmt.is_source:
+        return "create_source"
+    if isinstance(stmt, A.CreateMaterializedView):
+        return "create_mv"
+    return re.sub(r"(?<!^)(?=[A-Z])", "_", type(stmt).__name__).lower()
+
+
 def _walk_executors(root) -> Iterator[Any]:
     """Walk an executor tree through the common child attributes.
     `pumps` descends through a Merge's upstream dispatchers into NESTED
@@ -167,8 +178,13 @@ class Database:
         # per-barrier span tree (inject -> per-job collect -> commit),
         # ring-buffered for rw_barrier_trace and file-logged in the data
         # dir for offline hang diagnosis (risectl trace)
+        from ..utils.profile import spans
         from ..utils.trace import BarrierTracer
-        self.tracer = BarrierTracer(data_dir)
+        # spans of no particular job (`rw:barrier`, `rw:store_commit`,
+        # `rw:sql`, utils/profile.py) ride DeviceConfig.profile as the
+        # jobs' own do
+        self._span = spans(self.device is not None and self.device.profile)
+        self.tracer = BarrierTracer(data_dir, span=self._span)
         # flight recorder (utils/blackbox.py): point the process-wide
         # telemetry ring's on-disk mirror at this data dir so a crash or
         # wedge leaves its last seconds readable by `risectl blackbox`
@@ -344,7 +360,8 @@ class Database:
         from .parser import parse_sql_with_text
         out = []
         for stmt, text in parse_sql_with_text(sql):
-            result = self._execute(stmt)
+            with self._span("rw:sql", kind=_stmt_kind(stmt)):
+                result = self._execute(stmt)
             if isinstance(stmt, (A.CreateTable, A.CreateMaterializedView,
                                  A.CreateSink, A.DropObject, A.CreateIndex,
                                  A.AlterParallelism, A.CreateFunction)) \
@@ -374,7 +391,8 @@ class Database:
         """Run a single SELECT and return rows."""
         stmts = parse_sql(sql)
         assert len(stmts) == 1 and isinstance(stmts[0], (A.Select, A.SetOp))
-        return self._run_batch_select(stmts[0])
+        with self._span("rw:sql", kind="select"):
+            return self._run_batch_select(stmts[0])
 
     def _execute(self, stmt: Any) -> Any:
         if isinstance(stmt, A.CreateTable):
@@ -715,10 +733,11 @@ class Database:
         # device-resident state; the per-operator host DAG is dropped
         if self.device is not None and self.device.fuse:
             from ..device.fuse_planner import try_fuse
-            job = try_fuse(execu, ns, self.device, stmt.name,
-                           mv_state_table=mv_table,
-                           make_state=self._make_state,
-                           cap_registry=self._fused_cap_hw)
+            with self._span("rw:sql.fuse_plan"):
+                job = try_fuse(execu, ns, self.device, stmt.name,
+                               mv_state_table=mv_table,
+                               make_state=self._make_state,
+                               cap_registry=self._fused_cap_hw)
             if job is not None:
                 for shared, port in self._pending_subs:
                     shared.unsubscribe(port)
@@ -1350,7 +1369,8 @@ class Database:
         # runs under the decided state (cadence stretch, throttling)
         self._overload.tick(self)
         b = self.injector.inject()
-        span = self.tracer.inject(b.epoch.curr, b.kind.value)
+        span = self.tracer.inject(b.epoch.curr, b.kind.value,
+                                  b.is_checkpoint)
         # fused device jobs first: their epoch dispatch is ASYNC (no device
         # sync), so host executors below overlap with device compute
         for jname, job in self._fused.items():
@@ -1370,7 +1390,8 @@ class Database:
             self._window_ingest = b_ing if self._window_ingest is None \
                 else min(self._window_ingest, b_ing)
         if b.is_checkpoint:
-            self.store.commit_epoch(b.epoch.curr)
+            with self._span("rw:store_commit", epoch=b.epoch.curr):
+                self.store.commit_epoch(b.epoch.curr)
             self.epoch_committed = b.epoch.curr
             # post-checkpoint sink-committer step: the epoch's log entries
             # are durable now, so external delivery can go out

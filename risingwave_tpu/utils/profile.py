@@ -42,12 +42,40 @@ is decomposable from steady state.
 Records land in a memory ring (the `rw_epoch_profile` system table) AND —
 when a data directory is attached — in `epoch_profile.jsonl`, appended at
 checkpoints so `risectl profile` works offline against any data dir, the
-same contract as `barrier_trace.jsonl`. Overhead when enabled is a few
-`perf_counter` calls per epoch plus two per node; `DeviceConfig.profile=
-False` removes even that.
+same contract as `barrier_trace.jsonl`.
+
+The phases are SPANS (`JobProfiler.span`, `span`): name, start and end on
+`time.perf_counter_ns`, the innermost open span of the same thread as
+parent, the thread, and the identifiers that tie one barrier's work
+together (`job`, job instance `inst`, barrier `epoch`, event `seq`).
+Every span name starts with `rw:` (the benchmark's trace reduction
+charges idle gaps to host events named `tick:`, `sync…` or `window`; a
+program span under one of those names would change its numbers). A span
+also enters a `jax.profiler.TraceAnnotation` of its name, so under a
+profiler session it lands on the host plane of the same `.xplane.pb`, on
+the device trace's clock. Finished spans go to `SPANS`, one bounded
+process-global ring beside `blackbox.RECORDER` and `metrics.REGISTRY`:
+they outlive the job that made them. A span named `rw:<phase>` feeds the
+phase totals when it closes, so `phase_s`, `rw_epoch_profile`,
+`epoch_profile.jsonl` and `risectl profile` read what they always read;
+what is finer sits inside the phases as child spans:
+
+  rw:barrier > rw:store_commit | rw:epoch > rw:<phase>
+  rw:pack > rw:event_lo            rw:dispatch > rw:step > rw:compile_wait
+  rw:dispatch > rw:stats_fold      rw:device_sync > rw:stats_pull | rw:growth
+  rw:commit > rw:commit.mirror > .pull | .diff | .table_commit
+  rw:commit > rw:commit.job_state | rw:commit.gauges
+  rw:compile (worker thread)       rw:ingest.poll | .pack | .h2d (stager)
+  rw:pack > rw:ingest.wait (the dispatch thread blocked on the stager)
+  rw:sql > rw:sql.fuse_plan
+
+Overhead when enabled is two clock reads, one annotation and one ring
+append per span, a few dozen spans per barrier; `DeviceConfig.profile=
+False` hands out one shared null span and records nothing.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -55,14 +83,12 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from .blackbox import RECORDER
+
 PROFILE_FILE = "epoch_profile.jsonl"
 _MAX_FILE_BYTES = 4 << 20
-# record schema version stamped on every epoch record. Readers dispatch
-# on it (`decode_epoch`) instead of sniffing individual fields:
-#   1 (implicit — records with no `schema` field): pre-pack/h2d-split
-#     releases; `host_pack` held the combined staging wall and `shards`
-#     may be absent.
-#   2: current shape (pack/h2d split, `shards` always present).
+# record schema version stamped on every epoch record; readers normalize
+# through `decode_epoch`, so a format change is one branch on the version
 PROFILE_SCHEMA = 2
 PHASES = ("pack", "h2d", "promote_h2d", "dispatch", "exchange",
           "device_sync", "demote_d2h", "commit")
@@ -71,6 +97,146 @@ PHASES = ("pack", "h2d", "promote_h2d", "dispatch", "exchange",
 # arrived through a path growth accounting doesn't flag)
 COMPILE_THRESHOLD_S = 0.25
 RING = 512
+
+# ---------------------------------------------------------------------------
+# spans
+# ---------------------------------------------------------------------------
+
+SPAN_PREFIX = "rw:"
+# finished spans of every job of the process, oldest first (q7 makes ~40
+# a barrier): plain dicts with id, parent, name, t0, t1 (perf_counter_ns),
+# thread, tname, the identifiers in ID_KEYS where known, and the span's
+# own attributes
+SPAN_RING = 16384
+SPANS: deque = deque(maxlen=SPAN_RING)
+# identifiers a child span inherits from its parent
+ID_KEYS = ("job", "inst", "epoch", "seq")
+_OPEN = threading.local()          # .stack: this thread's open spans
+_SPAN_IDS = itertools.count(1)
+_INSTANCES = itertools.count(1)    # one number per JobProfiler
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
+
+
+def _open_stack() -> List["Span"]:
+    try:
+        return _OPEN.stack
+    except AttributeError:
+        _OPEN.stack = []
+        return _OPEN.stack
+
+
+class Span:
+    """One timed region; a context manager. `record=False` only times
+    (no ring record, no annotation, not a parent): what a caller that
+    needs the seconds uses when its job's profiler is off."""
+
+    __slots__ = ("name", "ids", "attrs", "owner", "record", "id", "parent",
+                 "t0", "t1", "excluded", "_ann")
+
+    def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None,
+                 owner: Optional["JobProfiler"] = None, record: bool = True):
+        if not name.startswith(SPAN_PREFIX):
+            raise ValueError(f"span name {name!r} does not start with "
+                             f"{SPAN_PREFIX!r}")
+        self.name = name
+        self.attrs = attrs or {}
+        self.ids = {k: self.attrs.pop(k) for k in ID_KEYS if k in self.attrs}
+        self.owner = owner
+        self.record = record
+        self.id = self.parent = None
+        self.t0 = self.t1 = 0
+        # seconds inside this span that were handed to ANOTHER phase
+        self.excluded = 0.0
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is done (`rows`)."""
+        self.attrs.update(attrs)
+
+    def no_phase(self) -> None:
+        """This span found nothing to do: it stays a span and feeds no
+        phase."""
+        self.owner = None
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1 - self.t0) / 1e9
+
+    def __enter__(self) -> "Span":
+        if self.record:
+            stack = _open_stack()
+            if stack:
+                self.parent = stack[-1].id
+                self.ids = {**stack[-1].ids, **self.ids}
+            self.id = next(_SPAN_IDS)
+            stack.append(self)
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter_ns()
+        stack = _open_stack()
+        if not self.record or self not in stack:
+            return False      # not recorded, or gone with its parent
+        # spans an exception left open above this one go with it
+        while True:
+            top = stack.pop()
+            top._ann.__exit__(*exc)
+            if top is self:
+                break
+        t = threading.current_thread()
+        SPANS.append({"id": self.id, "parent": self.parent,
+                      "name": self.name, "t0": self.t0, "t1": self.t1,
+                      "thread": t.ident, "tname": t.name,
+                      **self.ids, **self.attrs})
+        if self.owner is not None:
+            self.owner._span_closed(self, stack)
+        return False
+
+
+class _NullSpan:
+    """What a disabled profiler hands out: one shared object."""
+    seconds = 0.0
+    excluded = 0.0
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def no_phase(self) -> None:
+        pass
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NULL_SPAN = _NullSpan()
+
+
+def span(name: str, **attrs) -> Span:
+    """A span of no particular job (a barrier, a statement)."""
+    return Span(name, attrs)
+
+
+def null_span(name: str, **attrs) -> _NullSpan:
+    return NULL_SPAN
+
+
+def spans(enabled: bool):
+    """`span` or `null_span`: what an owner without a JobProfiler (the
+    Database, for `DeviceConfig.profile`) calls to open its spans."""
+    return span if enabled else null_span
 
 
 class JobProfiler:
@@ -85,10 +251,11 @@ class JobProfiler:
         # a dimension on every span so sharded/unsharded timings are
         # never conflated
         self.shards = shards
+        # which of the process's profilers this is: a re-created job of
+        # the same name is a new instance, and its spans say so
+        self.instance = next(_INSTANCES) if enabled else 0
         self.ring: deque = deque(maxlen=RING)
-        self.compiles: deque = deque(maxlen=256)   # (label, kind, seconds)
-        # full compile records incl. bucket/aot/cache_hit labels (the
-        # compile-service events; `compiles` keeps the legacy 3-tuples)
+        # compile records incl. bucket/aot/cache_hit labels
         self.compile_info: deque = deque(maxlen=256)
         # events may arrive from compile-service worker threads while the
         # barrier thread flushes — guard the shared buffers
@@ -112,15 +279,52 @@ class JobProfiler:
         if data_dir and self.enabled:
             self.path = os.path.join(data_dir, PROFILE_FILE)
 
+    # ---- spans -----------------------------------------------------------
+    def span(self, name: str, **attrs):
+        """A span of this job (see the module docstring); named
+        `rw:<phase>` it feeds that phase when it closes."""
+        if not self.enabled:
+            return NULL_SPAN
+        attrs["job"], attrs["inst"] = self.job, self.instance
+        return Span(name, attrs, owner=self)
+
+    def _span_closed(self, sp: Span, still_open: List[Span]) -> None:
+        """Feed the phase a `rw:<phase>` span is named after. Phases stay
+        disjoint: a phase span directly inside another takes its seconds
+        out of the outer one (`rw:exchange` in `rw:dispatch`); one deeper
+        inside (the steps of a growth replay under `rw:device_sync`)
+        feeds nothing, its time is the outer phase's."""
+        phase = sp.name[len(SPAN_PREFIX):]
+        if phase not in self.totals:
+            return
+        outer = next((o for o in reversed(still_open)
+                      if o.owner is self
+                      and o.name[len(SPAN_PREFIX):] in self.totals), None)
+        if outer is not None:
+            if outer.id != sp.parent:
+                return
+            outer.excluded += sp.seconds
+        self.phase(phase, sp.seconds - sp.excluded)
+
     # ---- epoch spans -----------------------------------------------------
-    def begin_epoch(self, seq: int, events: int) -> None:
-        self._cur = {"seq": seq, "events": events,
-                     "ph": {}, "t0": time.perf_counter()}
+    def begin_epoch(self, seq: int, events: int,
+                    epoch: Optional[int] = None) -> None:
+        if self._cur is not None:
+            # an exception ended the last barrier's work: close its span
+            self._cur["span"].__exit__(None, None, None)
+        attrs = {"seq": seq, "events": events}
+        if epoch is not None:
+            attrs["epoch"] = epoch
+        sp = self.span("rw:epoch", **attrs)
+        sp.__enter__()
+        self._cur = {"seq": seq, "events": events, "ph": {}, "span": sp}
 
     def phase(self, name: str, seconds: float) -> None:
-        """Accumulate a phase duration. Sync time from OUTSIDE an epoch
-        span (a SELECT pulling the MV between barriers) still lands in the
-        totals so warmup decomposition stays honest."""
+        """Accumulate a phase duration: what a closing `rw:<phase>` span
+        calls, and what a duration measured on another thread and handed
+        over is recorded with. Sync time from OUTSIDE an epoch span (a
+        SELECT pulling the MV between barriers) still lands in the totals
+        so warmup decomposition stays honest."""
         self.totals[name] = self.totals.get(name, 0.0) + seconds
         if self._cur is not None:
             ph = self._cur["ph"]
@@ -131,7 +335,9 @@ class JobProfiler:
         if cur is None:
             return
         self._cur = None
-        wall = time.perf_counter() - cur.pop("t0")
+        sp = cur["span"]
+        sp.__exit__(None, None, None)
+        wall = sp.seconds
         # "ts" = epoch END wall clock: the unified trace export
         # (utils/export.py) places the span at [ts - wall, ts] on the
         # coordinator timeline
@@ -145,7 +351,6 @@ class JobProfiler:
             self._buf.append(rec)
         self.epochs += 1
         try:
-            from .blackbox import RECORDER
             RECORDER.record("epoch", {
                 "job": self.job, "seq": rec["seq"],
                 "events": rec["events"], "shards": self.shards,
@@ -174,9 +379,15 @@ class JobProfiler:
         if cache_hit:
             rec["cache_hit"] = True
         with self._ev_lock:
-            self.compiles.append((label, kind, seconds))
             self.compile_info.append(rec)
             self._buf.append(rec)
+
+    @property
+    def compiles(self) -> List[Tuple[str, str, float]]:
+        """(label, kind, seconds) of every compile record."""
+        with self._ev_lock:
+            return [(r["label"], r["kind"], r["s"])
+                    for r in self.compile_info]
 
     # ---- file sink (flushed at checkpoints) ------------------------------
     def flush(self) -> None:
@@ -209,11 +420,9 @@ class JobProfiler:
     def rows(self) -> List[Tuple]:
         """rw_epoch_profile rows: (job, seq, events, shards, pack_ms,
         h2d_ms, promote_h2d_ms, dispatch_ms, exchange_ms,
-        device_sync_ms, demote_d2h_ms, commit_ms, wall_ms). Old-schema
-        records are normalized by `decode_epoch` (version dispatch, not
-        per-field sniffing). promote_h2d / demote_d2h are the state
-        tier's surgery phases (device/tiering.py) — zero when tiering
-        is off."""
+        device_sync_ms, demote_d2h_ms, commit_ms, wall_ms). promote_h2d
+        / demote_d2h are the state tier's surgery phases
+        (device/tiering.py) — zero when tiering is off."""
         out = []
         for r in self.ring:
             ph = decode_epoch(r)
@@ -227,7 +436,6 @@ class JobProfiler:
         """Compact report for bench detail blocks / risectl."""
         slow = sorted(self.ring, key=lambda r: -r["wall_ms"])[:top]
         with self._ev_lock:              # background compiles may land now
-            compiles = list(self.compiles)
             compile_info = list(self.compile_info)
         return {
             "epochs": self.epochs,
@@ -236,7 +444,7 @@ class JobProfiler:
                 {k: (round(v, 3) if k == "s" else v)
                  for k, v in rec.items() if k not in ("ev", "job")}
                 for rec in compile_info],
-            "compile_s": round(sum(s for _, _, s in compiles), 3),
+            "compile_s": round(sum(r["s"] for r in compile_info), 3),
             "top_epochs": [
                 {"seq": r["seq"], "wall_ms": round(r["wall_ms"], 3),
                  "ph_ms": {k: round(v, 3) for k, v in r["ph_ms"].items()}}
@@ -244,19 +452,17 @@ class JobProfiler:
         }
 
 
+# what code that may have no job's profiler at hand opens its spans on
+NULL_PROFILER = JobProfiler("", enabled=False)
+
+
 def decode_epoch(rec: Dict[str, Any]) -> Dict[str, float]:
-    """Schema-dispatched phase map of one epoch record. Every reader of
-    epoch records (rw_epoch_profile, risectl profile, the unified trace
-    export) normalizes through here, so a format change is one new
-    branch on the VERSION — not a field-presence heuristic copied into
-    each reader. Schema 1 (records with no `schema` field): `host_pack`
-    was the combined pack+h2d staging wall — folded into `pack` (h2d
-    was 0 by construction there; no staged transfers existed)."""
-    ph = dict(rec.get("ph_ms", {}))
-    if int(rec.get("schema", 1)) < 2:
-        if "host_pack" in ph:
-            ph["pack"] = ph.get("pack", 0.0) + ph.pop("host_pack")
-    return ph
+    """Phase map of one epoch record. Every reader of epoch records
+    (rw_epoch_profile, risectl profile, the unified trace export)
+    normalizes through here, so a format change is one branch on the
+    record's `schema` — not a field-presence heuristic copied into each
+    reader. One schema has been written so far."""
+    return dict(rec.get("ph_ms", {}))
 
 
 # ---------------------------------------------------------------------------

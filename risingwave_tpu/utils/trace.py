@@ -47,7 +47,12 @@ def rotate_tail(path: str) -> None:
 
 
 class BarrierTracer:
-    def __init__(self, data_dir: Optional[str] = None):
+    def __init__(self, data_dir: Optional[str] = None, span=None):
+        # `span(name, **attrs)` opens the `rw:barrier` span of each
+        # injected barrier (utils/profile.py); None = no spans
+        from .profile import null_span
+        self._span = span or null_span
+        self._open = None              # the barrier span not committed yet
         self.ring: deque = deque(maxlen=RING)
         self.path = os.path.join(data_dir, TRACE_FILE) if data_dir else None
         self._f = None
@@ -76,7 +81,15 @@ class BarrierTracer:
         except OSError:
             self._f = None             # tracing must never fail the job
 
-    def inject(self, epoch: int, kind: str) -> "BarrierSpan":
+    def inject(self, epoch: int, kind: str,
+               checkpoint: bool = False) -> "BarrierSpan":
+        if self._open is not None:
+            # the last barrier never committed (its tick raised): close
+            # its span, so that this barrier's does not nest inside it
+            self._open.__exit__(None, None, None)
+        self._open = self._span("rw:barrier", epoch=epoch, kind=kind,
+                                checkpoint=checkpoint)
+        self._open.__enter__()
         span = BarrierSpan(self, epoch, kind)
         self.ring.append(span)
         self._emit({"ev": "inject", "epoch": epoch, "kind": kind,
@@ -155,6 +168,9 @@ class BarrierSpan:
         self.commit_ts = time.time()
         self.tracer._emit({"ev": "commit", "epoch": self.epoch,
                            "ts": self.commit_ts})
+        if self.tracer._open is not None:
+            self.tracer._open.__exit__(None, None, None)
+            self.tracer._open = None
 
 
 def diagnose(path: str, last: int = 5, stuck_only: bool = False) -> str:

@@ -211,7 +211,7 @@ def test_held_compiles_block_dispatch_then_bit_identical():
 @pytest.mark.aot
 def test_compile_events_labeled():
     """Service compiles land in the requesting job's profiler with
-    `aot`/`bucket` labels and the idx:Type:sighash label grammar. Uses a
+    `aot`/`bucket` labels and the idx:name:sighash label grammar. Uses a
     plan shape no other test compiles (distinct max.events changes the
     source signature) so fresh events are guaranteed despite the shared
     process-global executable cache."""
@@ -226,8 +226,8 @@ def test_compile_events_labeled():
     assert evs, "AOT compiles must be recorded in the profiler"
     for rec in evs:
         assert rec["aot"] is True
-        idx, tname, sig = rec["label"].split(":")
-        assert tname.endswith("Node") and len(sig) == 8
+        idx, name, sig = rec["label"].split(":")
+        assert name == job.program.node_names[int(idx)] and len(sig) == 8
         assert "bucket" in rec
     assert db.query("SELECT * FROM q4")
 
@@ -497,7 +497,7 @@ def test_failed_aot_compile_is_loud_and_counted(monkeypatch, caplog):
     from risingwave_tpu.device.compile_service import CompileService
 
     args = _source_step_args()
-    real = fused._jit_step()
+    real = fused._jit_step(args[0])
 
     class RefusingCompiler:
         def lower(self, *a, **k):
@@ -506,7 +506,7 @@ def test_failed_aot_compile_is_loud_and_counted(monkeypatch, caplog):
         def __call__(self, *a, **k):
             return real(*a, **k)
 
-    monkeypatch.setattr(fused, "_jit_step", lambda: RefusingCompiler())
+    monkeypatch.setattr(fused, "_jit_step", lambda node: RefusingCompiler())
     svc = CompileService(workers=1)
     with caplog.at_level(logging.WARNING,
                          logger="risingwave_tpu.device.compile_service"):

@@ -457,19 +457,19 @@ def test_profile_schema_dispatch(tmp_path):
     from risingwave_tpu.utils.profile import (PROFILE_SCHEMA,
                                               decode_epoch,
                                               summarize_file)
-    assert PROFILE_SCHEMA >= 2
-    # schema-1 records fold host_pack into pack; schema-2 pass through
-    assert decode_epoch({"ph_ms": {"pack": 1.0, "host_pack": 2.0}}
-                        ) == {"pack": 3.0}
-    assert decode_epoch({"schema": 2,
-                         "ph_ms": {"pack": 1.0, "host_pack": 2.0}}
-                        ) == {"pack": 1.0, "host_pack": 2.0}
-    # a mixed-version file summarizes on one decode path
+    assert PROFILE_SCHEMA == 2
+    # the one schema written so far: the phase map passes through, as a
+    # copy, and a record without phases decodes to none
+    rec = {"schema": PROFILE_SCHEMA, "ph_ms": {"pack": 1.0, "h2d": 2.0}}
+    assert decode_epoch(rec) == {"pack": 1.0, "h2d": 2.0}
+    assert decode_epoch(rec) is not rec["ph_ms"]
+    assert decode_epoch({"schema": PROFILE_SCHEMA}) == {}
+    # every reader of a file summarizes on that one decode path
     path = str(tmp_path / "epoch_profile.jsonl")
     with open(path, "w") as f:
-        f.write(json.dumps({"ev": "epoch", "job": "j", "seq": 1,
-                            "events": 10, "wall_ms": 5.0,
-                            "ph_ms": {"pack": 1.0, "host_pack": 2.0,
+        f.write(json.dumps({"ev": "epoch", "schema": 2, "job": "j",
+                            "seq": 1, "events": 10, "wall_ms": 5.0,
+                            "ph_ms": {"pack": 1.0, "h2d": 2.0,
                                       "dispatch": 1.0}}) + "\n")
         f.write(json.dumps({"ev": "epoch", "schema": 2, "job": "j",
                             "seq": 2, "events": 10, "wall_ms": 4.0,
@@ -477,8 +477,8 @@ def test_profile_schema_dispatch(tmp_path):
                                       "dispatch": 1.0}}) + "\n")
     out = summarize_file(path)
     assert out["j"]["epochs"] == 2
-    assert out["j"]["phase_ms"]["pack"] == pytest.approx(5.5)
-    assert "host_pack" not in out["j"]["phase_ms"]
+    assert out["j"]["phase_ms"]["pack"] == pytest.approx(3.5)
+    assert out["j"]["phase_ms"]["h2d"] == pytest.approx(2.0)
 
 
 def test_served_staleness_reported_for_cache_lagged_selects(monkeypatch):

@@ -115,7 +115,8 @@ def _compile_step(idx, program, place, mesh=None):
         sds = jax.tree_util.tree_map(
             lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=place),
             sds)
-    step = _jit_step() if mesh is None else sharded_jit_step(mesh)
+    step = _jit_step(node) if mesh is None \
+        else sharded_jit_step(mesh, node)
     compiled = step.lower(*sds, node=node,
                           epoch_events=program.epoch_events,
                           salt=node._mut_sig()).compile()
@@ -156,7 +157,7 @@ def test_sharded_step_compiles_for_4_chips(topo, armed,
         lambda s, i_, e: sharded_apply(mesh, program.nodes[up], ee, s,
                                        tuple(i_), e, abstract=True),
         st, ins, extra)
-    exch = _exchange_jit(mesh).lower(
+    exch = _exchange_jit(mesh, node).lower(
         sds_sharded(out, mesh), node=node, xi=0, salt=node._mut_sig(),
         bounds=None, hot_keys=node.hot_keys,
         hot_side=node.hot_rep_side).compile()
