@@ -197,15 +197,20 @@ def _outputs(spec: DeviceAggSpec, vals: Sequence[jax.Array]
 
 
 def _core_tail(spec: DeviceAggSpec, state: SortedState,
-               ukeys: jax.Array, udeltas, ucount: jax.Array):
+               ukeys: jax.Array, udeltas, ucount: jax.Array,
+               trail: bool = False):
     """The merge half of the epoch pipeline: unique per-key deltas ->
     state merge + old/new change set. Shared by the raw-row path
     (`epoch_core`) and the pre-combined path (`epoch_core_combined`),
     which arrive at the same unique-delta representation from different
-    inputs."""
+    inputs. With `trail` (every epoch_core* passes it down) the change
+    set also holds the merge's `MergeTrail` as "merge_trail": a column
+    kept beside the state — the tier's touch stamps — follows its rows
+    through it by position."""
     with jax.named_scope("agg.merge"):
         old_found, old_vals = lookup(state, ukeys)
-        new_state, needed = merge(state, ukeys, udeltas, spec.kinds)
+        new_state, needed, *mtrail = merge(state, ukeys, udeltas,
+                                           spec.kinds, return_trail=trail)
         new_found, new_vals = lookup(new_state, ukeys)
     old_out, old_null = _outputs(spec, old_vals)
     new_out, new_null = _outputs(spec, new_vals)
@@ -219,19 +224,22 @@ def _core_tail(spec: DeviceAggSpec, state: SortedState,
         # sum/avg) and persists them to the state table for recovery
         "old_vals": tuple(old_vals), "new_vals": tuple(new_vals),
     }
+    if trail:
+        changes["merge_trail"] = mtrail[0]
     return new_state, needed, changes
 
 
 def epoch_core(spec: DeviceAggSpec, state: SortedState,
                keys: jax.Array, signs: jax.Array, mask: jax.Array,
-               inputs: Tuple[Tuple[jax.Array, jax.Array], ...]):
+               inputs: Tuple[Tuple[jax.Array, jax.Array], ...],
+               trail: bool = False):
     """The (un-jitted) epoch pipeline, shared by the single-chip step below
     and the shard-local body of parallel/sharded_agg.py."""
     with jax.named_scope("agg.reduce_delta"):
         deltas = _row_deltas(spec, signs, mask, inputs)
         ukeys, udeltas, ucount = batch_reduce(keys, mask, deltas,
                                               spec.kinds)
-    return _core_tail(spec, state, ukeys, udeltas, ucount)
+    return _core_tail(spec, state, ukeys, udeltas, ucount, trail)
 
 
 def precombine_core(spec: DeviceAggSpec,
@@ -259,7 +267,7 @@ def precombine_core(spec: DeviceAggSpec,
 
 def epoch_core_combined(spec: DeviceAggSpec, state: SortedState,
                         keys: jax.Array, counts: jax.Array,
-                        dvals, mask: jax.Array):
+                        dvals, mask: jax.Array, trail: bool = False):
     """Epoch pipeline over PRE-COMBINED rows: each input row is already a
     (key, raw-row count, per-column partial delta) tuple — one per key
     per upstream partition (several partitions' partials for one key may
@@ -272,7 +280,7 @@ def epoch_core_combined(spec: DeviceAggSpec, state: SortedState,
             keys, mask, [counts.astype(jnp.int64)] + list(dvals),
             (ReduceKind.SUM,) + spec.kinds)
     new_state, needed, ch = _core_tail(spec, state, ukeys, uvals[1:],
-                                       ucount)
+                                       ucount, trail)
     ch["rows_in"] = jnp.sum(uvals[0])
     ch["in_counts"] = uvals[0]
     return new_state, needed, ch
@@ -280,7 +288,8 @@ def epoch_core_combined(spec: DeviceAggSpec, state: SortedState,
 
 def epoch_core_full(spec: DeviceAggSpec, state: DeviceAggState,
                     keys: jax.Array, signs: jax.Array, mask: jax.Array,
-                    inputs: Tuple[Tuple[jax.Array, jax.Array], ...]):
+                    inputs: Tuple[Tuple[jax.Array, jax.Array], ...],
+                    trail: bool = False):
     """epoch_core + the retractable min/max multisets: one traced program
     covering main-state merge and every minput's sort-merge + extremes.
 
@@ -291,7 +300,7 @@ def epoch_core_full(spec: DeviceAggSpec, state: DeviceAggState,
       multiplicities (0 = pair died), for host-side state persistence.
     """
     new_main, needed, ch = epoch_core(spec, state.main, keys, signs, mask,
-                                      inputs)
+                                      inputs, trail)
     s64 = jnp.where(mask, signs, 0).astype(jnp.int64)
     new_ms: List[SortedMultiset] = []
     ms_needed: List[jax.Array] = []
@@ -317,7 +326,8 @@ def epoch_core_full(spec: DeviceAggSpec, state: DeviceAggState,
 
 def local_epoch_step(spec: DeviceAggSpec, state: DeviceAggState,
                      keys: jax.Array, signs: jax.Array, mask: jax.Array,
-                     inputs: Tuple[Tuple[jax.Array, jax.Array], ...]):
+                     inputs: Tuple[Tuple[jax.Array, jax.Array], ...],
+                     trail: bool = False):
     """One epoch's LOCAL aggregation step over the rows this program
     instance owns. On a single chip that is every row; under mesh
     sharding (`device/shard_exec.py`) it is the shard's exchange-routed
@@ -329,7 +339,7 @@ def local_epoch_step(spec: DeviceAggSpec, state: DeviceAggState,
     bit-identical to the global step, and the returned capacity needs
     are per-shard needs the pmax'd stats contract reports as the fleet
     high-water."""
-    return epoch_core_full(spec, state, keys, signs, mask, inputs)
+    return epoch_core_full(spec, state, keys, signs, mask, inputs, trail)
 
 
 @partial(jax.jit, static_argnames=("spec",))

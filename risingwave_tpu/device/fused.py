@@ -1287,29 +1287,30 @@ class AggNode(Node):
             return (self.capacity,)
         return (self.capacity, self.exch)
 
-    def _tier_tail(self, tstate, old_main, new_state, ch):
-        """Touch-column maintenance inside the traced step: carry each
-        surviving group's stamp across the merge's row permutation (by
-        key, not position), stamp this epoch's touched groups with the
-        current tick, and report (tres, tcold). Costs two searchsorteds
-        over arrays the step already sorts — no extra program, no sync."""
+    def _tier_tail(self, tstate, new_state, trail):
+        """Touch-column maintenance inside the traced step: the stamps
+        ride the merge the step already performs. `trail` (the agg
+        merge's `MergeTrail`) says which input row every new slot came
+        from, so a surviving group that the epoch's delta names — also
+        one whose delta nets to nothing — reads this tick, an untouched
+        one its old stamp by position, a dead or empty slot 0; then
+        (tres, tcold). Two gathers over the capacity, no search by key,
+        no extra program, no sync."""
+        import jax
         import jax.numpy as jnp
-        from .sorted_state import EMPTY_KEY
+        from .sorted_state import EMPTY_KEY, merged_src
         from .tiering import TIER_TTL, TieredState
         touch, tick = tstate.touch, tstate.tick
-        keys = new_state.main.keys
-        ocap = old_main.keys.shape[0]
-        idx = jnp.clip(jnp.searchsorted(old_main.keys, keys), 0, ocap - 1)
-        carried = jnp.where(old_main.keys[idx] == keys, touch[idx], 0)
-        tch = ch["keys"]
-        tidx = jnp.clip(jnp.searchsorted(tch, keys), 0,
-                        tch.shape[0] - 1)
-        touched = tch[tidx] == keys
-        live = keys != EMPTY_KEY
-        ntouch = jnp.where(live, jnp.where(touched, tick, carried), 0)
-        tres = jnp.sum(live).astype(jnp.int64)
-        tcold = jnp.sum(live & (tick - ntouch >= TIER_TTL)) \
-            .astype(jnp.int64)
+        with jax.named_scope("tier.touch"):
+            c = touch.shape[0]
+            src = merged_src(trail, last=True)
+            live = new_state.main.keys != EMPTY_KEY
+            ntouch = jnp.where(
+                live, jnp.where(src >= c, tick,
+                                touch[jnp.minimum(src, c - 1)]), 0)
+            tres = jnp.sum(live).astype(jnp.int64)
+            tcold = jnp.sum(live & (tick - ntouch >= TIER_TTL)) \
+                .astype(jnp.int64)
         return (TieredState(new_state, ntouch, tick + 1),
                 [tres, tcold])
 
@@ -1332,7 +1333,7 @@ class AggNode(Node):
             dvals = list(d.cols[2:2 + len(self.spec.kinds)])
             live = d.mask & (d.sign != 0)
             new_main, needed, ch = epoch_core_combined(
-                self.spec, state.main, keys, cnt, dvals, live)
+                self.spec, state.main, keys, cnt, dvals, live, self.tier)
             new_state = DeviceAggState(new_main, ())
             packbad = jnp.zeros((), jnp.int64)
             rows_in = ch["rows_in"].astype(jnp.int64)
@@ -1367,7 +1368,8 @@ class AggNode(Node):
                     inputs.append((d.cols[c.arg.index],
                                    jnp.ones(keys.shape, bool)))
             new_state, _needed, ch = local_epoch_step(
-                self.spec, state, keys, d.sign, d.mask, tuple(inputs))
+                self.spec, state, keys, d.sign, d.mask, tuple(inputs),
+                self.tier)
             needed, ms_needed = _needed
             rows_in = _nrows(d.mask & (d.sign != 0))
             stats_tail = [m.astype(jnp.int64) for m in ms_needed]
@@ -1386,6 +1388,9 @@ class AggNode(Node):
                 # psum across shards, sum across epochs — exact totals)
                 from .skew_stats import vnode_traffic
                 sk = sk + vnode_traffic(keys, d.mask & (d.sign != 0))
+        # the merge's trail is the tier tail's alone: no consumer of the
+        # change set may keep it alive as a step output
+        trail = ch.pop("merge_trail", None)
         if not self.emit_out:
             # terminal agg: only the MV apply reads the change set — keep
             # just what it needs; the delta stream is never materialized
@@ -1403,8 +1408,8 @@ class AggNode(Node):
                      ch["count"].astype(jnp.int64)] + stats_tail \
                 + [packbad, rows_in, rows_out] + sk
             if tstate is not None:
-                new_state, tstats = self._tier_tail(
-                    tstate, state.main, new_state, ch)
+                new_state, tstats = self._tier_tail(tstate, new_state,
+                                                    trail)
                 stats = stats + tstats
             return new_state, None, stats, aux
         # ---- change stream: old rows (-1) then new rows (+1) ------------
@@ -1447,8 +1452,7 @@ class AggNode(Node):
                  ch["count"].astype(jnp.int64)] + stats_tail \
             + [packbad, rows_in, _nrows(mask)] + sk
         if tstate is not None:
-            new_state, tstats = self._tier_tail(
-                tstate, state.main, new_state, ch)
+            new_state, tstats = self._tier_tail(tstate, new_state, trail)
             stats = stats + tstats
         return new_state, out, stats, ch
 
@@ -1641,9 +1645,9 @@ class JoinNode(Node):
         (bjk, bpk, bsg, bmk, bvals) = sides[1]
         # per-shard local step under mesh sharding, the whole step on one
         # chip: probe + merge + cross-delta pair netting (join_step)
-        new_a, new_b, njk, npk, nsign, nvals, needed = local_join_step(
-            a, b, ajk, apk, asg, amk, avals, bjk, bpk, bsg, bmk, bvals,
-            self.m)
+        new_a, new_b, njk, npk, nsign, nvals, needed, *trails = \
+            local_join_step(a, b, ajk, apk, asg, amk, avals,
+                            bjk, bpk, bsg, bmk, bvals, self.m, self.tier)
         omask = nsign != 0
         ocols = list(nvals)
         if self.cond is not None:
@@ -1681,36 +1685,36 @@ class JoinNode(Node):
         # touch at JOIN-KEY granularity (every row of one jk shares the
         # stamp — demotion/promotion move whole jk groups so probe
         # results never see a partial build side). An arriving delta on
-        # EITHER input touches the jk on BOTH sides.
-        from .sorted_state import EMPTY_KEY
+        # EITHER input touches the jk on BOTH sides. The stamps ride each
+        # side's merge by position (its `MergeTrail`); the epoch's
+        # touched keys are searched INTO the side — one query per delta
+        # row, none per slot — and mark their runs.
+        import jax
+        from .join_step import mark_key_runs
+        from .sorted_state import EMPTY_KEY, merged_src
         from .tiering import TIER_TTL, TieredState
         tick = tstate.tick
-        tkeys = jnp.sort(jnp.concatenate(
-            [jnp.where(amk & (asg != 0), ajk, EMPTY_KEY),
-             jnp.where(bmk & (bsg != 0), bjk, EMPTY_KEY)]))
 
-        def side_touch(old_side, old_touch, new_side):
-            nk = new_side.jk
-            oc = old_side.jk.shape[0]
-            idx = jnp.clip(jnp.searchsorted(old_side.jk, nk,
-                                            side="left"), 0, oc - 1)
-            carried = jnp.where(old_side.jk[idx] == nk,
-                                old_touch[idx], 0)
-            ti = jnp.clip(jnp.searchsorted(tkeys, nk), 0,
-                          tkeys.shape[0] - 1)
-            hit = tkeys[ti] == nk
-            live = nk != EMPTY_KEY
-            return jnp.where(live, jnp.where(hit, tick, carried), 0)
+        def side_touch(old_touch, new_side, trail, tkeys):
+            c = old_touch.shape[0]
+            src = merged_src(trail, last=False)
+            carried = jnp.where(src < c,
+                                old_touch[jnp.minimum(src, c - 1)], 0)
+            hit = mark_key_runs(new_side.jk, tkeys)
+            live = new_side.jk != EMPTY_KEY
+            ntouch = jnp.where(live, jnp.where(hit, tick, carried), 0)
+            return ntouch, live, live & (tick - ntouch >= TIER_TTL)
 
-        ta, tb = tstate.touch
-        nta = side_touch(a, ta, new_a)
-        ntb = side_touch(b, tb, new_b)
-        live_a = new_a.jk != EMPTY_KEY
-        live_b = new_b.jk != EMPTY_KEY
-        tres = (jnp.sum(live_a) + jnp.sum(live_b)).astype(jnp.int64)
-        tcold = (jnp.sum(live_a & (tick - nta >= TIER_TTL))
-                 + jnp.sum(live_b & (tick - ntb >= TIER_TTL))) \
-            .astype(jnp.int64)
+        with jax.named_scope("tier.touch"):
+            tkeys = jnp.concatenate(
+                [jnp.where(amk & (asg != 0), ajk, EMPTY_KEY),
+                 jnp.where(bmk & (bsg != 0), bjk, EMPTY_KEY)])
+            ta, tb = tstate.touch
+            trail_a, trail_b = trails[0]
+            nta, live_a, cold_a = side_touch(ta, new_a, trail_a, tkeys)
+            ntb, live_b, cold_b = side_touch(tb, new_b, trail_b, tkeys)
+            tres = (jnp.sum(live_a) + jnp.sum(live_b)).astype(jnp.int64)
+            tcold = (jnp.sum(cold_a) + jnp.sum(cold_b)).astype(jnp.int64)
         stats = stats + [tres, tcold]
         return (TieredState((new_a, new_b), (nta, ntb), tick + 1),
                 out, stats, None)

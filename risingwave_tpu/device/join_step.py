@@ -80,16 +80,20 @@ def batch_reduce_rows(jk, pk, signs, mask, vals):
     return ujk, upk, usign, uvals
 
 
-def merge_side(side: JoinSide, djk, dpk, dsign, dvals
-               ) -> Tuple[JoinSide, jax.Array]:
+def merge_side(side: JoinSide, djk, dpk, dsign, dvals,
+               return_trail: bool = False) -> Tuple:
     """Apply unique (jk,pk) deltas: +1 insert/upsert, -1 delete, 0 no-op.
 
     One stable variadic lexsort (state rows concatenated first, so they
     precede their delta on ties — sorted_state.sort_cols rationale) +
     combine + sort-based compaction. Zero-sign deltas merge as no-ops:
     they pair with their state row (if any) contributing pres 0, and
-    compact away alone (pres_m == 0)."""
-    from .sorted_state import compact_rows, sort_cols
+    compact away alone (pres_m == 0).
+
+    Returns (new_side, needed); with `return_trail` also the merge's
+    `MergeTrail` (sorted_state.merge has the contract), without it the
+    traced program is the one it always was."""
+    from .sorted_state import MergeTrail, compact_rows, sort_cols
     c = side.jk.shape[0]
     jk = jnp.concatenate([side.jk, djk])
     pk = jnp.concatenate([side.pk, dpk])
@@ -97,7 +101,8 @@ def merge_side(side: JoinSide, djk, dpk, dsign, dvals
                             dsign.astype(jnp.int32)])
     vals = [jnp.concatenate([sv, dv.astype(sv.dtype)])
             for sv, dv in zip(side.vals, dvals)]
-    (jk, pk), out = sort_cols([jk, pk], [pres] + vals)
+    (jk, pk), out, *sperm = sort_cols([jk, pk], [pres] + vals,
+                                      return_perm=return_trail)
     pres, vals = out[0], list(out[1:])
     same_next = jnp.concatenate(
         [(jk[:-1] == jk[1:]) & (pk[:-1] == pk[1:]), jnp.zeros((1,), bool)])
@@ -110,9 +115,32 @@ def merge_side(side: JoinSide, djk, dpk, dsign, dvals
     alive = ~same_prev & (jk != EMPTY_KEY) & (pres_m > 0)
     needed = jnp.sum(alive).astype(jnp.int32)
     out = compact_rows(alive, [jk, pk], vals_m, c,
-                       [EMPTY_KEY, EMPTY_KEY] + [0] * len(vals_m))
-    return JoinSide(out[0], out[1], jnp.minimum(needed, c),
-                    tuple(out[2:])), needed
+                       [EMPTY_KEY, EMPTY_KEY] + [0] * len(vals_m),
+                       return_perm=return_trail)
+    new = JoinSide(out[0], out[1], jnp.minimum(needed, c),
+                   tuple(out[2:2 + len(vals_m)]))
+    if return_trail:
+        return new, needed, MergeTrail(sperm[0], same_next, out[-1])
+    return new, needed
+
+
+def mark_key_runs(jk: jax.Array, queries: jax.Array) -> jax.Array:
+    """bool (C,): the rows of a jk-sorted side whose join key is among
+    `queries` (keys in any order, repeats welcome, EMPTY_KEY = no query).
+    One binary search per QUERY and none per row: a query finds the first
+    row of its key's run and marks it (a scatter of as many elements as
+    there are queries), and the mark spreads along the run in a prefix
+    max over (run start, mark) codes — so the searches' cost follows the
+    deltas that ask, not the capacity of the side."""
+    c = jk.shape[0]
+    lo = jnp.minimum(jnp.searchsorted(jk, queries, side="left",
+                                      method=search_method()), c - 1)
+    ok = (jk[lo] == queries) & (queries != EMPTY_KEY)
+    mark = jnp.zeros((c,), jnp.int32).at[jnp.where(ok, lo, c)].set(
+        1, mode="drop")
+    first = jnp.concatenate([jnp.ones((1,), bool), jk[1:] != jk[:-1]])
+    code = jnp.where(first, 2 * jnp.arange(c, dtype=jnp.int32) + mark, -1)
+    return (jax.lax.associative_scan(jnp.maximum, code) & 1) == 1
 
 
 def probe(side: JoinSide, qjk, qmask, m: int):
@@ -135,10 +163,12 @@ def probe(side: JoinSide, qjk, qmask, m: int):
 
 def join_core(a: JoinSide, b: JoinSide,
               a_jk, a_pk, a_sign, a_mask, a_vals,
-              b_jk, b_pk, b_sign, b_mask, b_vals, m: int):
+              b_jk, b_pk, b_sign, b_mask, b_vals, m: int,
+              trail: bool = False):
     """One epoch of both sides' rows -> (new states, pair change set).
     Unjitted core, shared by the single-chip step below and the shard-local
-    body of parallel/sharded_join.py.
+    body of parallel/sharded_join.py. With `trail` a sixth value holds the
+    two sides' `MergeTrail`s (merge_side).
 
     Pair change set: for each emitted pair, sign = producing delta's sign
     (+1 insert pair, -1 retract pair); payloads gathered from both sides,
@@ -164,8 +194,10 @@ def join_core(a: JoinSide, b: JoinSide,
             "mask": m1,
         }
     with jax.named_scope("join.merge"):
-        new_a, needed_a = merge_side(a, dajk, dapk, dasign, davals)
-        new_b, needed_b = merge_side(b, dbjk, dbpk, dbsign, dbvals)
+        new_a, needed_a, *trail_a = merge_side(a, dajk, dapk, dasign,
+                                               davals, trail)
+        new_b, needed_b, *trail_b = merge_side(b, dbjk, dbpk, dbsign,
+                                               dbvals, trail)
     # A_new >< dB
     with jax.named_scope("join.probe"):
         r2, s2, m2, need2 = probe(new_a, dbjk, dbsign != 0, m)
@@ -180,6 +212,9 @@ def join_core(a: JoinSide, b: JoinSide,
         }
     needed = {"a": needed_a, "b": needed_b,
               "pairs": jnp.maximum(need1, need2)}
+    if trail:
+        return (new_a, new_b, out1, out2, needed,
+                (trail_a[0], trail_b[0]))
     return new_a, new_b, out1, out2, needed
 
 
@@ -193,7 +228,8 @@ def join_epoch_step(a: JoinSide, b: JoinSide,
 
 def local_join_step(a: JoinSide, b: JoinSide,
                     a_jk, a_pk, a_sign, a_mask, a_vals,
-                    b_jk, b_pk, b_sign, b_mask, b_vals, m: int):
+                    b_jk, b_pk, b_sign, b_mask, b_vals, m: int,
+                    trail: bool = False):
     """One epoch's LOCAL join step: join_core plus cross-delta pair
     netting (the r02 pair-resurrection fix) over the rows this program
     instance owns. On a single chip that is every row; under mesh
@@ -204,10 +240,11 @@ def local_join_step(a: JoinSide, b: JoinSide,
 
     Returns (new_a, new_b, njk, npk, nsign, nvals, needed): netted
     unique pairs keyed by (left pk, right pk), payload columns
-    last-write-wins, plus the capacity-need stats of join_core."""
-    new_a, new_b, o1, o2, needed = join_core(
+    last-write-wins, plus the capacity-need stats of join_core — and,
+    with `trail`, join_core's pair of merge trails last."""
+    new_a, new_b, o1, o2, needed, *trails = join_core(
         a, b, a_jk, a_pk, a_sign, a_mask, a_vals,
-        b_jk, b_pk, b_sign, b_mask, b_vals, m)
+        b_jk, b_pk, b_sign, b_mask, b_vals, m, trail)
     cat = lambda k: jnp.concatenate([o1[k], o2[k]])
     catv = lambda k, i: jnp.concatenate([o1[k][i], o2[k][i]])
     sign = cat("sign")
@@ -216,7 +253,7 @@ def local_join_step(a: JoinSide, b: JoinSide,
         + [catv("b_vals", i) for i in range(len(b_vals))]
     njk, npk, nsign, nvals = batch_reduce_rows(
         cat("a_pk"), cat("b_pk"), sign, mask, pvals)
-    return new_a, new_b, njk, npk, nsign, nvals, needed
+    return (new_a, new_b, njk, npk, nsign, nvals, needed, *trails)
 
 
 class DeviceHashJoin:

@@ -10,6 +10,13 @@ jax arrays with static shapes, so an epoch apply is one jitted XLA program:
                --merge--------> new state (+ needed-slot count for resize)
     queries    --lookup-------> gathered payloads
 
+A column that lives BESIDE the state (the state tier's touch stamps,
+`device/tiering.py`) follows its rows through a merge by position:
+`merge(..., return_trail=True)` also says how the rows moved, and
+`merged_src` of that which input row each output slot came from, so the
+caller re-aligns the column with two gathers instead of searching for
+every key again.
+
 Empty slots hold EMPTY_KEY (int64 max) so they sort past every live key and
 binary search stays valid. Capacity growth is host-driven: `merge` reports
 how many slots it *needed*; when that exceeds capacity the host re-pads the
@@ -18,7 +25,7 @@ old state to 2x and re-runs (one recompile per capacity bucket).
 from __future__ import annotations
 
 import enum
-from typing import NamedTuple, Optional, Sequence, Tuple
+from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -186,13 +193,15 @@ def running_sum(x: jax.Array) -> jax.Array:
     return jnp.cumsum(x.astype(jnp.int64))
 
 
-def sort_cols(keys: Sequence[jax.Array], cols: Sequence[jax.Array]
-              ) -> Tuple[Tuple[jax.Array, ...], Tuple[jax.Array, ...]]:
+def sort_cols(keys: Sequence[jax.Array], cols: Sequence[jax.Array],
+              return_perm: bool = False) -> Tuple:
     """Stable sort of payload columns by key columns: rank-sort + gathers
     beyond 2 payloads (fastest compile — see cheap_compile), else one
-    variadic `lax.sort`."""
+    variadic `lax.sort`. With `return_perm` the rank-sort form is taken
+    whatever the payload count and its int32 permutation (sorted position
+    -> input row) is returned third."""
     nk = len(keys)
-    if len(cols) <= 2 or not cheap_compile():
+    if (len(cols) <= 2 or not cheap_compile()) and not return_perm:
         out = jax.lax.sort(list(keys) + list(cols), num_keys=nk,
                            is_stable=True)
         return tuple(out[:nk]), tuple(out[nk:])
@@ -200,34 +209,61 @@ def sort_cols(keys: Sequence[jax.Array], cols: Sequence[jax.Array]
     rank = jnp.arange(n, dtype=jnp.int32)
     out = jax.lax.sort(list(keys) + [rank], num_keys=nk, is_stable=True)
     idx = out[nk]
-    return tuple(out[:nk]), tuple(c[idx] for c in cols)
+    res = tuple(out[:nk]), tuple(c[idx] for c in cols)
+    return res + (idx,) if return_perm else res
 
 
 def compact_rows(alive: jax.Array, keys: Sequence[jax.Array],
                  cols: Sequence[jax.Array], out_len: int,
-                 fills: Sequence[Any]) -> Tuple:
+                 fills: Sequence[Any], return_perm: bool = False) -> Tuple:
     """Stable compaction of alive rows to the front, dead rows replaced by
     `fills`, result truncated to out_len. Implemented as one variadic sort
     on (dead, position) — NOT a scatter (see sort_cols). Row order among
-    alive rows is preserved, so key-sorted input stays key-sorted."""
+    alive rows is preserved, so key-sorted input stays key-sorted. With
+    `return_perm` the index form is taken whatever the column count and
+    its int32 permutation (output slot -> input row) is returned last."""
     n = alive.shape[0]
     rank = jnp.where(alive, 0, n).astype(jnp.int32) \
         + jnp.arange(n, dtype=jnp.int32)
     masked = [jnp.where(alive, a, f) for a, f in
               zip(list(keys) + list(cols), fills)]
-    if len(masked) <= 3 or not cheap_compile():
+    if (len(masked) <= 3 or not cheap_compile()) and not return_perm:
         out = jax.lax.sort([rank] + masked, num_keys=1, is_stable=False)
         return tuple(a[:out_len] for a in out[1:])
     _, idx = jax.lax.sort([rank, jnp.arange(n, dtype=jnp.int32)],
                           num_keys=1, is_stable=False)
     idx = idx[:out_len]
-    return tuple(a[idx] for a in masked)
+    out = tuple(a[idx] for a in masked)
+    return out + (idx,) if return_perm else out
+
+
+class MergeTrail(NamedTuple):
+    """How a sort-combine-compact merge moved its rows (`merge`,
+    `join_step.merge_side` with `return_trail`): what `merged_src` turns
+    into one source index per output slot."""
+    sort_perm: jax.Array        # int32 (n,) sorted position -> input row
+    same_next: jax.Array        # bool (n,) the next sorted row has this key
+    compact_perm: jax.Array     # int32 (C,) output slot -> sorted position
+
+
+def merged_src(trail: MergeTrail, last: bool) -> jax.Array:
+    """Which input row a LIVE output slot of a merge came from: int32
+    index into concat(state rows, delta rows) of one row of the slot's
+    run — the `last` (the delta row where the key has one) or the first
+    (the state row where the key had one). One int32 gather through the
+    compaction's permutation; an empty slot reads garbage (gate on the
+    new state's key)."""
+    sp = trail.sort_perm
+    if last:
+        sp = jnp.where(trail.same_next,
+                       jnp.concatenate([sp[1:], sp[-1:]]), sp)
+    return sp[trail.compact_perm]
 
 
 def merge(state: SortedState, dkeys: jax.Array,
           dvals: Sequence[jax.Array], kinds: Sequence[ReduceKind],
-          drop_dead: bool = True, dead_col: int = 0
-          ) -> Tuple[SortedState, jax.Array]:
+          drop_dead: bool = True, dead_col: int = 0,
+          return_trail: bool = False) -> Tuple:
     """Merge unique per-key deltas (from `batch_reduce`) into the state.
 
     Every key appears at most once in `state` and at most once in the delta,
@@ -238,7 +274,12 @@ def merge(state: SortedState, dkeys: jax.Array,
     emits DELETE and drops state when count reaches 0).
 
     Returns (new_state, needed) — `needed` > capacity means the merge was
-    truncated and must be retried on a grown state.
+    truncated and must be retried on a grown state. With `return_trail` a
+    third value, the `MergeTrail`, says how the rows moved: `merged_src` of
+    it is the input row of every live output slot (an index < capacity is
+    that state row, one >= capacity the delta row `index - capacity`), so
+    a column kept beside the state rides the merge by position. Without it
+    the traced program is the one it always was.
     """
     c = state.capacity
     # named scopes are HLO metadata only: they put these stages' device
@@ -247,7 +288,8 @@ def merge(state: SortedState, dkeys: jax.Array,
         keys = jnp.concatenate([state.keys, dkeys])
         vals = [jnp.concatenate([sv, dv.astype(sv.dtype)])
                 for sv, dv in zip(state.vals, dvals)]
-        (keys,), vals = sort_cols([keys], vals)
+        (keys,), vals, *sperm = sort_cols([keys], vals,
+                                          return_perm=return_trail)
     same_next = jnp.concatenate([keys[:-1] == keys[1:], jnp.zeros((1,), bool)])
     same_prev = jnp.concatenate([jnp.zeros((1,), bool), keys[1:] == keys[:-1]])
     merged = []
@@ -261,9 +303,13 @@ def merge(state: SortedState, dkeys: jax.Array,
     with jax.named_scope("merge.compact"):
         out = compact_rows(alive, [keys], merged, c,
                            [EMPTY_KEY] + [_neutral(k, v.dtype)
-                                          for v, k in zip(merged, kinds)])
-    new_count = jnp.minimum(needed, c)
-    return SortedState(out[0], new_count, tuple(out[1:])), needed
+                                          for v, k in zip(merged, kinds)],
+                           return_perm=return_trail)
+    new = SortedState(out[0], jnp.minimum(needed, c),
+                      tuple(out[1:1 + len(merged)]))
+    if return_trail:
+        return new, needed, MergeTrail(sperm[0], same_next, out[-1])
+    return new, needed
 
 
 def lookup(state: SortedState, qkeys: jax.Array

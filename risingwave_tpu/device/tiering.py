@@ -10,7 +10,10 @@ applied to the sorted-array state the device operators already use):
   hot tier   — the device SortedState/JoinSide tables, exactly as
                before, now carrying a last-touched-epoch column
                (device/fused.py stamps it inside the existing traced
-               step; no extra program, no extra sync).
+               step: the column rides the step's own merge by position
+               — `sorted_state.MergeTrail` —, the join searches the
+               epoch's touched keys into each side; no search per
+               slot, no extra program, no extra sync).
   cold tier  — per-node, per-shard host dicts (`ColdStore`) keyed by
                the packed group/join key, holding the exact payload
                row + its touch stamp, populated by the coordinator off

@@ -416,3 +416,301 @@ def test_ctl_tiering_report(monkeypatch, tmp_path, capsys):
     assert os.path.exists(jp)
     db.run("DROP MATERIALIZED VIEW qa")
     assert not os.path.exists(jp)
+
+
+# ---------------------------------------------------------------------------
+# the touch stamp rides the merge (ISSUE 27): traced steps against a
+# by-key dictionary, and the shape of their programs
+# ---------------------------------------------------------------------------
+
+STEP_CAP = 32       # slots before the scheduled grow (then 64)
+STEP_ROWS = 24      # delta rows an epoch (padded)
+STEP_EPOCHS = 16
+GROW_AT = 9         # the epoch that runs right after cap_resize
+
+
+def _tier_agg_node(combined, capacity=STEP_CAP):
+    """A tier-armed count(*) / sum(c1) GROUP BY c0 AggNode, built as the
+    fuse planner builds it."""
+    from types import SimpleNamespace as NS
+    import jax.numpy as jnp
+    from risingwave_tpu.device.agg_step import DeviceAggSpec
+    from risingwave_tpu.device.fused import AggNode, PackPlan
+    spec = DeviceAggSpec.build(["count_star", "sum"], [jnp.int64] * 2,
+                               append_only=False)
+    calls = [NS(kind="count", arg=None), NS(kind="sum", arg=NS(index=1))]
+    node = AggNode(0, [0], calls, PackPlan.plan([(0, 4095, 1)]), spec,
+                   capacity, None)
+    if combined:
+        node.enable_precombine()
+    return node
+
+
+def _tier_join_node(capacity=STEP_CAP, pairs=256):
+    import jax.numpy as jnp
+    from risingwave_tpu.device.fused import JoinNode, PackPlan
+    return JoinNode(0, 1, [0], [0], PackPlan.plan([(0, 4095, 1)]), None,
+                    capacity, pairs, [jnp.int64] * 2, [jnp.int64] * 2)
+
+
+def _pad(rows, width, n=STEP_ROWS):
+    """rows: [(sign, masked, *ints)] -> (sign, mask, cols) padded to n."""
+    assert len(rows) <= n, len(rows)
+    sign = np.zeros(n, np.int32)
+    mask = np.zeros(n, bool)
+    cols = [np.zeros(n, np.int64) for _ in range(width)]
+    for i, (s, m, *vs) in enumerate(rows):
+        sign[i], mask[i] = s, m
+        for c, v in zip(cols, vs):
+            c[i] = v
+    return sign, mask, cols
+
+
+def _agg_delta(rows, combined):
+    """[(sign, masked, key, value)] as the node's input delta: raw rows,
+    or the PrecombineNode layout [key, raw rows, *partial deltas] (one
+    partial per raw row; the agg re-combines them)."""
+    import jax.numpy as jnp
+    from risingwave_tpu.device.fused import Delta
+    if combined:
+        rows = [(1, m, k, 1, s, s, s * v, s) for s, m, k, v in rows]
+    sign, mask, cols = _pad(rows, 6 if combined else 2)
+    return Delta([jnp.asarray(c) for c in cols], jnp.asarray(sign),
+                 jnp.asarray(mask))
+
+
+def _agg_epochs(rng, combined):
+    """STEP_EPOCHS epochs of [(sign, masked, key, value)] with every case
+    the carry has to get right, beside random traffic over 20 keys:
+    groups that go cold (keys 100..103 after epoch 0), a retraction to
+    group death (epoch 3), a delta that nets to nothing on a live key
+    (epoch 4, key 100 — touched all the same), a masked-in row of sign 0
+    (epoch 5, raw rows only: it names key 101), an empty epoch (6)."""
+    held = {}                       # key -> values inserted, not retracted
+    out = []
+    for e in range(STEP_EPOCHS):
+        rows = []
+        if e == 0:
+            rows = [(1, True, k, 7) for k in (100, 101, 102, 103, 104)]
+        elif e == 3:
+            rows = [(-1, True, 104, 7)]
+        elif e == 4:
+            rows = [(1, True, 100, 9), (-1, True, 100, 9)]
+        elif e == 5 and not combined:
+            rows = [(0, True, 101, 5)]
+        if e != 6:
+            for _ in range(int(rng.integers(4, 14))):
+                k = int(rng.integers(0, 20))
+                if held.get(k) and rng.random() < 0.45:
+                    rows.append((-1, True, k, held[k].pop()))
+                else:
+                    v = int(rng.integers(1, 50))
+                    held.setdefault(k, []).append(v)
+                    rows.append((1, True, k, v))
+            rows.append((1, False, 105, 3))         # masked out: no row
+        out.append(rows)
+    return out
+
+
+def _stat(node, stats, name):
+    return int(stats[node.stat_names.index(name)])
+
+
+@pytest.mark.tiering
+@pytest.mark.parametrize("case", ["agg", "agg-combined", "join-side-a",
+                                  "join-side-b"])
+def test_touch_rides_merge_against_dictionary(case):
+    """Drive the traced tier-armed step and hold the touch column, `tres`
+    and `tcold` to a dictionary kept by key in plain Python, after every
+    epoch: a surviving key the epoch names reads this tick, any other its
+    old stamp, a dead or empty slot 0."""
+    from risingwave_tpu.device.fused import _node_step
+    from risingwave_tpu.device.sorted_state import EMPTY_KEY
+    from risingwave_tpu.device.tiering import TIER_TTL
+    rng = np.random.default_rng(27)
+    if case.startswith("agg"):
+        combined = case == "agg-combined"
+        node = _tier_agg_node(combined)
+        node.enable_tiering()
+        state = node.init_state()
+        groups, stamp = {}, {}          # key -> [rows, sum]; key -> tick
+        for tick, rows in enumerate(_agg_epochs(rng, combined)):
+            if tick == GROW_AT:
+                state = node.cap_resize(state, {"main": 2 * STEP_CAP})
+            state, _, stats, _ = _node_step(
+                node, STEP_ROWS, state, [_agg_delta(rows, combined)], None)
+            named = set()
+            for s, m, k, v in rows:
+                if m:
+                    named.add(k)
+                    g = groups.setdefault(k, [0, 0])
+                    g[0] += s
+                    g[1] += s * v
+            for k in [k for k, g in groups.items() if g[0] == 0]:
+                del groups[k]
+                stamp.pop(k, None)
+            for k in named & groups.keys():
+                stamp[k] = tick
+            keys = np.asarray(state.inner.main.keys)
+            touch = np.asarray(state.touch)
+            live = keys != EMPTY_KEY
+            assert dict(zip(keys[live].tolist(), touch[live].tolist())) \
+                == stamp, (case, tick)
+            assert not touch[~live].any(), (case, tick)
+            assert int(state.tick) == tick + 1
+            assert _stat(node, stats, "tres") == len(stamp)
+            assert _stat(node, stats, "tcold") == sum(
+                tick - t >= TIER_TTL for t in stamp.values()), (case, tick)
+        assert len(keys) == 2 * STEP_CAP and len(stamp) > STEP_CAP // 2
+        assert any(STEP_EPOCHS - 1 - t >= TIER_TTL for t in stamp.values())
+        return
+    _drive_join_against_dictionary(rng, 0 if case == "join-side-a" else 1)
+
+
+def _drive_join_against_dictionary(rng, side):
+    """The join's stamp is per join key and a delta on EITHER input
+    stamps the key on BOTH sides. Scripted on `side`: rows whose key the
+    OTHER input alone touches (epoch 3, key 100), a key that goes cold
+    (101), a delete of one of two rows of a key (epoch 4, key 102: the
+    row that stays is stamped), a masked-in row of sign 0 (epoch 5: it
+    touches nothing), an empty epoch (6)."""
+    import jax.numpy as jnp
+    from risingwave_tpu.device.fused import Delta, _node_step
+    from risingwave_tpu.device.sorted_state import EMPTY_KEY
+    from risingwave_tpu.device.tiering import TIER_TTL
+    node = _tier_join_node()
+    node.enable_tiering()
+    state = node.init_state()
+    rows = ({}, {})                 # per side: pk -> jk
+    stamp = ({}, {})                # per side: jk -> tick
+    next_pk = [1000]
+
+    def ins(s, jk):
+        next_pk[0] += 1
+        return (1, True, jk, next_pk[0], s)
+
+    def delete(s, jk):
+        pk = next(p for p, k in rows[s].items() if k == jk)
+        return (-1, True, jk, pk, s)
+
+    other = 1 - side
+    for tick in range(STEP_EPOCHS):
+        ops = []                    # (sign, masked, jk, pk, side)
+        if tick == 0:
+            ops = [ins(side, 100), ins(side, 101), ins(other, 101),
+                   ins(side, 102), ins(side, 102)]
+        elif tick == 3:
+            ops = [ins(other, 100)]
+        elif tick == 4:
+            ops = [delete(side, 102)]
+        elif tick == 5:
+            ops = [(0, True, 101, 999, side)]
+        if tick not in (0, 6):
+            for _ in range(int(rng.integers(3, 12))):
+                s = int(rng.integers(0, 2))
+                jk = int(rng.integers(0, 12))
+                gone = {o[3] for o in ops}
+                mine = [p for p, k in rows[s].items()
+                        if k == jk and p not in gone]
+                if mine and rng.random() < 0.4:
+                    ops.append((-1, True, jk, mine[0], s))
+                else:
+                    ops.append(ins(s, jk))
+            ops.append((1, False, 103, 998, side))      # masked out
+        if tick == GROW_AT:
+            state = node.cap_resize(state, {"a": 2 * STEP_CAP,
+                                            "b": 2 * STEP_CAP})
+        deltas = []
+        for s in (0, 1):
+            sign, mask, (jk, pk) = _pad(
+                [o[:4] for o in ops if o[4] == s], 2)
+            deltas.append(Delta([jnp.asarray(jk), jnp.asarray(pk)],
+                                jnp.asarray(sign), jnp.asarray(mask),
+                                pk=jnp.asarray(pk)))
+        state, _, stats, _ = _node_step(node, STEP_ROWS, state, deltas,
+                                        None)
+        touched = {o[2] for o in ops if o[1] and o[0] != 0}
+        for sign, m, jk, pk, s in ops:
+            if m and sign > 0:
+                rows[s][pk] = jk
+            elif m and sign < 0:
+                del rows[s][pk]
+        for s in (0, 1):
+            livek = set(rows[s].values())
+            for k in [k for k in stamp[s] if k not in livek]:
+                del stamp[s][k]
+            for k in livek & touched:
+                stamp[s][k] = tick
+            assert livek == stamp[s].keys()
+        jk = np.asarray(state.inner[side].jk)
+        touch = np.asarray(state.touch[side])
+        live = jk != EMPTY_KEY
+        assert sorted(jk[live].tolist()) == sorted(rows[side].values())
+        assert {(k, t) for k, t in zip(jk[live].tolist(),
+                                       touch[live].tolist())} \
+            == set(stamp[side].items()), (side, tick)
+        assert not touch[~live].any(), (side, tick)
+        assert _stat(node, stats, "tres") == len(rows[0]) + len(rows[1])
+        assert _stat(node, stats, "tcold") == sum(
+            tick - stamp[s][k] >= TIER_TTL
+            for s in (0, 1) for k in rows[s].values()), (side, tick)
+    assert len(jk) == 2 * STEP_CAP
+    assert stamp[side][100] >= 3 and stamp[side][101] == 0
+
+
+def _loops(jaxpr, found=None):
+    """Every `scan` / `while` equation of a jaxpr, sub-jaxprs included."""
+    from jax.extend import core as jcore
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            found.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jcore.Jaxpr):
+                    _loops(sub, found)
+    return found
+
+
+def _step_jaxpr(node, n_ins, rows=48):
+    import jax
+    import jax.numpy as jnp
+    from risingwave_tpu.device.fused import Delta
+    z = jnp.zeros((rows,), jnp.int64)
+    ins = [Delta([z] * (6 if getattr(node, "combined", False) else 2),
+                 jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), bool),
+                 pk=z) for _ in range(n_ins)]
+    return jax.make_jaxpr(lambda st, ds: node.apply(st, ds, None, rows))(
+        node.init_state(), ins).jaxpr
+
+
+@pytest.mark.tiering
+@pytest.mark.parametrize("combined", [False, True])
+def test_tier_armed_agg_step_adds_no_loop(combined):
+    """The stamp rides the merge's permutations: arming the tier may add
+    gathers to the agg step, never a binary search (`searchsorted` of the
+    compile-cheap form is a `scan` loop; the by-key carry held two)."""
+    plain, armed = _tier_agg_node(combined, 1024), \
+        _tier_agg_node(combined, 1024)
+    armed.enable_tiering()
+    n_plain = len(_loops(_step_jaxpr(plain, 1)))
+    assert n_plain >= 2                     # the merge's two lookups
+    assert len(_loops(_step_jaxpr(armed, 1))) <= n_plain
+
+
+@pytest.mark.tiering
+def test_tier_armed_join_step_searches_follow_the_delta():
+    """No loop of the tier-armed join step carries an array as long as a
+    side: whatever it searches for, it asks once per delta row (48) or
+    pair slot (256), never once per slot of the side (1024)."""
+    cap = 1024
+    armed = _tier_join_node(cap)
+    armed.enable_tiering()
+    loops = _loops(_step_jaxpr(armed, 2))
+    assert loops
+    for eqn in loops:
+        side_long = [v.aval.shape for v in eqn.outvars
+                     if getattr(v.aval, "shape", ()) and
+                     v.aval.shape[0] >= cap]
+        assert not side_long, (eqn.primitive.name, side_long)
