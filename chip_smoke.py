@@ -4,6 +4,11 @@
     python chip_smoke.py --chips 4  # four chips: mesh_shards=4 vs 1, nothing else
                                     # (append bid_groupby or q7 to run one of the two)
 
+Since PR 28 the `bid_groupby` half of `--chips 4` is covered by the
+benchmark's cell `bid-agg.mesh4` (`benchmarks/`, the same group-by at
+`mesh_shards=4` in steady state, checked against the frozen numpy reference
+in every run); the q7 half is still only here.
+
 One process, JAX imported once. It exits non-zero unless `jax.devices()`
 reports the `tpu` platform (and, with --chips 4, four of them) — there is no
 road back to the CPU. Every phase drives the system through SQL exactly as

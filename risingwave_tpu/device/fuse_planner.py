@@ -596,9 +596,14 @@ def try_fuse(execu, ns, device_cfg, name: str,
             n = data_shards(mesh)
             cap0 = exchange_cap(ee, n)
             for node in f.nodes:
+                # per-shard occupancy first: the exchange's host-spliced
+                # stats stay last in the node's layout
+                if node.shard_spec().state == "vnode":
+                    node.enable_shard_live(n)
                 if node.shard_spec().exchanges:
                     node.enable_exchange(
-                        cap0, slot_bytes=8 * n * _exchange_row_width(node))
+                        cap0, n,
+                        slot_bytes=8 * n * _exchange_row_width(node))
         hot_on = _env_bool("RW_HOT_KEY_REP",
                            getattr(device_cfg, "hot_key_rep", True))
         if mesh is not None and skew_on and hot_on:

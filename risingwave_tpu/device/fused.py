@@ -447,6 +447,12 @@ class Node:
     # traced step when armed (enable_flow); the slots accumulate by SUM
     # across epochs and shards. False everywhere else.
     flow: bool = False
+    # per-shard occupancy under a mesh (device/shard_exec.py): the
+    # high-water stats whose sum is this node's live entries, and whether
+    # `enable_shard_live` has armed one "live<s>" stat a shard (never on
+    # single-chip programs or un-keyed nodes)
+    live_stats: Tuple[str, ...] = ()
+    shard_live: bool = False
 
     def init_state(self):
         return None
@@ -478,7 +484,21 @@ class Node:
         Stateful keyed nodes override with state="vnode" (+ exchanges)."""
         return ShardSpec()
 
-    def enable_exchange(self, cap: int,
+    def enable_shard_live(self, n_shards: int) -> None:
+        """Arm per-shard occupancy for a vnode-sharded node
+        (planner-called, once, under a mesh, BEFORE enable_exchange — the
+        host-spliced exchange stats stay last — and before the program is
+        built): "live<s>" = shard s's live entries, the sum of
+        `live_stats`, read out of the all_gather that already reduces
+        those stats (`shard_exec.sharded_apply`). High-waters, like the
+        stats they come from. No-op for a node without `live_stats`."""
+        if self.live_stats and not self.shard_live:
+            assert not set(self.live_stats) & set(self.stat_sums)
+            self.shard_live = True
+            self.stat_names = tuple(self.stat_names) + tuple(
+                f"live{s}" for s in range(n_shards))
+
+    def enable_exchange(self, cap: int, n_shards: int,
                         slot_bytes: Optional[int] = None) -> None:
         """Arm the in-program exchange stage for this node's flagged
         inputs (planner-called, once, before the program is built): the
@@ -486,10 +506,16 @@ class Node:
         slot whose per-epoch high-water ("exch", appended to stat_names)
         rides the stats vector through the normal grow+replay path.
         `slot_bytes` is the planner's estimate of one buffered row's HBM
-        width across all destination buckets (budget math)."""
+        width across all destination buckets (budget math). The live
+        rows each of the `n_shards` shards receives from each stage ride
+        along as SUM stats "xin<stage>_<shard>" (what the exchange's
+        one-hot already counts, psum'd per destination)."""
         assert self.shard_spec().exchanges, "node has no exchange stage"
         if self.exch is None:
-            self.stat_names = tuple(self.stat_names) + ("exch",)
+            xin = tuple(f"xin{xi}_{s}" for xi in range(len(
+                self.shard_spec().exchanges)) for s in range(n_shards))
+            self.stat_names = tuple(self.stat_names) + ("exch",) + xin
+            self.stat_sums = tuple(self.stat_sums) + xin
         self.exch = int(cap)
         if slot_bytes is not None:
             self.exch_bytes = int(slot_bytes)
@@ -1071,6 +1097,7 @@ class AggNode(Node):
                                 + [f"ms{i}" for i in range(len(spec.minputs))]
                                 + ["packbad", "rows_in", "rows_out"])
         self.stat_sums = ("rows_in", "rows_out")
+        self.live_stats = ("needed",)
 
     def enable_skew(self):
         from .skew_stats import SKEW_STAT_NAMES
@@ -1478,6 +1505,7 @@ class JoinNode(Node):
         self.stat_names = ("need_a", "need_b", "need_pairs", "packbad",
                            "rows_in", "rows_out")
         self.stat_sums = ("rows_in", "rows_out")
+        self.live_stats = ("need_a", "need_b")
 
     def enable_skew(self):
         from .skew_stats import SKEW_STAT_NAMES
@@ -1730,6 +1758,7 @@ class MVKeyedNode(Node):
         self.capacity = capacity
         self.stat_names = ("needed", "rows_in")
         self.stat_sums = ("rows_in",)
+        self.live_stats = ("needed",)
 
     def shard_spec(self):
         # co-partitioned with its agg (the change set arrives already on
@@ -1797,6 +1826,7 @@ class MVPairNode(Node):
         self.capacity = capacity
         self.stat_names = ("needed", "rows_in")
         self.stat_sums = ("rows_in",)
+        self.live_stats = ("needed",)
 
     def shard_spec(self):
         # co-partitioned with its join: a pair lives on the shard owning
@@ -2212,7 +2242,7 @@ class FusedProgram:
         stats: List[Any] = []
         for i, node in enumerate(self.nodes):
             ins = [outs[j] for j in node.inputs]
-            exch_need = None
+            exch_need, exch_rows = None, []
             if mesh is not None and node.exch is not None:
                 # in-program ICI shuffle: route each flagged input's rows
                 # to the shard owning their key's vnode block. Its own
@@ -2220,15 +2250,20 @@ class FusedProgram:
                 # "dispatch" (dispatch is async — this wall is enqueue
                 # cost, the device-side ICI time lands in device_sync
                 # like all device compute)
+                from ..parallel.mesh import data_shards
                 from .shard_exec import delta_sds, exchange_delta
-                with spans.span("rw:exchange"):
-                    for xi, ex in enumerate(node.shard_spec().exchanges):
+                shards = data_shards(mesh)
+                for xi, ex in enumerate(node.shard_spec().exchanges):
+                    with spans.span("rw:exchange", node=self.node_names[i],
+                                    xi=xi, shards=shards, exch=node.exch,
+                                    rows_slots=shards * node.exch):
                         self._exch_sds[(i, xi)] = delta_sds(ins[ex.input])
-                        ins[ex.input], need = exchange_delta(
+                        ins[ex.input], need, rows_in = exchange_delta(
                             mesh, node, xi, ins[ex.input],
                             bounds=self.vnode_bounds)
-                        exch_need = need if exch_need is None \
-                            else jnp.maximum(exch_need, need)
+                    exch_need = need if exch_need is None \
+                        else jnp.maximum(exch_need, need)
+                    exch_rows.extend(rows_in)
             ins = tuple(ins)
             if node.takes_event_lo:
                 extra = jnp.int64(event_lo) if not hasattr(
@@ -2268,10 +2303,11 @@ class FusedProgram:
             outs.append(out)
             auxes.append(aux)
             if exch_need is not None:
-                # the "exch" stat (appended to the node's stat_names by
-                # enable_exchange) is produced by the exchange stage, not
-                # the node's apply — splice it in here
-                s = list(s) + [exch_need]
+                # the "exch" stat and the "xin" stats after it (appended
+                # to the node's stat_names by enable_exchange) are
+                # produced by the exchange stages, not the node's apply —
+                # splice them in here
+                s = list(s) + [exch_need] + exch_rows
             stats.extend(s)
         return tuple(new_states), tuple(stats)
 
@@ -3452,8 +3488,10 @@ class FusedJob:
                     if dirty:
                         self.job_state_table.commit(epoch)
             if prof is not None:
-                with prof.span("rw:commit.gauges"):
+                with prof.span("rw:commit.gauges") as gauges:
                     self._export_hbm_gauges()
+                    if self.program.mesh is not None:
+                        gauges.set(shard_report=self.shard_report())
         if self.freshness is not None and self._window_ingest is not None:
             # end-to-end staleness of this commit: the oldest epoch in
             # the checkpoint window was dispatched (= its events came
@@ -4169,13 +4207,29 @@ class FusedJob:
         bucket's fraction of the live total; metric='hot_key': ordinal =
         rank, key = the 40-bit-truncated hot key, value = its per-epoch
         row count (the hottest (key, epoch) observed — see
-        device/skew_stats.py for the exact semantics). All read from the
-        stats the regular syncs already pulled — zero extra device
-        traffic."""
+        device/skew_stats.py for the exact semantics); on a mesh, also
+        metric='shard_live' / 'exchange_rows_in' (ordinal = shard; see
+        `shard_report`), whether or not skew telemetry is armed. All read
+        from the stats the regular syncs already pulled — zero extra
+        device traffic."""
         from .skew_stats import (SK_BUCKETS, SK_TOPK, skew_ratio,
                                  traffic_divergence, unpack_hot)
         out: List[Tuple] = []
         totals = self._stat_totals
+        # what the shards themselves report (`shard_report`), armed with
+        # the mesh and not with the skew telemetry: 'shard_live' = live
+        # entries of shard `ordinal`, 'exchange_rows_in' = live rows shard
+        # `ordinal` received from exchange stage `key`
+        shard = self.shard_report() or {"keyed": [], "exchanges": []}
+        for metric, entries, per_shard in (
+                ("shard_live", shard["keyed"], "live"),
+                ("exchange_rows_in", shard["exchanges"], "rows_in")):
+            for e in entries:
+                tname = type(self.program.nodes[e["i"]]).__name__
+                tot = sum(e[per_shard])
+                out.extend((e["i"], tname, metric, s, e.get("xi"), int(v),
+                            v / tot if tot else 0.0)
+                           for s, v in enumerate(e[per_shard]))
         for i, node in enumerate(self.program.nodes):
             if not (node.skew or node.flow):
                 continue
@@ -4234,6 +4288,36 @@ class FusedJob:
                     out.append((i, tname, "hot_policy", r, hk,
                                 node.hot_rep_side, None))
         return out
+
+    def shard_report(self) -> Optional[Dict[str, Any]]:
+        """What each shard of a mesh-sharded job holds and receives, from
+        the stats the regular syncs already pull (job-lifetime totals,
+        checkpoint-fresh; no device traffic): per exchange stage the
+        bucket capacity `exch`, the `slots` a shard's step is handed an
+        epoch (shards x exch) and `rows_in[shard]`, the live rows each
+        shard received, summed over the job's epochs; per keyed node
+        `live[shard]`, each shard's live entries (high-water);
+        `rebalances`, the routing switches adopted. None on one chip.
+        JSON-able: every checkpoint leaves it on its `rw:commit.gauges`
+        span, where it outlives the job."""
+        if self.program.mesh is None:
+            return None
+        n = self.mesh_shards
+        exchanges, keyed = [], []
+        for i, node in enumerate(self.program.nodes):
+            st = self.program.node_stats(i, self._stat_totals)
+            name = self.program.node_names[i]
+            if node.exch is not None:
+                exchanges.extend(
+                    {"node": name, "i": i, "xi": xi, "exch": node.exch,
+                     "slots": n * node.exch,
+                     "rows_in": [st[f"xin{xi}_{s}"] for s in range(n)]}
+                    for xi in range(len(node.shard_spec().exchanges)))
+            if node.shard_live:
+                keyed.append({"node": name, "i": i,
+                              "live": [st[f"live{s}"] for s in range(n)]})
+        return {"shards": n, "rebalances": self.rebalances,
+                "exchanges": exchanges, "keyed": keyed}
 
     def node_skew_ratio(self, i: int) -> Optional[float]:
         """Occupancy skew ratio (max/mean bucket) of node i, or None

@@ -199,70 +199,79 @@ def _exchange_local(mesh, node, xi: int, d, abstract: bool,
     n = data_shards(mesh)
     exch = node.exch
     ex = node.shard_spec().exchanges[xi]
-    if ex.packed:
-        # pre-combined deltas carry the packed key verbatim (column 0)
-        key = d.cols[ex.key_idx[0]]
-    else:
-        key = node.pack.pack([d.cols[i] for i in ex.key_idx])
-    vn = compute_vnodes_jnp(key, VNODE_COUNT)
-    dest = _route_dest(vn, n, bounds)
-    live = d.mask & (d.sign != 0)
-    bcast = None
-    if hot_keys:
-        from .skew_stats import SK_KEY_MASK
-        k40 = key & SK_KEY_MASK
-        is_hot = jnp.zeros(key.shape, bool)
-        for hk in hot_keys:
-            is_hot = is_hot | (k40 == hk)
-        is_hot = is_hot & live
-        if xi == hot_side or not ex.carry_pk or d.pk is None:
-            bcast = is_hot                 # replicated (build) side
+    with jax.named_scope("exchange.route"):
+        if ex.packed:
+            # pre-combined deltas carry the packed key verbatim (column 0)
+            key = d.cols[ex.key_idx[0]]
         else:
-            # salted (probe) side: deterministic by row identity
-            dest = jnp.where(is_hot, (d.pk % n).astype(jnp.int32), dest)
+            key = node.pack.pack([d.cols[i] for i in ex.key_idx])
+        vn = compute_vnodes_jnp(key, VNODE_COUNT)
+        dest = _route_dest(vn, n, bounds)
+        live = d.mask & (d.sign != 0)
+        bcast = None
+        if hot_keys:
+            from .skew_stats import SK_KEY_MASK
+            k40 = key & SK_KEY_MASK
+            is_hot = jnp.zeros(key.shape, bool)
+            for hk in hot_keys:
+                is_hot = is_hot | (k40 == hk)
+            is_hot = is_hot & live
+            if xi == hot_side or not ex.carry_pk or d.pk is None:
+                bcast = is_hot             # replicated (build) side
+            else:
+                # salted (probe) side: deterministic by row identity
+                dest = jnp.where(is_hot, (d.pk % n).astype(jnp.int32), dest)
     # only the columns the node declares it reads ship over ICI; the
     # routed delta zero-fills the rest (never touched by declaration)
     ncols = len(d.cols)
     refs = list(ex.ref_idx) if ex.ref_idx is not None else list(range(ncols))
-    arrays: List[Any] = [d.cols[i] for i in refs] \
-        + [jnp.where(live, d.sign, 0).astype(jnp.int32)]
-    if ex.carry_pk:
-        arrays.append(d.pk)
-    onehot = (dest[None, :] == jnp.arange(n, dtype=jnp.int32)[:, None]) \
-        & live[None, :]
-    if bcast is not None:
-        onehot = onehot | bcast[None, :]
-    counts = jnp.sum(onehot, axis=1)
-    # max bucket fill = the "exch" capacity stat; > exch means rows were
-    # dropped this epoch -> sync detects overflow, grows, replays.
-    # Replicated copies count per destination — their HBM is real.
-    need = jnp.max(counts).astype(jnp.int64)
-    pos = jnp.cumsum(onehot, axis=1) - 1
-    bufs = []
-    if bcast is None:
-        # single-destination fast path (no hot keys): one [B] scatter
-        posr = jnp.take_along_axis(pos, dest[None, :].astype(jnp.int32),
-                                   axis=0)[0]
-        rdest = jnp.where(live, dest, n)  # OOB rows drop out of the set
-        for a in arrays:
-            buf = jnp.zeros((n, exch), dtype=a.dtype)
-            bufs.append(buf.at[rdest, posr].set(a, mode="drop"))
-    else:
-        # multi-destination scatter: a broadcast row occupies its slot
-        # in EVERY destination bucket, in the same row order
-        dd = jnp.arange(n, dtype=jnp.int32)[:, None]
-        idx = jnp.where(onehot, pos, exch)     # OOB -> dropped
-        for a in arrays:
-            buf = jnp.zeros((n, exch), dtype=a.dtype)
-            bufs.append(buf.at[dd, idx].set(
-                jnp.broadcast_to(a[None], (n,) + a.shape), mode="drop"))
+    with jax.named_scope("exchange.bucket"):
+        arrays: List[Any] = [d.cols[i] for i in refs] \
+            + [jnp.where(live, d.sign, 0).astype(jnp.int32)]
+        if ex.carry_pk:
+            arrays.append(d.pk)
+        onehot = (dest[None, :] == jnp.arange(n, dtype=jnp.int32)[:, None]) \
+            & live[None, :]
+        if bcast is not None:
+            onehot = onehot | bcast[None, :]
+        counts = jnp.sum(onehot, axis=1)
+        # max bucket fill = the "exch" capacity stat; > exch means rows
+        # were dropped this epoch -> sync detects overflow, grows, replays.
+        # Replicated copies count per destination — their HBM is real.
+        need = jnp.max(counts).astype(jnp.int64)
+        pos = jnp.cumsum(onehot, axis=1) - 1
+        bufs = []
+        if bcast is None:
+            # single-destination fast path (no hot keys): one [B] scatter
+            posr = jnp.take_along_axis(pos, dest[None, :].astype(jnp.int32),
+                                       axis=0)[0]
+            rdest = jnp.where(live, dest, n)  # OOB rows drop out of the set
+            for a in arrays:
+                buf = jnp.zeros((n, exch), dtype=a.dtype)
+                bufs.append(buf.at[rdest, posr].set(a, mode="drop"))
+        else:
+            # multi-destination scatter: a broadcast row occupies its slot
+            # in EVERY destination bucket, in the same row order
+            dd = jnp.arange(n, dtype=jnp.int32)[:, None]
+            idx = jnp.where(onehot, pos, exch)     # OOB -> dropped
+            for a in arrays:
+                buf = jnp.zeros((n, exch), dtype=a.dtype)
+                bufs.append(buf.at[dd, idx].set(
+                    jnp.broadcast_to(a[None], (n,) + a.shape), mode="drop"))
+    # live rows each destination receives this epoch (the "xin" stats):
+    # this shard's per-destination counts, summed over the source shards
+    # beside the collective that swaps the buckets
+    sent = counts.astype(jnp.int64)
     if abstract:
         recv = bufs                        # all_to_all is shape-preserving
     else:
-        recv = [jax.lax.all_to_all(b, SHARD_AXIS, split_axis=0,
-                                   concat_axis=0, tiled=False)
-                for b in bufs]
-        need = _pmax(need)
+        with jax.named_scope("exchange.a2a"):
+            recv = [jax.lax.all_to_all(b, SHARD_AXIS, split_axis=0,
+                                       concat_axis=0, tiled=False)
+                    for b in bufs]
+            need = _pmax(need)
+            sent = jax.lax.psum(sent, SHARD_AXIS)
+    rows_in = [sent[s] for s in range(n)]
     rb = n * exch
     rs = [r.reshape(rb) for r in recv]
     sign = rs[len(refs)]
@@ -271,7 +280,7 @@ def _exchange_local(mesh, node, xi: int, d, abstract: bool,
             for i in range(ncols)]
     out = Delta(cols, sign, sign != 0,
                 pk=rs[len(refs) + 1] if ex.carry_pk else None)
-    return out, need
+    return out, need, rows_in
 
 
 def exchange_apply(mesh, node, xi: int, delta, abstract: bool = False,
@@ -280,22 +289,23 @@ def exchange_apply(mesh, node, xi: int, delta, abstract: bool = False,
     """Global-view exchange of one input delta: route every live row to
     the shard owning its key's vnode block (under the routing policy —
     see `_exchange_local`). Returns (routed delta with
-    [n_shards, n_shards * exch] rows per shard, max-bucket-fill stat)."""
+    [n_shards, n_shards * exch] rows per shard, max-bucket-fill stat,
+    the live rows each destination shard receives, one scalar a shard)."""
     import jax
 
     if abstract:
         import jax.numpy as jnp
         n = data_shards(mesh)
-        out, need = _exchange_local(mesh, node, xi, _drop(delta), True,
-                                    bounds, hot_keys, hot_side)
+        out, need, rows_in = _exchange_local(
+            mesh, node, xi, _drop(delta), True, bounds, hot_keys, hot_side)
         lift = lambda t: jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), t)
-        return lift(out), need
+        return lift(out), need, rows_in
 
     def local(d):
-        out, need = _exchange_local(mesh, node, xi, _drop(d), False,
-                                    bounds, hot_keys, hot_side)
-        return _lift1(out), need
+        out, need, rows_in = _exchange_local(
+            mesh, node, xi, _drop(d), False, bounds, hot_keys, hot_side)
+        return _lift1(out), need, rows_in
 
     # specs need only the output TREE STRUCTURE (one P(shard) per leaf);
     # the abstract body mirrors it exactly
@@ -305,7 +315,8 @@ def exchange_apply(mesh, node, xi: int, delta, abstract: bool = False,
     fn = _shard_map(local, mesh=mesh,
                     in_specs=(_spec_sharded(delta),),
                     out_specs=(_spec_sharded(out_sds[0]),
-                               _spec_replicated(out_sds[1])),
+                               _spec_replicated(out_sds[1]),
+                               _spec_replicated(out_sds[2])),
                     check_vma=False)
     return fn(delta)
 
@@ -374,7 +385,8 @@ def exchange_delta(mesh, node, xi: int, delta,
     staged one (zero compile), else the jitted path (cached per mesh;
     static on the node's structural signature + mutable-capacity salt +
     routing policy, so an `exch` growth or a policy change re-traces
-    exactly this small program and nothing else)."""
+    exactly this small program and nothing else). Returns what
+    `exchange_apply` does: (routed delta, "exch" stat, "xin" stats)."""
     EXCH_STATS["calls"] += 1
     salt = _exch_salt(node, bounds)
     key = _exch_key(mesh, node, xi, salt, delta)
@@ -473,6 +485,11 @@ def sharded_apply(mesh, node, epoch_events: int, state, ins, extra,
         pad = n * ev_local - epoch_events
     names = node.stat_names
     sums = set(node.stat_sums)
+    # per-shard live entries (`Node.enable_shard_live`): the stats named
+    # in `live_stats` are high-waters, so their per-shard values are
+    # already in the one all_gather below — read before its max
+    live_idx = [names.index(s) for s in node.live_stats] \
+        if node.shard_live else []
 
     def local_body(state, ins, extra, abst: bool):
         lst = _drop(state)
@@ -501,15 +518,23 @@ def sharded_apply(mesh, node, epoch_events: int, state, ins, extra,
                 stats[names.index("rows_out")] = _nrows(live)
         if abst:
             red = list(stats)
+            if live_idx:
+                red += [sum(stats[i] for i in live_idx)] * n
         else:
             red = [jax.lax.psum(s, SHARD_AXIS) if names[i] in sums
                    else None for i, s in enumerate(stats)]
             mx = [i for i, r in enumerate(red) if r is None]
             if mx:
-                # every high-water stat in ONE collective
-                hw = _pmax(jnp.stack([stats[i] for i in mx]))
+                # every high-water stat in ONE collective (all_gather +
+                # max: see `_pmax`)
+                per_shard = jax.lax.all_gather(
+                    jnp.stack([stats[i] for i in mx]), SHARD_AXIS)
+                hw = jnp.max(per_shard, axis=0)
                 for k, i in enumerate(mx):
                     red[i] = hw[k].astype(stats[i].dtype)
+            if live_idx:
+                live = sum(per_shard[:, mx.index(i)] for i in live_idx)
+                red += [live[s] for s in range(n)]
         return st, out, red, aux
 
     if abstract:

@@ -133,7 +133,11 @@ SYSTEM_TABLES: Dict[str, Tuple[Schema, Callable[[Any], List[Tuple]]]] = {
     # bucket, share = fraction of live keys), its max/mean ratio
     # (metric='skew_ratio', share carries the ratio, value the live
     # total) and the top-K heavy-hitter candidates (metric='hot_key',
-    # key = 40-bit-truncated hot key, value = its per-epoch row count)
+    # key = 40-bit-truncated hot key, value = its per-epoch row count);
+    # mesh-sharded jobs add what each shard holds and receives
+    # (metric='shard_live', ordinal = shard, value = its live entries;
+    # metric='exchange_rows_in', key = exchange stage, value = live rows
+    # the shard received — FusedJob.shard_report)
     "rw_key_skew": (
         Schema.of(("job", T.VARCHAR), ("node", T.INT64),
                   ("type", T.VARCHAR), ("metric", T.VARCHAR),

@@ -65,6 +65,10 @@ what is finer sits inside the phases as child spans:
   rw:dispatch > rw:stats_fold      rw:device_sync > rw:stats_pull | rw:growth
   rw:commit > rw:commit.mirror > .pull | .diff | .table_commit
   rw:commit > rw:commit.job_state | rw:commit.gauges
+  rw:dispatch > rw:exchange (one an exchange stage of a mesh-sharded
+    job: `node`, `xi`, `shards`, `exch` = bucket capacity, `rows_slots` =
+    shards x exch, the rows the stage hands its step); `rw:commit.gauges`
+    of such a job carries `shard_report` (`FusedJob.shard_report()`)
   rw:compile (worker thread)       rw:ingest.poll | .pack | .h2d (stager)
   rw:pack > rw:ingest.wait (the dispatch thread blocked on the stager)
   rw:sql > rw:sql.fuse_plan

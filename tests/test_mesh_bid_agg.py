@@ -1,0 +1,235 @@
+"""The bid group-by at `mesh_shards=4` with the benchmark's defaults: the
+deployment the cell `bid-agg.mesh4` measures on four chips, at a small size
+on four of the CPU's virtual devices.
+
+Tier-1 pins the default-on traced features off (conftest); the benchmark
+runs the defaults, so this file forces them back on. The yardstick is the
+benchmark's frozen numpy reference (`benchmarks/lib/nexmark_ref.py`) and the
+configuration's own `counts` (`benchmarks/configs/nexmark-bid-agg-p4.py`),
+neither of which imports the program.
+"""
+import collections
+import importlib.util
+import os
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the configuration's .py imports `nexmark_ref` from the benchmark's lib
+sys.path.insert(0, os.path.join(ROOT, "benchmarks", "lib"))
+
+from risingwave_tpu.config import DeviceConfig  # noqa: E402
+from risingwave_tpu.core.vnode import VNODE_COUNT  # noqa: E402
+from risingwave_tpu.parallel.mesh import shard_of_vnode  # noqa: E402
+from risingwave_tpu.sql import Database  # noqa: E402
+from risingwave_tpu.utils.profile import SPANS  # noqa: E402
+
+pytestmark = pytest.mark.mesh
+
+SHARDS = 4
+CAPACITY = 1024
+ARMED = ("RW_SKEW_STATS", "RW_FLOW_STATS", "RW_AGG_PRECOMBINE",
+         "RW_STATE_TIERING")
+# (events a poll, polls an epoch, events: four epochs): 64 polls of 32 events
+# divide by 4 shards, 65 polls of 31 (2,015 events) do not: the last shard's
+# block is padded
+CADENCES = {"divides": (32, 64, 8192), "padded": (31, 65, 8060)}
+SEEDS = (1, 2**31 + 5)
+
+
+def _config_code():
+    """The configuration's .py, loaded as the benchmark's runner loads it."""
+    path = os.path.join(ROOT, "benchmarks", "configs", "nexmark-bid-agg-p4.py")
+    spec = importlib.util.spec_from_file_location("bench_config_p4", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+CODE = _config_code()
+
+
+@pytest.fixture(scope="module")
+def armed():
+    """The benchmark's configuration: every default-on traced feature on."""
+    mp = pytest.MonkeyPatch()
+    for k in ARMED:
+        mp.setenv(k, "1")
+    yield mp
+    mp.undo()
+
+
+_RUNS = {}
+
+
+def _drive(armed, seed, cadence, shards=SHARDS):
+    """One drained run of the cell's statements (cached per module):
+    (MV rows, job, the job's spans)."""
+    key = (seed, cadence, shards)
+    if key in _RUNS:
+        return _RUNS[key]
+    from risingwave_tpu.connectors.nexmark import (NexmarkConfig,
+                                                   NexmarkGenerator)
+    from risingwave_tpu.device import fuse_planner
+    chunk, polls, events = CADENCES[cadence]
+    armed.setattr(fuse_planner, "EPOCH_POLLS", polls)
+    db = Database(device=DeviceConfig(capacity=CAPACITY, mesh_shards=shards,
+                                      mv_persist_every=64),
+                  checkpoint_frequency=8)
+    db._nexmark_gen = NexmarkGenerator(NexmarkConfig(seed=seed))
+    for sql in CODE.SOURCES:
+        db.run(sql.format(events=events, chunk=chunk))
+    db.run(CODE.MV_SQL)
+    job = db.catalog.get(CODE.MV).runtime["fused_job"]
+    assert job is not None and job.program.epoch_events == chunk * polls
+    while job.counter < job.max_events or job.committed < job.counter:
+        db.tick()
+    job.sync()
+    rows = CODE.normalise(db.query(CODE.READ_SQL))
+    spans = [s for s in SPANS if s.get("inst") == job.profiler.instance]
+    _RUNS[key] = rows, job, spans
+    return _RUNS[key]
+
+
+@pytest.mark.parametrize("cadence", sorted(CADENCES))
+@pytest.mark.parametrize("seed", SEEDS)
+def test_equals_the_frozen_reference(armed, seed, cadence):
+    rows, job, _ = _drive(armed, seed, cadence)
+    assert job.program.mesh is not None \
+        and job.program.mesh.devices.size == SHARDS
+    assert job.counter == job.committed == CADENCES[cadence][2]
+    assert job.growth_replays == 0 and job.recoveries == 0
+    assert any(type(n).__name__ == "PrecombineNode"
+               for n in job.program.nodes), "the defaults pre-combine"
+    want = CODE.reference(seed, job.counter)
+    assert len(want) > 100
+    assert collections.Counter(rows) == collections.Counter(want)
+
+
+def _keyed_state_keys(job):
+    """{node index: [live packed keys of shard s, ...]} of the keyed nodes,
+    read from the device state."""
+    from risingwave_tpu.device.sorted_state import EMPTY_KEY
+    out = {}
+    for k in job.shard_report()["keyed"]:
+        st = job.states[k["i"]]
+        st = getattr(st, "inner", st)           # the state tier's wrapper
+        keys = np.asarray(getattr(st, "main", st).keys)
+        assert keys.shape[0] == SHARDS
+        out[k["i"]] = [row[row != EMPTY_KEY] for row in keys]
+    return out
+
+
+@pytest.mark.parametrize("cadence", sorted(CADENCES))
+def test_the_shares_add_up(armed, cadence):
+    """Per-shard live groups sum to the reference's group count, every key
+    sits on the shard that owns its vnode, and no key is on two."""
+    seed = SEEDS[0]
+    rows, job, _ = _drive(armed, seed, cadence)
+    groups = len(CODE.reference(seed, CADENCES[cadence][2]))
+    report = job.shard_report()
+    assert report["shards"] == SHARDS and report["rebalances"] == 0
+    assert [type(job.program.nodes[k["i"]]).__name__
+            for k in report["keyed"]] == ["AggNode", "MVKeyedNode"]
+    state = _keyed_state_keys(job)
+    for k in report["keyed"]:
+        assert sum(k["live"]) == groups
+        per_shard = state[k["i"]]
+        assert [len(x) for x in per_shard] == k["live"]
+        every = np.concatenate(per_shard)
+        assert len(np.unique(every)) == len(every) == groups
+        for s, keys in enumerate(per_shard):
+            owner = shard_of_vnode(
+                compute_vnodes_of(keys), SHARDS, VNODE_COUNT)
+            assert (owner == s).all()
+    # the same rows through the SQL surface
+    live = {(node, shard): value
+            for node, _type, metric, shard, _key, value, _share
+            in job.skew_report() if metric == "shard_live"}
+    assert live == {(k["i"], s): v for k in report["keyed"]
+                    for s, v in enumerate(k["live"])}
+
+
+def compute_vnodes_of(keys):
+    """Vnode of packed int64 keys: CRC32 of the 8 big-endian bytes, as the
+    host-side `compute_vnodes` has it for one int64 column."""
+    import zlib
+    return np.array([zlib.crc32(int(k).to_bytes(8, "big", signed=True))
+                     % VNODE_COUNT for k in keys], dtype=np.int64)
+
+
+@pytest.mark.parametrize("cadence", sorted(CADENCES))
+def test_exchange_spans_and_rows_in(armed, cadence):
+    """One `rw:exchange` span an exchange stage and epoch, inside
+    `rw:dispatch`, with the stage's sizes; the rows the shards received are
+    the pre-combined rows the configuration's `counts` reckons."""
+    seed = SEEDS[1]
+    _, job, spans = _drive(armed, seed, cadence)
+    chunk, polls, events = CADENCES[cadence]
+    epochs = events // (chunk * polls)
+    agg = next(i for i, n in enumerate(job.program.nodes)
+               if type(n).__name__ == "AggNode")
+    exch = job.program.nodes[agg].exch
+    by_id = {s["id"]: s for s in spans}
+    exchange = [s for s in spans if s["name"] == "rw:exchange"]
+    assert len(exchange) == epochs
+    for s in exchange:
+        assert (s["node"], s["xi"], s["shards"], s["exch"],
+                s["rows_slots"]) == (job.program.node_names[agg], 0,
+                                     SHARDS, exch, SHARDS * exch)
+        outer = by_id[s["parent"]]
+        assert outer["name"] == "rw:dispatch"
+        assert outer["t0"] <= s["t0"] and s["t1"] <= outer["t1"]
+    (stage,) = job.shard_report()["exchanges"]
+    assert (stage["i"], stage["xi"], stage["exch"], stage["slots"]) \
+        == (agg, 0, exch, SHARDS * exch)
+    pre = next(i for i, n in enumerate(job.program.nodes)
+               if type(n).__name__ == "PrecombineNode")
+    sent = job.program.node_stats(pre, job._stat_totals)["rows_out"]
+    assert sum(stage["rows_in"]) == sent
+    counts = CODE.counts(seed, events, chunk * polls)
+    assert sent == counts["exchange_rows"]
+    assert counts["exchange_rows_fullest"] * SHARDS >= sent
+    # the last checkpoint left the same report on its span
+    gauges = [s for s in spans if s["name"] == "rw:commit.gauges"]
+    assert gauges[-1]["shard_report"] == job.shard_report()
+
+
+def _lowered(idx, program):
+    """Node `idx`'s one-chip step as the compile service lowers it, with
+    the operations' name stacks in the text."""
+    from risingwave_tpu.device.compile_service import abstract_program_avals
+    from risingwave_tpu.device.fused import _jit_step
+    node = program.nodes[idx]
+    sds = abstract_program_avals(program.nodes, program.epoch_events,
+                                 None)[idx]
+    return _jit_step(node).lower(
+        *sds, node=node, epoch_events=program.epoch_events,
+        salt=node._mut_sig()).as_text(debug_info=True)
+
+
+def test_one_chip_programs_carry_nothing_of_the_mesh(armed):
+    """`mesh_shards=1`: no exchange scope in the agg, pre-combine and MV
+    steps, no per-shard stat in their layout, no shard report."""
+    db = Database(device=DeviceConfig(capacity=CAPACITY, mesh_shards=1,
+                                      aot_compile=False))
+    for sql in CODE.SOURCES:
+        db.run(sql.format(events=8192, chunk=32))
+    db.run(CODE.MV_SQL)
+    job = db.catalog.get(CODE.MV).runtime["fused_job"]
+    assert job.program.mesh is None and job.shard_report() is None
+    seen = set()
+    for i, node in enumerate(job.program.nodes):
+        kind = type(node).__name__
+        assert not node.shard_live and node.exch is None
+        assert not [s for s in node.stat_names
+                    if s.startswith(("live", "xin")) or s == "exch"]
+        if kind in ("AggNode", "PrecombineNode", "MVKeyedNode"):
+            text = _lowered(i, job.program)
+            if kind == "AggNode":       # the text does carry scopes
+                assert "agg.merge" in text
+            assert "exchange." not in text and "all_to_all" not in text
+            seen.add(kind)
+    assert seen == {"AggNode", "PrecombineNode", "MVKeyedNode"}
