@@ -70,4 +70,5 @@ from .sorted_state import (  # noqa: E402,F401
     lookup,
     make_state,
     merge,
+    merge_changes,
 )

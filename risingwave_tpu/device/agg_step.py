@@ -4,12 +4,15 @@ Device analog of `HashAggExecutor::apply_chunk` + barrier `flush_data`
 (`src/stream/src/executor/aggregate/hash_agg.rs:331,411`), re-shaped for XLA:
 the whole epoch's rows are applied as ONE traced program —
 
-    rows -> per-key deltas -> (lookup old outputs) -> merge -> (lookup new)
-         -> change set (insert / delete / update-pair material)
+    rows -> per-key deltas -> merge -> change set, read off the merge by
+         position (insert / delete / update-pair material)
 
 so the device never sees data-dependent control flow, and barrier-granular
 batching (parity is defined at barrier boundaries; intra-epoch order is free)
 is the optimization license, exactly the reference's shared-buffer trick.
+The change set — what every touched key held before and holds after — is
+not searched for: the merge's own sort put each delta row next to the
+state row of its key, and `sorted_state.merge_changes` reads it there.
 
 Supported device aggregates: count / count(col) / sum / avg (retractable),
 min / max — either append-only single-extreme state (cheapest, the fused
@@ -31,7 +34,7 @@ import numpy as np
 from .minput import (SortedMultiset, ms_batch_reduce, ms_find,
                      ms_group_minmax, ms_grow, ms_make, ms_merge)
 from .sorted_state import (EMPTY_KEY, ReduceKind, SortedState, batch_reduce,
-                           grow_state, lookup, make_state, merge,
+                           grow_state, make_state, merge, merge_changes,
                            sanitize_keys)
 
 # Aggregate kinds the device step supports.
@@ -203,15 +206,19 @@ def _core_tail(spec: DeviceAggSpec, state: SortedState,
     state merge + old/new change set. Shared by the raw-row path
     (`epoch_core`) and the pre-combined path (`epoch_core_combined`),
     which arrive at the same unique-delta representation from different
-    inputs. With `trail` (every epoch_core* passes it down) the change
-    set also holds the merge's `MergeTrail` as "merge_trail": a column
-    kept beside the state — the tier's touch stamps — follows its rows
-    through it by position."""
+    inputs. The change set is read off the merge by position
+    (`sorted_state.merge_changes`: the merge's `MergeTrail` says which
+    state row each delta key met), so the step searches the state for no
+    key; a truncated merge (`needed` > capacity, replayed on a grown state
+    by every caller) reads as the truncated state would. With `trail`
+    (every epoch_core* passes it down) the change set also holds that
+    trail as "merge_trail": a column kept beside the state — the tier's
+    touch stamps — follows its rows through it by position."""
     with jax.named_scope("agg.merge"):
-        old_found, old_vals = lookup(state, ukeys)
-        new_state, needed, *mtrail = merge(state, ukeys, udeltas,
-                                           spec.kinds, return_trail=trail)
-        new_found, new_vals = lookup(new_state, ukeys)
+        new_state, needed, mtrail = merge(state, ukeys, udeltas, spec.kinds,
+                                          return_trail=True)
+        old_found, old_vals, new_found, new_vals = merge_changes(
+            state, new_state, ukeys, udeltas, spec.kinds, mtrail)
     old_out, old_null = _outputs(spec, old_vals)
     new_out, new_null = _outputs(spec, new_vals)
     changes = {
@@ -225,7 +232,7 @@ def _core_tail(spec: DeviceAggSpec, state: SortedState,
         "old_vals": tuple(old_vals), "new_vals": tuple(new_vals),
     }
     if trail:
-        changes["merge_trail"] = mtrail[0]
+        changes["merge_trail"] = mtrail
     return new_state, needed, changes
 
 

@@ -8,14 +8,17 @@ jax arrays with static shapes, so an epoch apply is one jitted XLA program:
 
     delta rows --batch_reduce--> unique per-key deltas
                --merge--------> new state (+ needed-slot count for resize)
-    queries    --lookup-------> gathered payloads
+               --merge_changes-> each delta key's old and new payloads
+    queries    --lookup-------> gathered payloads (keys no merge is moving)
 
-A column that lives BESIDE the state (the state tier's touch stamps,
-`device/tiering.py`) follows its rows through a merge by position:
-`merge(..., return_trail=True)` also says how the rows moved, and
-`merged_src` of that which input row each output slot came from, so the
-caller re-aligns the column with two gathers instead of searching for
-every key again.
+Whatever has to follow rows through a merge does so by position, never by
+searching for the keys again: `merge(..., return_trail=True)` also says
+how the rows moved (`MergeTrail`). `merged_src` of it names the input row
+each output slot came from, so a column that lives BESIDE the state (the
+state tier's touch stamps, `device/tiering.py`) is re-aligned with two
+gathers; `merge_changes` reads it from the delta's side — which state row
+each delta key met and what the pair combined to — which is the agg
+step's whole change set.
 
 Empty slots hold EMPTY_KEY (int64 max) so they sort past every live key and
 binary search stays valid. Capacity growth is host-driven: `merge` reports
@@ -240,7 +243,8 @@ def compact_rows(alive: jax.Array, keys: Sequence[jax.Array],
 class MergeTrail(NamedTuple):
     """How a sort-combine-compact merge moved its rows (`merge`,
     `join_step.merge_side` with `return_trail`): what `merged_src` turns
-    into one source index per output slot."""
+    into one source index per output slot, and `merge_changes` into the
+    state row each delta key met."""
     sort_perm: jax.Array        # int32 (n,) sorted position -> input row
     same_next: jax.Array        # bool (n,) the next sorted row has this key
     compact_perm: jax.Array     # int32 (C,) output slot -> sorted position
@@ -258,6 +262,51 @@ def merged_src(trail: MergeTrail, last: bool) -> jax.Array:
         sp = jnp.where(trail.same_next,
                        jnp.concatenate([sp[1:], sp[-1:]]), sp)
     return sp[trail.compact_perm]
+
+
+def merge_changes(state: SortedState, new_state: SortedState,
+                  dkeys: jax.Array, dvals: Sequence[jax.Array],
+                  kinds: Sequence[ReduceKind], trail: MergeTrail,
+                  drop_dead: bool = True, dead_col: int = 0) -> Tuple:
+    """What `merge(state, dkeys, dvals, ...)` did to each delta key, read
+    off the merge by position: (old_found, old_vals, new_found, new_vals),
+    each aligned with `dkeys` — exactly `lookup(state, dkeys)` and
+    `lookup(new_state, dkeys)` wherever found (elsewhere garbage, as
+    there; gate on found), without a search: O(B) gathers where two
+    lookups walk log2(capacity) rounds each.
+
+    The old side: the stable sort put a key's state row directly before
+    its delta row, so in sorted space a delta row's partner is the input
+    row of the position before it (`met`, -1 where the state had none).
+    What brings that back to delta order is one two-operand sort over "is
+    a delta row" (`compact_rows` with the answer as its one column — NOT
+    a scatter): the delta is key-sorted with its pads last
+    (`batch_reduce`) and the merge's sort is stable, so the j-th delta row
+    in sorted order IS delta row j. Then one gather a payload column.
+    The new side needs no gather at all: a key's run is its state row and
+    its delta row, so the merged payload is their `_combine` (the delta's
+    own value where the state had none), the group is alive by the merge's
+    own rule (`dead_col` != 0), and of a truncated merge (`needed` >
+    capacity: the caller grows and replays) the new state holds the alive
+    keys up to its last slot's."""
+    c, b = state.capacity, dkeys.shape[0]
+    with jax.named_scope("by_position"):
+        sp = trail.sort_perm
+        same_prev = jnp.concatenate([jnp.zeros((1,), bool),
+                                     trail.same_next[:-1]])
+        met = jnp.where(same_prev, jnp.concatenate([sp[:1], sp[:-1]]), -1)
+        (met,) = compact_rows(sp >= c, [met], [], b, [-1])
+        real = dkeys != EMPTY_KEY       # a pad's neighbour is another pad
+        old_found = (met >= 0) & real
+        row = jnp.clip(met, 0, c - 1)
+        old_vals = tuple(v[row] for v in state.vals)
+        dvals = [dv.astype(ov.dtype) for dv, ov in zip(dvals, old_vals)]
+        new_vals = tuple(jnp.where(old_found, _combine(k, ov, dv), dv)
+                         for k, ov, dv in zip(kinds, old_vals, dvals))
+        new_found = real & (dkeys <= new_state.keys[c - 1])
+        if drop_dead:
+            new_found &= new_vals[dead_col] != 0
+    return old_found, old_vals, new_found, new_vals
 
 
 def merge(state: SortedState, dkeys: jax.Array,
@@ -278,8 +327,10 @@ def merge(state: SortedState, dkeys: jax.Array,
     third value, the `MergeTrail`, says how the rows moved: `merged_src` of
     it is the input row of every live output slot (an index < capacity is
     that state row, one >= capacity the delta row `index - capacity`), so
-    a column kept beside the state rides the merge by position. Without it
-    the traced program is the one it always was.
+    a column kept beside the state rides the merge by position, and
+    `merge_changes` reads each delta key's old and new payloads off it (the
+    agg step's change set). Without it the traced program is the one it
+    always was (the MV apply, `ops/`, `parallel/`).
     """
     c = state.capacity
     # named scopes are HLO metadata only: they put these stages' device
@@ -314,8 +365,11 @@ def merge(state: SortedState, dkeys: jax.Array,
 
 def lookup(state: SortedState, qkeys: jax.Array
            ) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
-    """Binary-search gather. Returns (found[B], vals at match — neutral-ish
-    garbage where not found; gate on `found`)."""
+    """Binary-search gather, for keys no merge is moving: the tier's
+    evict cores look up the keys they are about to take out. (What a merge
+    did to its own delta keys is `merge_changes`, by position; this is its
+    plain reference in the tests.) Returns (found[B], vals at match —
+    neutral-ish garbage where not found; gate on `found`)."""
     with jax.named_scope("lookup"):
         idx = jnp.searchsorted(state.keys, qkeys, method=search_method())
         idx = jnp.minimum(idx, state.capacity - 1)

@@ -61,6 +61,43 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(skip)
 
 
+def _loops(jaxpr, found=None):
+    """Every `scan` / `while` equation of a jaxpr, sub-jaxprs included."""
+    from jax.extend import core as jcore
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            found.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jcore.Jaxpr):
+                    _loops(sub, found)
+    return found
+
+
+def _loops_over(jaxpr, rows):
+    """The loops of a jaxpr that read, carry or produce an array with a
+    dimension of `rows` or more: [(primitive, [shape...])]. A binary
+    search over a state (`searchsorted` of the compile-cheap form is a
+    loop that reads the sorted run) shows here whatever its query count."""
+    out = []
+    for eqn in _loops(jaxpr):
+        long = [v.aval.shape for v in list(eqn.invars) + list(eqn.outvars)
+                if max(getattr(v.aval, "shape", ()) or (0,)) >= rows]
+        if long:
+            out.append((eqn.primitive.name, long))
+    return out
+
+
+@pytest.fixture
+def jaxpr_loops():
+    """(loops, loops_over) — the probes the structural tests of the step
+    programs share (tests/test_tiering.py, tests/test_mesh_bid_agg.py,
+    tests/test_device_state.py)."""
+    return _loops, _loops_over
+
+
 @pytest.fixture
 def mesh8():
     """The 8-shard 1-D device mesh the sharded fused path runs over in

@@ -175,3 +175,266 @@ def test_sort_cols_stable_and_compact_rows():
         got = list(np.asarray(out[1]))
         assert got[:len(want)] == want
         assert all(x == -1 for x in got[len(want):])
+
+
+# ---------------------------------------------------------------------------
+# the agg step's change set by position (ISSUE 29): `_core_tail` reads what
+# each delta key held and holds off the merge's trail. Its plain reference is
+# the by-key `lookup` of the old and of the new state (which stays in
+# sorted_state.py), and a dictionary kept by key in plain Python.
+# ---------------------------------------------------------------------------
+
+CS_CAP = 32         # slots before the grow (then 64)
+CS_EPOCHS = 18
+CS_FILL_AT = 8      # the epoch that leaves the state filled to capacity
+CS_OVER_AT = 10     # the epoch whose merge does not fit: needed > capacity
+
+# case -> (entry, call kinds, value dtype, append_only, delta rows an epoch)
+CS_CASES = {
+    "raw-count-sum": ("raw", ["count_star", "sum"], np.int64, False, 24),
+    # two payload columns: took the variadic sort before the trail
+    "raw-count": ("raw", ["count_star"], np.int64, False, 24),
+    "raw-float-avg": ("raw", ["count", "avg"], np.float64, False, 24),
+    # a delta longer than the state (a mesh shard's received delta)
+    "raw-long-delta": ("raw", ["count_star", "sum"], np.int64, False, 80),
+    # the bid group-by's spec, pre-combined rows
+    "combined-count-sum-max": ("combined", ["count_star", "sum", "max"],
+                               np.int64, True, 24),
+    "combined-long-delta": ("combined", ["count_star", "sum"], np.int64,
+                            False, 80),
+    # retractable max: the multiset side state beside the main run
+    "full-retractable-max": ("full", ["count_star", "max"], np.int64,
+                             False, 24),
+}
+
+
+def _cs_epochs(rng, rows, live_keys):
+    """CS_EPOCHS epochs of [(sign, masked, key, value)]; `live_keys()` is
+    the reference's live key set right now (the script fills the state to
+    exactly its capacity, then overflows it). Beside random traffic over 24
+    keys with retractions: scripted groups (100..104), a retraction to
+    group death (3), a delta that nets to nothing on a live key and one on
+    an absent key (4), a masked-in row of sign 0 on a live and on an absent
+    key (5), an all-masked epoch (6), rows of EMPTY_KEY (7: a mesh shard's
+    padding), the fill (8), an epoch on the full state with one death and
+    one birth (9), the overflow (10), then traffic on the grown state."""
+    held = {}                       # key -> values inserted, not retracted
+
+    def ins(k, v=None):
+        v = int(rng.integers(1, 50)) if v is None else v
+        held.setdefault(k, []).append(v)
+        return (1, True, k, v)
+
+    def retract(k):
+        return (-1, True, k, held[k].pop())
+
+    for e in range(CS_EPOCHS):
+        rows_e = []
+        if e == 0:
+            rows_e = [ins(k, 7) for k in (100, 101, 102, 103, 104)]
+        elif e == 3:
+            rows_e = [retract(104)]
+        elif e == 4:
+            rows_e = [(1, True, 100, 9), (-1, True, 100, 9),
+                      (1, True, 300, 4), (-1, True, 300, 4)]
+        elif e == 5:
+            rows_e = [(0, True, 101, 5), (0, True, 301, 5)]
+        elif e == 6:
+            rows_e = [(1, False, int(rng.integers(0, 24)), 3)
+                      for _ in range(rows)]
+        elif e == 7:
+            rows_e = [(1, False, int(EMPTY_KEY), 1)] * 3
+        elif e == CS_FILL_AT:
+            fresh = iter(range(200, 200 + CS_CAP))
+            rows_e = [ins(next(fresh))
+                      for _ in range(CS_CAP - len(live_keys()))]
+            assert len(rows_e) <= rows, "the fill does not fit one delta"
+        elif e == CS_FILL_AT + 1:
+            live = sorted(live_keys())
+            assert len(live) == CS_CAP
+            dying = next(k for k in live if len(held[k]) == 1)
+            rows_e = [retract(dying), ins(400)] + [ins(k) for k in live[:6]
+                                                   if k != dying]
+        elif e == CS_OVER_AT:
+            # new keys before, between and behind the live ones: some push
+            # live groups out of the truncated state, some fall out of it
+            rows_e = [ins(k) for k in (-5, -4, 150, 151, 500, 501, 502)] \
+                + [ins(k) for k in sorted(live_keys())[-3:]]
+        if e not in (6, CS_FILL_AT, CS_FILL_AT + 1, CS_OVER_AT):
+            for _ in range(int(rng.integers(4, 12))):
+                k = int(rng.integers(0, 24))
+                if held.get(k) and rng.random() < 0.45:
+                    rows_e.append(retract(k))
+                elif e < CS_FILL_AT or e > CS_OVER_AT or k in live_keys():
+                    rows_e.append(ins(k))
+            rows_e.append((1, False, 105, 3))       # masked out: no row
+        assert len(rows_e) <= rows
+        yield e, rows_e
+
+
+def _cs_pad(rows_e, rows, dtype):
+    sign = np.zeros(rows, np.int32)
+    mask = np.zeros(rows, bool)
+    keys = np.zeros(rows, np.int64)
+    vals = np.zeros(rows, dtype)
+    for i, (s, m, k, v) in enumerate(rows_e):
+        sign[i], mask[i], keys[i] = s, m, k
+        vals[i] = v / 4 if dtype == np.float64 else v   # exact in binary
+    return (jnp.asarray(keys), jnp.asarray(sign), jnp.asarray(mask),
+            jnp.asarray(vals))
+
+
+def _cs_fold(groups, kinds, keys, mask, deltas):
+    """The dictionary's merge: fold masked rows' payload deltas into
+    key -> [payload...] by each column's ReduceKind; a group whose
+    row_count reaches 0 at the end of the epoch is gone. Returns the keys
+    the epoch named."""
+    named = set()
+    for i in np.flatnonzero(mask):
+        k = int(keys[i])
+        named.add(k)
+        row = [d[i].item() for d in deltas]
+        g = groups.get(k)
+        if g is None:
+            groups[k] = row
+            continue
+        for c, kind in enumerate(kinds):
+            if kind == ReduceKind.SUM:
+                g[c] += row[c]
+            else:
+                g[c] = (min if kind == ReduceKind.MIN else max)(g[c], row[c])
+    for k in [k for k, g in groups.items() if g[0] == 0]:
+        del groups[k]
+    return named
+
+
+@pytest.mark.parametrize("case", list(CS_CASES))
+def test_change_set_by_position_equals_lookup(case):
+    """Drive `_core_tail` through its entries and hold, after every epoch,
+    the change set (`old_found`, `new_found`, the payload and output
+    columns wherever found) to the by-key `lookup` of the old and of the
+    new state, and the new state, `needed` and the found flags to the
+    dictionary. A merge that does not fit (`needed` > capacity) reads as
+    the truncated state does; then the state is grown as `cap_resize` and
+    `flush_epoch` grow it (`grow_state`) and the epoch replayed."""
+    import jax
+    from risingwave_tpu.device import grow_state
+    from risingwave_tpu.device.agg_step import (
+        DeviceAggState, _outputs, _row_deltas, epoch_core,
+        epoch_core_combined, epoch_core_full)
+    from risingwave_tpu.device.minput import ms_make
+    entry, call_kinds, dtype, append_only, rows = CS_CASES[case]
+    spec = DeviceAggSpec.build(call_kinds, [dtype] * len(call_kinds),
+                               append_only=append_only)
+    step = jax.jit({"raw": epoch_core, "combined": epoch_core_combined,
+                    "full": epoch_core_full}[entry], static_argnums=0)
+    row_deltas = jax.jit(_row_deltas, static_argnums=0)
+    state = spec.make_state(CS_CAP)
+    # (the multisets get room for the whole run: only the main run grows)
+    minputs = tuple(ms_make(16 * CS_CAP) for _ in spec.minputs)
+    groups = {}                     # key -> [payload...], the reference
+    seen = set()
+    rng = np.random.default_rng(29)
+    for e, rows_e in _cs_epochs(rng, rows, lambda: set(groups)):
+        keys, sign, mask, vals = _cs_pad(rows_e, rows, dtype)
+        inputs = tuple((vals, jnp.ones(rows, bool)) for _ in call_kinds)
+        deltas = row_deltas(spec, sign, mask, inputs)
+        if entry == "combined":     # AggNode.apply's mask: sign 0 is no row
+            mask = mask & (sign != 0)
+        before = {k: list(g) for k, g in groups.items()}
+        named = _cs_fold(groups, spec.kinds, np.asarray(keys),
+                         np.asarray(mask), [np.asarray(d) for d in deltas])
+        while True:
+            if entry == "raw":
+                new, needed, ch = step(spec, state, keys, sign, mask, inputs)
+            elif entry == "combined":
+                new, needed, ch = step(spec, state, keys,
+                                       jnp.ones(rows, jnp.int64), deltas,
+                                       mask)
+            else:
+                full, (needed, ms_needed), ch = step(
+                    spec, DeviceAggState(state, minputs), keys, sign, mask,
+                    inputs)
+                new, new_ms = full
+                assert all(int(m) <= 16 * CS_CAP for m in ms_needed)
+            cap = state.capacity
+            fits = int(needed) <= cap
+            assert int(needed) == len(groups), (case, e)
+            # -- the by-key reference -----------------------------------
+            ck = np.asarray(ch["keys"])
+            n = int(ch["count"])
+            assert ck[:n].tolist() == sorted(named) and \
+                (ck[n:] == EMPTY_KEY).all(), (case, e)
+            for side, st in (("old", state), ("new", new)):
+                found, ref = lookup(st, ch["keys"])
+                found = np.asarray(found)
+                assert (np.asarray(ch[f"{side}_found"]) == found).all(), \
+                    (case, e, side)
+                outs, nulls = _outputs(spec, ref)
+                for name, want in ((f"{side}_vals", ref),
+                                   (f"{side}_out", outs),
+                                   (f"{side}_null", nulls)):
+                    for got, w in zip(ch[name], want):
+                        assert got.dtype == w.dtype
+                        assert (np.asarray(got)[found]
+                                == np.asarray(w)[found]).all(), \
+                            (case, e, name)
+            # -- the dictionary -----------------------------------------
+            new_keys = np.asarray(new.keys)
+            kept = set(sorted(groups)[:cap])
+            for j in range(n):
+                k = int(ck[j])
+                assert bool(ch["old_found"][j]) == (k in before), (case, e)
+                assert bool(ch["new_found"][j]) == (k in kept), (case, e)
+                if k in before:
+                    assert [v[j].item() for v in ch["old_vals"]] \
+                        == before[k], (case, e, k)
+                if k in kept:
+                    assert [v[j].item() for v in ch["new_vals"]] \
+                        == groups[k], (case, e, k)
+            live = new_keys != EMPTY_KEY
+            assert int(new.count) == min(len(groups), cap) == live.sum()
+            assert {int(k): [v[i].item() for v in new.vals]
+                    for i, k in enumerate(new_keys) if live[i]} \
+                == {k: groups[k] for k in kept}, (case, e)
+            seen.add("fits" if fits else "truncated")
+            if fits:
+                break
+            # replay on a grown state, as every caller does
+            assert e == CS_OVER_AT, (case, e)
+            state = grow_state(state, 2 * cap, spec.kinds)
+        if e == CS_FILL_AT:
+            assert len(groups) == CS_CAP
+        state = new
+        if entry == "full":
+            minputs = new_ms
+    assert seen == {"fits", "truncated"} and state.capacity == 2 * CS_CAP
+    assert len(groups) > CS_CAP
+
+
+@pytest.mark.parametrize("entry", ["raw", "combined", "full-append-only"])
+def test_agg_entries_search_the_state_for_no_key(entry, jaxpr_loops):
+    """The change set comes by position: no entry of the agg step holds a
+    loop (`searchsorted` of the compile-cheap form is one) unless its spec
+    has retractable min / max, whose multiset side state keeps its own
+    searches."""
+    import jax
+    from risingwave_tpu.device.agg_step import (
+        DeviceAggState, epoch_core, epoch_core_combined, epoch_core_full)
+    spec = DeviceAggSpec.build(["count_star", "sum", "max"], [np.int64] * 3)
+    rows, cap = 64, 64
+    z = jnp.zeros(rows, jnp.int64)
+    state = spec.make_state(cap)
+    if entry == "combined":
+        jaxpr = jax.make_jaxpr(
+            lambda st: epoch_core_combined(spec, st, z, z, [z] * 6,
+                                           z == 0))(state)
+    else:
+        fn, st = (epoch_core, state) if entry == "raw" else \
+            (epoch_core_full, DeviceAggState(state, ()))
+        jaxpr = jax.make_jaxpr(
+            lambda st: fn(spec, st, z, z.astype(jnp.int32), z == 0,
+                          tuple((z, z == 0) for _ in spec.calls)))(st)
+    loops, _ = jaxpr_loops
+    assert loops(jaxpr.jaxpr) == []
+    assert "sort[" in str(jaxpr)    # (the probe does read primitives)

@@ -210,15 +210,21 @@ def _lowered(idx, program):
         salt=node._mut_sig()).as_text(debug_info=True)
 
 
-def test_one_chip_programs_carry_nothing_of_the_mesh(armed):
-    """`mesh_shards=1`: no exchange scope in the agg, pre-combine and MV
-    steps, no per-shard stat in their layout, no shard report."""
-    db = Database(device=DeviceConfig(capacity=CAPACITY, mesh_shards=1,
+def _planned(shards):
+    """The cell's statements planned at `mesh_shards=shards`, nothing run:
+    the fused job."""
+    db = Database(device=DeviceConfig(capacity=CAPACITY, mesh_shards=shards,
                                       aot_compile=False))
     for sql in CODE.SOURCES:
         db.run(sql.format(events=8192, chunk=32))
     db.run(CODE.MV_SQL)
-    job = db.catalog.get(CODE.MV).runtime["fused_job"]
+    return db.catalog.get(CODE.MV).runtime["fused_job"]
+
+
+def test_one_chip_programs_carry_nothing_of_the_mesh(armed):
+    """`mesh_shards=1`: no exchange scope in the agg, pre-combine and MV
+    steps, no per-shard stat in their layout, no shard report."""
+    job = _planned(1)
     assert job.program.mesh is None and job.shard_report() is None
     seen = set()
     for i, node in enumerate(job.program.nodes):
@@ -233,3 +239,33 @@ def test_one_chip_programs_carry_nothing_of_the_mesh(armed):
             assert "exchange." not in text and "all_to_all" not in text
             seen.add(kind)
     assert seen == {"AggNode", "PrecombineNode", "MVKeyedNode"}
+
+
+@pytest.mark.parametrize("shards", [SHARDS, 1])
+def test_agg_step_searches_its_state_for_no_key(armed, shards, jaxpr_loops):
+    """The agg step of the deployment, sharded (`shard_map` over four
+    devices, a received delta of `shards * exch` rows a shard) and on one
+    chip, with the benchmark's defaults armed: no `scan` / `while` of it
+    reads, carries or produces an array of `capacity` rows or more. The
+    change set is read off the merge by position
+    (`sorted_state.merge_changes`), not looked up by key."""
+    import jax
+    from risingwave_tpu.device.compile_service import abstract_program_avals
+    from risingwave_tpu.device.fused import _jit_step
+    from risingwave_tpu.device.shard_exec import sharded_jit_step
+    program = _planned(shards).program
+    assert (program.mesh is not None) == (shards > 1)
+    idx = next(i for i, n in enumerate(program.nodes)
+               if type(n).__name__ == "AggNode")
+    node = program.nodes[idx]
+    assert node.combined and node.tier and node.capacity == CAPACITY
+    sds = abstract_program_avals(program.nodes, program.epoch_events,
+                                 program.mesh)[idx]
+    step = _jit_step(node) if program.mesh is None \
+        else sharded_jit_step(program.mesh, node)
+    jaxpr = jax.make_jaxpr(lambda *a: step(
+        *a, node=node, epoch_events=program.epoch_events,
+        salt=node._mut_sig()))(*sds).jaxpr
+    assert ("shard_map" in str(jaxpr)) == (shards > 1)
+    _loops, loops_over = jaxpr_loops
+    assert loops_over(jaxpr, CAPACITY) == []

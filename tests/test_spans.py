@@ -227,7 +227,8 @@ def test_module_is_named_after_its_node_and_scopes_are_metadata(
     assert "lambda" not in hlo.split("\n", 1)[0]
     ops = re.findall(r'op_name="([^"]*)"', hlo)
     assert any("agg.merge/merge.sort" in o for o in ops)
-    assert any("agg.merge/lookup" in o for o in ops)
+    assert any("agg.merge/by_position" in o for o in ops)
+    assert not any("lookup" in o for o in ops)
     # scopes are metadata only: without them, the same program
     text = lowered.as_text()
     monkeypatch.setattr(jax, "named_scope",

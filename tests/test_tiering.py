@@ -658,21 +658,6 @@ def _drive_join_against_dictionary(rng, side):
     assert stamp[side][100] >= 3 and stamp[side][101] == 0
 
 
-def _loops(jaxpr, found=None):
-    """Every `scan` / `while` equation of a jaxpr, sub-jaxprs included."""
-    from jax.extend import core as jcore
-    found = [] if found is None else found
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name in ("scan", "while"):
-            found.append(eqn)
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                sub = getattr(sub, "jaxpr", sub)
-                if isinstance(sub, jcore.Jaxpr):
-                    _loops(sub, found)
-    return found
-
-
 def _step_jaxpr(node, n_ins, rows=48):
     import jax
     import jax.numpy as jnp
@@ -687,27 +672,35 @@ def _step_jaxpr(node, n_ins, rows=48):
 
 @pytest.mark.tiering
 @pytest.mark.parametrize("combined", [False, True])
-def test_tier_armed_agg_step_adds_no_loop(combined):
-    """The stamp rides the merge's permutations: arming the tier may add
-    gathers to the agg step, never a binary search (`searchsorted` of the
-    compile-cheap form is a `scan` loop; the by-key carry held two)."""
-    plain, armed = _tier_agg_node(combined, 1024), \
-        _tier_agg_node(combined, 1024)
+def test_tier_armed_agg_step_adds_no_loop(combined, jaxpr_loops):
+    """Neither the plain nor the tier-armed agg step searches its state:
+    no `scan` / `while` of the step reads, carries or produces an array of
+    `capacity` rows or more (`searchsorted` of the compile-cheap form is
+    such a loop). The change set comes off the merge by position
+    (`sorted_state.merge_changes`) and the stamp rides the same trail, so
+    arming the tier adds gathers, never a search. (A spec with retractable
+    min / max keeps the multiset side state's own searches.)"""
+    loops, loops_over = jaxpr_loops
+    cap = 1024
+    plain, armed = _tier_agg_node(combined, cap), _tier_agg_node(combined, cap)
     armed.enable_tiering()
-    n_plain = len(_loops(_step_jaxpr(plain, 1)))
-    assert n_plain >= 2                     # the merge's two lookups
-    assert len(_loops(_step_jaxpr(armed, 1))) <= n_plain
+    for node in (plain, armed):
+        for rows in (48, cap):      # a short delta, and one as long as the state
+            jaxpr = _step_jaxpr(node, 1, rows)
+            assert loops_over(jaxpr, cap) == [], (node.tier, rows)
+    assert len(loops(_step_jaxpr(armed, 1))) \
+        <= len(loops(_step_jaxpr(plain, 1)))
 
 
 @pytest.mark.tiering
-def test_tier_armed_join_step_searches_follow_the_delta():
+def test_tier_armed_join_step_searches_follow_the_delta(jaxpr_loops):
     """No loop of the tier-armed join step carries an array as long as a
     side: whatever it searches for, it asks once per delta row (48) or
     pair slot (256), never once per slot of the side (1024)."""
     cap = 1024
     armed = _tier_join_node(cap)
     armed.enable_tiering()
-    loops = _loops(_step_jaxpr(armed, 2))
+    loops = jaxpr_loops[0](_step_jaxpr(armed, 2))
     assert loops
     for eqn in loops:
         side_long = [v.aval.shape for v in eqn.outvars
