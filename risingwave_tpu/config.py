@@ -11,7 +11,7 @@
 
 The device tier (`DeviceConfig`) governs the SQL->device dispatch seam:
 whether eligible plan fragments lower onto the TPU executors and over
-which mesh.
+how many chips a fused job is sharded.
 """
 from __future__ import annotations
 
@@ -23,23 +23,20 @@ from typing import Any, Dict, Optional
 class DeviceConfig:
     """Device-path lowering config (the `from_proto` dispatch policy).
 
-    mesh      — jax.sharding.Mesh to shard operator state over; None = one
-                chip (still jitted epoch steps, no collectives).
     capacity  — initial per-operator state slots (grows by pow2 on demand).
     minmax    — lower min/max aggregates onto the retractable sorted-
                 multiset state (device/minput.py).
     """
-    mesh: Optional[Any] = None
     capacity: int = 1024
     minmax: bool = True
-    # mesh-sharded FUSED programs (device/shard_exec.py): eligible fused
+    # chips a job is sharded over (device/shard_exec.py): eligible fused
     # MV fragments execute as ONE shard_map'd epoch program over an
     # n-device 1-D mesh — node state carries a leading shard axis with a
     # vnode-keyed PartitionSpec, the cross-vnode shuffle for joins/aggs
     # runs as an in-program all_to_all over ICI, and global stats reduce
-    # via psum/pmax. 1 = today's single-chip fused path, byte-for-byte
-    # unchanged. Distinct from `mesh`, which shards the PER-OPERATOR
-    # host executors (parallel/sharded_*) and disables fusion.
+    # via psum/pmax. 1 = the single-chip fused path. An MV the fuse
+    # planner rejects runs on the one-chip per-operator executors
+    # (ops/device_agg.py, ops/device_join.py) whatever this says.
     mesh_shards: int = 1
     # serving replicas (parallel/mesh.REPLICA_AXIS): the fused mesh
     # becomes (mesh_shards, replicas) with state sharded over the data
@@ -452,7 +449,8 @@ def resolve_device(device) -> Optional[DeviceConfig]:
 
     None | "off"      -> host-only execution
     "on" | "single"   -> device path on one chip
-    int n             -> device path sharded over an n-device mesh
+    int n             -> DeviceConfig(mesh_shards=n): fused jobs sharded
+                         over n chips
     DeviceConfig      -> as given
     """
     if device is None or device == "off":
@@ -462,8 +460,7 @@ def resolve_device(device) -> Optional[DeviceConfig]:
     elif device in ("on", "single"):
         cfg = DeviceConfig()
     elif isinstance(device, int):
-        from .parallel import make_mesh
-        cfg = DeviceConfig(mesh=make_mesh(device))
+        cfg = DeviceConfig(mesh_shards=device)
     else:
         raise ValueError(f"bad device config {device!r}")
     if cfg.compile_cache_dir is not None:

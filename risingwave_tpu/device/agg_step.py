@@ -240,8 +240,8 @@ def epoch_core(spec: DeviceAggSpec, state: SortedState,
                keys: jax.Array, signs: jax.Array, mask: jax.Array,
                inputs: Tuple[Tuple[jax.Array, jax.Array], ...],
                trail: bool = False):
-    """The (un-jitted) epoch pipeline, shared by the single-chip step below
-    and the shard-local body of parallel/sharded_agg.py."""
+    """The (un-jitted) epoch pipeline under the jitted steps below and the
+    fused job's `AggNode` (device/fused.py)."""
     with jax.named_scope("agg.reduce_delta"):
         deltas = _row_deltas(spec, signs, mask, inputs)
         ukeys, udeltas, ucount = batch_reduce(keys, mask, deltas,

@@ -518,8 +518,8 @@ def try_fuse(execu, ns, device_cfg, name: str,
     indices, so a different plan can never inherit them.
     """
     from ..ops import ProjectExecutor
-    if device_cfg is None or getattr(device_cfg, "mesh", None) is not None:
-        return None        # fused path is single-chip; mesh uses sharded ops
+    if device_cfg is None:
+        return None
     try:
         f = _Fuser(device_cfg)
         if not isinstance(execu, ProjectExecutor):

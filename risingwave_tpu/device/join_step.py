@@ -166,8 +166,8 @@ def join_core(a: JoinSide, b: JoinSide,
               b_jk, b_pk, b_sign, b_mask, b_vals, m: int,
               trail: bool = False):
     """One epoch of both sides' rows -> (new states, pair change set).
-    Unjitted core, shared by the single-chip step below and the shard-local
-    body of parallel/sharded_join.py. With `trail` a sixth value holds the
+    Unjitted core, shared by the per-operator engine's step below and the
+    fused `JoinNode` (device/fused.py). With `trail` a sixth value holds the
     two sides' `MergeTrail`s (merge_side).
 
     Pair change set: for each emitted pair, sign = producing delta's sign

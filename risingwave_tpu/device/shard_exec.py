@@ -9,9 +9,8 @@ as:
 
 * **State partitioning** — every stateful node's arrays gain a leading
   `[n_shards, ...]` axis; shard s owns the contiguous vnode block
-  `vnode_block_bounds(n)[s] : [s+1]` of group/join keys, the same
-  contiguous-block layout the host-side sharded operators and rescale
-  use (a shard's key range stays compact for the sorted-run state).
+  `vnode_block_bounds(n)[s] : [s+1]` of group/join keys (contiguous
+  blocks keep a shard's key range compact for the sorted-run state).
 
 * **In-program exchange** — the cross-vnode shuffle joins/aggs need
   (rows whose key hashes to another shard's vnode block) is an

@@ -11,7 +11,7 @@ same): count(*) decides group liveness — a group whose row count reaches 0
 emits a DELETE and drops its state.
 
 The TPU device path for the int-keyed sum/count/min/max subset lives in
-`risingwave_tpu/device/agg_step.py` (sharded: `parallel/sharded_agg.py`);
+`risingwave_tpu/device/agg_step.py` (sharded: `device/shard_exec.py`);
 this host implementation is the exact path and the fallback for decimals
 and other host-only types.
 """

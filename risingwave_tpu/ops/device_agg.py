@@ -6,8 +6,10 @@ aggregation fragment onto this executor instead of the per-row host
 `HashAggExecutor`. Protocol-identical from the outside — consumes
 Chunk|Barrier|Watermark, emits barrier-aligned change chunks, commits its
 state table — but the group maintenance runs as ONE jitted XLA program per
-epoch (`device/agg_step.py`; sharded over a mesh via
-`parallel/sharded_agg.py`).
+epoch (`device/agg_step.py`) on one chip. A job shards over several
+chips as a fused program (`DeviceConfig.mesh_shards`,
+`device/shard_exec.py`); this executor is what an MV the fuse planner
+rejects runs on, and the tree `device/fuse_planner.py` matches.
 
 Exactness contract:
 * group keys: lossless bit-packing for narrow keys, hash64 + host decode
@@ -123,8 +125,7 @@ class DeviceHashAggExecutor(UnaryExecutor):
                  calls: Sequence[AggCall],
                  state_table: Optional[StateTable] = None,
                  minput_tables: Sequence[StateTable] = (),
-                 mesh: Optional[Any] = None, capacity: int = 1024,
-                 append_only: bool = False):
+                 capacity: int = 1024, append_only: bool = False):
         in_schema = input.schema
         fields = [in_schema.fields[i] for i in group_key_indices]
         fields += [Field(f"agg#{i}", c.return_type)
@@ -163,42 +164,9 @@ class DeviceHashAggExecutor(UnaryExecutor):
                                if c.kind in ("sum", "avg")
                                and not np.issubdtype(
                                    np.dtype(dc.acc_dtype), np.floating)]
-        self.mesh = mesh
-        self._capacity = capacity
-        self.engine: Any = self._make_engine(mesh, capacity)
-
-    def _make_engine(self, mesh: Optional[Any], capacity: int) -> Any:
-        if mesh is not None:
-            from ..parallel.sharded_agg import ShardedHashAgg
-            return ShardedHashAgg(self.spec, mesh, capacity=capacity,
-                                  pull_formatted=False)
         from ..device.agg_step import DeviceHashAgg
-        return DeviceHashAgg(self.spec, capacity=capacity,
-                             pull_formatted=False)
-
-    def rescale_mesh(self, mesh: Optional[Any]) -> None:
-        """Barrier-boundary elastic rescale (`scale.rs:2329` analog):
-        lift the live device state off the old mesh and re-install it
-        vnode-sharded onto the new one (None = single chip). The caller
-        (Database._alter_parallelism) guarantees the in-flight barrier
-        committed, so the epoch buffers are empty."""
-        assert not getattr(self.engine, "_keys", None) \
-            and not getattr(self.engine, "_rows", None), \
-            "rescale requires a barrier boundary (buffered rows pending)"
-        n_new = mesh.devices.size if mesh is not None else 1
-        n_old = self.mesh.devices.size if self.mesh is not None else 1
-        if n_new == n_old:
-            return
-        keys, vals = self.engine.live_main()
-        minputs = [self.engine.live_minput(mi)
-                   for mi in range(len(self.spec.minputs))]
-        self.mesh = mesh
-        self.engine = self._make_engine(mesh, self._capacity)
-        if len(keys):
-            self.engine.load_state(keys, vals)
-        for mi, (k1, k2, cnt) in enumerate(minputs):
-            if len(k1):
-                self.engine.load_minput(mi, k1, k2, cnt)
+        self.engine = DeviceHashAgg(self.spec, capacity=capacity,
+                                    pull_formatted=False)
 
     # ---- recovery -------------------------------------------------------
     def _recover(self) -> None:

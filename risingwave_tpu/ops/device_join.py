@@ -3,8 +3,9 @@
 The dispatch-seam sibling of `ops/device_agg.py` for the reference's
 north-star op (`src/stream/src/executor/hash_join.rs:575-686`): an INNER
 equi-join whose match-finding runs as one jitted epoch step over sorted
-(join_key, row_id) multimaps in HBM (`device/join_step.py`; sharded with a
-two-sided all_to_all via `parallel/sharded_join.py`).
+(join_key, row_id) multimaps in HBM (`device/join_step.py`) on one chip.
+A join shards over several chips as a fused program
+(`DeviceConfig.mesh_shards`, `device/shard_exec.py`).
 
 Division of labor:
 * device — the quadratic part: per-epoch delta reduce, sorted-multimap
@@ -69,7 +70,6 @@ class DeviceHashJoinExecutor(Executor):
                  condition: Optional[Expr] = None,
                  left_state: Optional[StateTable] = None,
                  right_state: Optional[StateTable] = None,
-                 mesh: Optional[Any] = None,
                  capacity: int = 1024, pair_capacity: int = 4096,
                  max_chunk_size: int = 1024):
         schema = left.schema.concat(right.schema)
@@ -82,10 +82,9 @@ class DeviceHashJoinExecutor(Executor):
         self.state_tables = {"a": left_state, "b": right_state}
         self._recovered = left_state is None and right_state is None
         self.max_chunk_size = max_chunk_size
-        self.mesh = mesh
-        self._capacity = capacity
-        self._pair_capacity = pair_capacity
-        self.engine: Any = self._make_engine(mesh)
+        from ..device.join_step import DeviceHashJoin
+        self.engine = DeviceHashJoin([], [], capacity=capacity,
+                                     pair_capacity=pair_capacity)
         self.dicts = {"a": _RowDict(), "b": _RowDict()}
         # per-epoch net state-row changes: rh -> (net sign, row). Drives
         # both state-table persistence and row-cache eviction — an entry is
@@ -99,39 +98,6 @@ class DeviceHashJoinExecutor(Executor):
         self._wm: Dict[str, Dict[int, Any]] = {"a": {}, "b": {}}
         self._emitted_wm: Dict[int, Any] = {}
         self._clean_wm: Dict[int, Any] = {}
-
-    def _make_engine(self, mesh: Optional[Any]) -> Any:
-        if mesh is not None:
-            from ..parallel.sharded_join import ShardedHashJoin
-            return ShardedHashJoin([], [], mesh, capacity=self._capacity,
-                                   pair_capacity=self._pair_capacity)
-        from ..device.join_step import DeviceHashJoin
-        return DeviceHashJoin([], [], capacity=self._capacity,
-                              pair_capacity=self._pair_capacity)
-
-    def rescale_mesh(self, mesh: Optional[Any]) -> None:
-        """Barrier-boundary elastic rescale: rebuild the engine on the new
-        mesh and lazily re-load both sides from the committed state tables
-        (the recovery path — join state is fully durable per barrier, so
-        re-recovery IS the reshard)."""
-        buf = getattr(self.engine, "_buf", None)
-        assert not buf or not any(buf.values()), \
-            "rescale requires a barrier boundary (buffered rows pending)"
-        n_new = mesh.devices.size if mesh is not None else 1
-        n_old = self.mesh.devices.size if self.mesh is not None else 1
-        if n_new == n_old:
-            return
-        assert all(st is not None for st in self.state_tables.values()), \
-            "join rescale requires state tables (re-recovery reshard)"
-        self.mesh = mesh
-        self.engine = self._make_engine(mesh)
-        self.dicts = {"a": _RowDict(), "b": _RowDict()}
-        self._epoch_net = {"a": {}, "b": {}}
-        # eager: the execute() generator only checks _recovered at stream
-        # start, which already ran — reload both sides now (tables are
-        # committed; the caller is at a barrier boundary)
-        self._recovered = False
-        self._recover()
 
     # ---- recovery -------------------------------------------------------
     def _recover(self) -> None:

@@ -6,7 +6,7 @@ vis-bitmap building + U-pair fixing `:843-930`; Broadcast/Simple/RoundRobin
 `exchange/permit.rs:35` (credit-based backpressure channel).
 
 In the TPU runtime the device-side exchange is one all-to-all inside the
-jitted epoch step (`parallel/sharded_agg.py`); these HOST executors exist
+jitted epoch step (`device/shard_exec.py`); these HOST executors exist
 for multi-fragment host pipelines (different operators at different
 parallelism) and for the multi-host DCN path, where chunks move between
 processes — the same two-tier split the reference has between in-process

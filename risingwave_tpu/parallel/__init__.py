@@ -14,4 +14,3 @@ no per-channel alignment machinery is needed. Backpressure degenerates to the
 host feeding epochs one at a time.
 """
 from .mesh import make_mesh, shard_of_vnode, vnode_block_bounds  # noqa: F401
-from .sharded_agg import ShardedHashAgg, make_sharded_agg_step  # noqa: F401

@@ -1149,10 +1149,26 @@ class _RemoteSetBase:
 
     # ---- lifecycle ------------------------------------------------------
     def shutdown(self) -> None:
+        """Kill the workers and wait until nothing of this set runs: a
+        drain thread outlives its worker by the frames still in its
+        socket, and while it reads them it evaluates the process-global
+        `fragment.drain` failpoint — a point armed after a shutdown that
+        returned early would fire on this dead set, not on the one it
+        was armed for."""
         for w in self.workers:
             if w.proc.poll() is None:
                 w.proc.kill()
         self.server.close()
+        for ch in getattr(self, "channels", ()):
+            ch.close()          # unblocks a drain waiting on capacity
+        for w in self.workers:
+            try:
+                w.proc.wait(timeout=10)
+            except subprocess.TimeoutExpired:
+                continue        # unkillable: its drain cannot be joined
+            t = w.drain_thread
+            if t is not None and t is not threading.current_thread():
+                t.join(timeout=10)
 
     def __del__(self):  # dropped plans must not leak worker processes
         try:

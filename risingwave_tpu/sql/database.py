@@ -260,10 +260,9 @@ class Database:
     def _device_mode_str(self) -> str:
         if self.device is None:
             return "off"
-        mode = ("mesh:%d" % self.device.mesh.devices.size
-                if self.device.mesh is not None else "single")
+        mode = "single"
         ms = getattr(self.device, "mesh_shards", 1) or 1
-        if self.device.mesh is None and ms > 1:
+        if ms > 1:
             # mesh-sharded FUSED programs: state layouts are per-shard,
             # so a reopen must shard identically. Replicas MIRROR state
             # (layouts unchanged) but the marker still records them —
@@ -294,17 +293,19 @@ class Database:
         minmax = parts[-1] == "minmax"
         if minmax:
             parts = parts[:-1]
-        if parts[0] == "single":
-            ms = 1
-            reps = 1
-            if len(parts) > 1 and parts[1].startswith("fshard"):
-                ms = int(parts[1][len("fshard"):])
-            if len(parts) > 2 and parts[2].startswith("rep"):
-                reps = int(parts[2][len("rep"):])
-            return DeviceConfig(minmax=minmax, mesh_shards=ms,
-                                replicas=reps)
-        from ..parallel import make_mesh
-        return DeviceConfig(mesh=make_mesh(int(parts[1])), minmax=minmax)
+        if parts[0] != "single":
+            raise ValueError(
+                f"data directory {data_dir!r} carries the device marker "
+                f"{mode!r}, which names no dispatch policy: jobs shard "
+                "by DeviceConfig.mesh_shards (marker 'single:fshardN'); "
+                "recreate the directory under it")
+        ms = 1
+        reps = 1
+        if len(parts) > 1 and parts[1].startswith("fshard"):
+            ms = int(parts[1][len("fshard"):])
+        if len(parts) > 2 and parts[2].startswith("rep"):
+            reps = int(parts[2][len("rep"):])
+        return DeviceConfig(minmax=minmax, mesh_shards=ms, replicas=reps)
 
     def _check_device_marker(self) -> None:
         """Durable stores record the dispatch policy that shaped their state
@@ -503,7 +504,7 @@ class Database:
                        "state_table": mv_table, "shared": shared,
                        "port": shared.subscribe()}
         # Virtual source (fused device path): a nexmark source under a
-        # single-chip device policy does NOT start a host datagen job —
+        # fusing device policy does NOT start a host datagen job —
         # fused MVs regenerate events on device. The host chain is built
         # (for planning and as the fallback) but activates lazily, only if
         # a non-fusable consumer appears (_activate_source). Matches the
@@ -511,8 +512,7 @@ class Database:
         # (`create_source.rs` — sources are passive until subscribed).
         obj.runtime["virtual"] = (stmt.is_source and connector == "nexmark"
                                   and self.device is not None
-                                  and self.device.fuse
-                                  and self.device.mesh is None)
+                                  and self.device.fuse)
         self.catalog.create(obj)
         if not obj.runtime["virtual"]:
             self._iters[stmt.name] = obj.runtime["port"].execute()
@@ -918,52 +918,30 @@ class Database:
         return self.system_params.get(stmt.name)
 
     def _alter_parallelism(self, stmt: A.AlterParallelism) -> str:
-        """Elastic scale-out/in of one job's device-sharded operators
-        (`src/meta/src/stream/scale.rs:2329` reschedule analog).
-
-        Runs at a barrier boundary: `flush()` completes the in-flight
-        barrier on every job first (all epoch buffers empty, state
-        committed), then each device engine re-shards its vnode-mapped
-        state onto an n-device mesh (`parallel/rescale.py`). Logged to the
-        DDL log, so recovery replays the same topology — engines that
-        recover AFTER the replayed rescale load their rows straight onto
-        the new mesh."""
+        """ALTER MATERIALIZED VIEW ... SET PARALLELISM n: records the
+        job's parallelism in the catalog and the DDL log at a barrier
+        boundary (`src/meta/src/stream/scale.rs:2329` is the reference's
+        reschedule). It moves no state: a device job takes its shard
+        count from `DeviceConfig.mesh_shards` when it is created, so the
+        statement is refused on a database with a device policy."""
         obj = self.catalog.get(stmt.name)
         if obj.kind != "mv":
             raise ValueError(f"{stmt.name!r} is not a materialized view")
-        if (obj.runtime or {}).get("fused_job") is not None:
-            raise ValueError(
-                f"{stmt.name!r} runs as a fused single-chip device job; "
-                "create the database with a device mesh to shard it")
         n = stmt.parallelism
         if n < 1:
             raise ValueError("PARALLELISM must be >= 1")
         if not self._replaying:
+            if self.device is not None:
+                raise ValueError(
+                    f"cannot re-shard {stmt.name!r}: device jobs take "
+                    "their shard count from DeviceConfig.mesh_shards at "
+                    "creation")
             # barrier boundary; during DDL-log replay the dataflow is
             # half-rebuilt and ticking it would feed sources into only the
             # already-replayed jobs (buffers are empty anyway on replay)
             self.flush()
-        from ..parallel import make_mesh
-        mesh = make_mesh(n) if n > 1 else None
-        rescaled = 0
-        stack = [obj.runtime["shared"].upstream]
-        seen = set()
-        while stack:
-            e = stack.pop()
-            if id(e) in seen:
-                continue
-            seen.add(id(e))
-            if hasattr(e, "rescale_mesh"):
-                e.rescale_mesh(mesh)
-                rescaled += 1
-            for attr in ("input", "port", "left_exec", "right_exec",
-                         "barrier_source"):
-                c = getattr(e, attr, None)
-                if c is not None:
-                    stack.append(c)
-            stack.extend(getattr(e, "inputs", ()))   # Union/Merge children
         obj.parallelism = n
-        return f"ALTER_PARALLELISM_{rescaled}"
+        return "ALTER_PARALLELISM_0"
 
     def _create_function(self, stmt: A.CreateFunction) -> str:
         """CREATE FUNCTION ... LANGUAGE python (`udf/python.rs` analog):

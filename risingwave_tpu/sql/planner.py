@@ -328,7 +328,6 @@ class Planner:
                    for _ in range(device_minput_count(calls, ao))]
             return DeviceHashAggExecutor(input, group_indices, calls,
                                          state_table=st, minput_tables=mts,
-                                         mesh=self.device.mesh,
                                          capacity=self.device.capacity,
                                          append_only=ao)
         if self.parallelism > 1 and group_indices and not eowc \
@@ -598,7 +597,7 @@ class Planner:
             execu: Executor = DeviceHashJoinExecutor(
                 lexec, rexec, lkeys, rkeys, condition=cond,
                 left_state=left_state, right_state=right_state,
-                mesh=self.device.mesh, capacity=self.device.capacity)
+                capacity=self.device.capacity)
         elif self.parallelism > 1 \
                 and getattr(self, "placement", "local") == "process" \
                 and cond is None \

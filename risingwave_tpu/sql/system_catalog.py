@@ -333,9 +333,6 @@ def _label(e) -> str:
     ki = getattr(e, "key_idx", None)
     if isinstance(ki, dict):
         bits.append(f"on={ki.get('a')}={ki.get('b')}")
-    mesh = getattr(e, "mesh", None)
-    if mesh is not None:
-        bits.append(f"mesh={mesh.devices.size}")
     if getattr(e, "append_only", False):
         bits.append("append_only")
     return name + (" { " + ", ".join(bits) + " }" if bits else "")

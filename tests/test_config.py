@@ -99,3 +99,42 @@ def test_device_section_typo_fails_even_when_off(tmp_path):
     from risingwave_tpu.config import NodeConfig
     with pytest.raises(ValueError, match="unknown config key"):
         NodeConfig.from_toml(str(p))
+
+
+def test_device_mode_int_is_mesh_shards(tmp_path):
+    """One spelling of "n chips": an int device argument — and TOML
+    `mode = "<n>"` — is the fused job's shard count."""
+    from risingwave_tpu.config import DeviceConfig, resolve_device
+    p = tmp_path / "rw.toml"
+    p.write_text("[device]\nmode = '8'\n")
+    cfg = NodeConfig.from_toml(str(p)).device
+    assert cfg.mesh_shards == 8
+    assert cfg == resolve_device(8) == DeviceConfig(mesh_shards=8)
+
+
+def test_device_int_marker_is_fshard_and_reopens(tmp_path):
+    import json
+    d = str(tmp_path)
+    db = Database(data_dir=d, device=8)
+    db.run("CREATE TABLE t (k INT, v BIGINT)")
+    db.run("CREATE MATERIALIZED VIEW mv AS SELECT k, sum(v) AS s "
+           "FROM t GROUP BY k")
+    db.run("INSERT INTO t VALUES (1, 10), (2, 20), (1, 5)")
+    before = sorted(db.query("SELECT * FROM mv"))
+    with open(tmp_path / "device_mode.json") as f:
+        assert json.load(f)["mode"] == "single:fshard8:minmax"
+    assert Database._device_from_marker(d).mesh_shards == 8
+    db2 = Database(data_dir=d, device=8)
+    assert sorted(db2.query("SELECT * FROM mv")) == before == [(1, 15),
+                                                               (2, 20)]
+
+
+def test_per_operator_mesh_marker_is_refused(tmp_path):
+    """A directory whose state was laid out by the per-operator mesh
+    executors is not guessed at: the error names the marker and the
+    option that shards a job now."""
+    (tmp_path / "device_mode.json").write_text('{"mode": "mesh:8:minmax"}')
+    with pytest.raises(ValueError, match=r"mesh:8:minmax.*mesh_shards"):
+        Database(data_dir=str(tmp_path), device="auto")
+    with pytest.raises(ValueError, match=r"mesh:8:minmax"):
+        Database(data_dir=str(tmp_path), device=8)

@@ -62,11 +62,18 @@ ON AuctionBids.starttime = MaxBids.starttime_c
 
 
 def _run(mv_sql, name, shards, srcs=(BID_SRC,), n=N, capacity=512,
-         aot=False, data_dir=None, keep=False):
-    db = Database(device=DeviceConfig(capacity=capacity,
-                                      mesh_shards=shards,
-                                      aot_compile=aot),
-                  data_dir=data_dir)
+         aot=False, data_dir=None, keep=False, int_device=False):
+    if int_device:
+        # the user's spelling of "n chips"; sized like every other run of
+        # this file so that it compiles no program of its own
+        db = Database(device=shards, data_dir=data_dir)
+        assert db.device.mesh_shards == shards
+        db.device.capacity, db.device.aot_compile = capacity, aot
+    else:
+        db = Database(device=DeviceConfig(capacity=capacity,
+                                          mesh_shards=shards,
+                                          aot_compile=aot),
+                      data_dir=data_dir)
     for s in srcs:
         db.run(s.format(n=n, c=CHUNK))
     db.run(mv_sql)
@@ -134,6 +141,25 @@ def test_q1_agg_bit_identity():
     r8, j8, _ = _run(Q1_MV, "q1a", 8)
     assert r1 == r8                     # bit-identical, ORDER included
     assert j8.plan_hash != j1.plan_hash  # per-shard state never collides
+
+
+@pytest.mark.mesh
+def test_device_int_fuses_over_the_mesh():
+    """`Database(device=8)` is `mesh_shards=8`: the group-by fuses into
+    one program over all 8 devices (`_run` asserts the mesh's size) and
+    its MV is the one-chip run's, row for row."""
+    r8, _, _ = _run(Q1_MV, "q1a", 8, int_device=True)
+    r1, _, _ = _run(Q1_MV, "q1a", 1)
+    assert len(r1) > 0 and r8 == r1
+
+
+def test_device_int_keeps_the_nexmark_source_virtual():
+    """Under `device=8` fused MVs make their events on the device, so the
+    source starts no host datagen until a non-fusable consumer appears."""
+    db = Database(device=8)
+    db.run(BID_SRC.format(n=N, c=CHUNK))
+    assert db.catalog.get("bid").runtime["virtual"] is True
+    assert "bid" not in db._iters
 
 
 @pytest.mark.mesh

@@ -87,7 +87,11 @@ def _q3_oracle(n, chunk, ticks):
     for _ in range(ticks):
         db.tick()
     rows = sorted(db.query("SELECT * FROM q3"))
-    find_remote(db, "q3").shutdown()
+    rfs = find_remote(db, "q3")
+    rfs.shutdown()
+    # shutdown waits: a drain still reading its dead worker's last frames
+    # would spend the NEXT test's armed `fragment.drain` on this set
+    assert not any(w.drain_thread.is_alive() for w in rfs.workers)
     return rows
 
 
