@@ -33,9 +33,9 @@ import numpy as np
 
 from .minput import (SortedMultiset, ms_batch_reduce, ms_find,
                      ms_group_minmax, ms_grow, ms_make, ms_merge)
-from .sorted_state import (EMPTY_KEY, ReduceKind, SortedState, batch_reduce,
-                           grow_state, make_state, merge, merge_changes,
-                           sanitize_keys)
+from .sorted_state import (EMPTY_KEY, ReduceKind, SortedState, _neutral,
+                           batch_reduce, grow_state, make_state, merge,
+                           merge_changes, sanitize_keys)
 
 # Aggregate kinds the device step supports.
 DEVICE_AGG_KINDS = ("count", "count_star", "sum", "avg", "min", "max")
@@ -163,7 +163,6 @@ def _row_deltas(spec: DeviceAggSpec, signs, mask,
             deltas[call.cols[0]] = sv
         else:  # min / max — append-only: neutral where invalid
             kind = spec.kinds[call.cols[0]]
-            from .sorted_state import _neutral
             v = jnp.where(valid & mask, vals.astype(call.acc_dtype),
                           _neutral(kind, call.acc_dtype))
             deltas[call.cols[0]] = v
@@ -255,14 +254,22 @@ def precombine_core(spec: DeviceAggSpec,
     """Local pre-combine ("Global Hash Tables Strike Back!": per-partition
     pre-aggregation before the global merge): collapse an epoch's raw
     rows to ONE partial-aggregate row per unique group key. Returns
-    (ukeys, ucnt, udeltas): key-sorted with EMPTY_KEY padding, live rows
-    a prefix; `ucnt` is the exact raw-row count behind each combined row
-    (the downstream rows_in stat and the heavy-hitter evidence).
+    (ukeys, ucnt, udeltas); `ucnt` is the exact raw-row count behind each
+    combined row (the downstream rows_in stat and the heavy-hitter
+    evidence).
+
+    THE OUTPUT CONTRACT (what `batch_reduce` leaves; stated here once and
+    named where it is relied on — `epoch_core_combined`'s pass-through,
+    `sorted_state.merge_changes`): keys UNIQUE and ASCENDING, the live
+    rows a PREFIX, every row behind them a pad of EMPTY_KEY with the
+    neutral payload of its column's kind (`ucnt` 0).
+
     Exactness: the per-key delta columns combine by the SAME associative
     reductions (`spec.kinds`) the state merge applies, so combining here
-    and re-combining after the exchange is bit-identical to merging raw
-    rows — the caller guarantees integer-only SUM columns (float sums
-    are order-sensitive) and no multiset side state."""
+    — and, behind an exchange, re-combining the source shards' partials —
+    is bit-identical to merging raw rows; the caller guarantees
+    integer-only SUM columns (float sums are order-sensitive) and no
+    multiset side state."""
     with jax.named_scope("agg.reduce_delta"):
         live = mask & (signs != 0)
         deltas = _row_deltas(spec, signs, mask, inputs)
@@ -274,18 +281,39 @@ def precombine_core(spec: DeviceAggSpec,
 
 def epoch_core_combined(spec: DeviceAggSpec, state: SortedState,
                         keys: jax.Array, counts: jax.Array,
-                        dvals, mask: jax.Array, trail: bool = False):
+                        dvals, mask: jax.Array, trail: bool = False,
+                        recombine: bool = True):
     """Epoch pipeline over PRE-COMBINED rows: each input row is already a
-    (key, raw-row count, per-column partial delta) tuple — one per key
-    per upstream partition (several partitions' partials for one key may
-    arrive under mesh sharding; the batch_reduce here re-combines them).
-    Returns (new_state, needed, changes) exactly like `epoch_core`, plus
+    (key, raw-row count, per-column partial delta) tuple. Returns
+    (new_state, needed, changes) exactly like `epoch_core`, plus
     changes["rows_in"] = total raw rows behind the combined input (the
-    flow stat the raw path would have counted)."""
+    flow stat the raw path would have counted) and changes["in_counts"],
+    those counts per unique key.
+
+    One algorithm — unique per-key deltas -> `_core_tail` — whose first
+    stage is needed or not by what the input is (static: the caller
+    knows its plan at trace time, `AggNode.apply`):
+
+    * `recombine` (the default: any rows): a key may arrive several
+      times — behind a mesh exchange once from each source shard — and
+      `batch_reduce` sorts and combines the partials to one row a key.
+    * not `recombine`: the rows are ONE `precombine_core`'s output as it
+      left it (its output contract, with `mask` = key is not EMPTY_KEY),
+      which already IS the unique, key-sorted, pads-last delta a
+      `batch_reduce` over it would return bit for bit — so it is passed
+      through: no sort, no gather, no scatter, no segment reduction.
+      The elementwise select below only re-states the pads as neutral."""
+    kinds = (ReduceKind.SUM,) + spec.kinds
+    vals = [counts.astype(jnp.int64)] + list(dvals)
     with jax.named_scope("agg.reduce_delta"):
-        ukeys, uvals, ucount = batch_reduce(
-            keys, mask, [counts.astype(jnp.int64)] + list(dvals),
-            (ReduceKind.SUM,) + spec.kinds)
+        if recombine:
+            ukeys, uvals, ucount = batch_reduce(keys, mask, vals, kinds)
+        else:
+            with jax.named_scope("passthrough"):
+                ukeys = jnp.where(mask, keys, EMPTY_KEY)
+                uvals = tuple(jnp.where(mask, v, _neutral(k, v.dtype))
+                              for v, k in zip(vals, kinds))
+                ucount = jnp.sum(mask).astype(jnp.int32)
     new_state, needed, ch = _core_tail(spec, state, ukeys, uvals[1:],
                                        ucount, trail)
     ch["rows_in"] = jnp.sum(uvals[0])

@@ -568,6 +568,11 @@ class Node:
         MVKeyedNode: its agg's change set) — part of the jit signature."""
         raise NotImplementedError
 
+    def span_attrs(self) -> Dict[str, Any]:
+        """What this node's `rw:step` span says beside `node` and `i`:
+        a decision the node took from its own plan when it was traced."""
+        return {}
+
     def _sig(self) -> Tuple:
         return (id(self),)            # default: no structural sharing
 
@@ -1066,7 +1071,11 @@ class AggNode(Node):
     as a signed delta (old rows retract, new rows insert; unchanged groups
     suppressed). Change-set internals are exposed via ctx for a terminal
     keyed MV. With `combined` armed (enable_precombine), the input is a
-    PrecombineNode's partial-aggregate delta instead of raw rows."""
+    PrecombineNode's partial-aggregate delta instead of raw rows: on one
+    chip that node's own output, taken as it is (one row a key, key-sorted,
+    reduced once); behind an exchange (`exch` armed, under a mesh) the
+    source shards' partials, which the step re-combines. The node reads
+    which of the two off its own plan when it is traced (`recombine`)."""
 
     def __init__(self, input: int, group_idx: Sequence[int], calls,
                  pack: PackPlan, spec, capacity: int,
@@ -1139,6 +1148,17 @@ class AggNode(Node):
                        for k, dt in zip(self.spec.kinds, self.spec.dtypes)
                        ), "pre-combine over a float SUM column"
         self.combined = True
+
+    @property
+    def recombine(self) -> bool:
+        """Of a `combined` node: does the step reduce its pre-combined
+        delta a second time? True behind an exchange (one partial a
+        source shard and key), False where the delta is one
+        PrecombineNode's output. Read off the node's own plan."""
+        return self.exch is not None
+
+    def span_attrs(self):
+        return {"recombine": self.recombine} if self.combined else {}
 
     def shard_spec(self):
         if self.combined:
@@ -1351,16 +1371,20 @@ class AggNode(Node):
         d = ins[0]
         if self.combined:
             # pre-combined input ([key, raw-row count, *partial deltas],
-            # PrecombineNode layout): re-combine cross-partition partials
-            # and merge — no packing (key pre-packed, bounds pre-checked
-            # upstream), no multisets (enable_precombine forbids them)
+            # PrecombineNode layout) — no packing (key pre-packed, bounds
+            # pre-checked upstream), no multisets (enable_precombine
+            # forbids them). Behind an exchange a key arrives once from
+            # each source shard and the partials are re-combined; with
+            # none the delta is the one PrecombineNode's output
+            # (`precombine_core`'s contract) and is merged as it is
             from .agg_step import epoch_core_combined
             keys = d.cols[0]
             cnt = d.cols[1]
             dvals = list(d.cols[2:2 + len(self.spec.kinds)])
             live = d.mask & (d.sign != 0)
             new_main, needed, ch = epoch_core_combined(
-                self.spec, state.main, keys, cnt, dvals, live, self.tier)
+                self.spec, state.main, keys, cnt, dvals, live, self.tier,
+                recombine=self.recombine)
             new_state = DeviceAggState(new_main, ())
             packbad = jnp.zeros((), jnp.int64)
             rows_in = ch["rows_in"].astype(jnp.int64)
@@ -2274,7 +2298,8 @@ class FusedProgram:
                 extra = auxes[node.inputs[0]]
             else:
                 extra = None
-            with spans.span("rw:step", node=self.node_names[i], i=i) as sp:
+            with spans.span("rw:step", node=self.node_names[i], i=i,
+                            **node.span_attrs()) as sp:
                 if svc is not None:
                     # compile-service path: ready executables dispatch
                     # with zero trace; a pending one is waited for (the

@@ -176,7 +176,7 @@ def cheap_compile() -> bool:
     so the variadic forms do not start inside any serving budget.
     r04/r05 measured them up to 3x faster at RUN time for a 2^20-slot
     agg; `RW_TPU_CHEAP_COMPILE=0` keeps them reachable until a benchmark
-    settles that trade (ROADMAP D5)."""
+    settles that trade (ROADMAP D3)."""
     global _CHEAP_COMPILE
     if _CHEAP_COMPILE is None:
         import os
@@ -280,9 +280,10 @@ def merge_changes(state: SortedState, new_state: SortedState,
     row of the position before it (`met`, -1 where the state had none).
     What brings that back to delta order is one two-operand sort over "is
     a delta row" (`compact_rows` with the answer as its one column — NOT
-    a scatter): the delta is key-sorted with its pads last
-    (`batch_reduce`) and the merge's sort is stable, so the j-th delta row
-    in sorted order IS delta row j. Then one gather a payload column.
+    a scatter): the delta is key-sorted with its pads last (what
+    `batch_reduce` leaves: `agg_step.precombine_core`'s output contract)
+    and the merge's sort is stable, so the j-th delta row in sorted order
+    IS delta row j. Then one gather a payload column.
     The new side needs no gather at all: a key's run is its state row and
     its delta row, so the merged payload is their `_combine` (the delta's
     own value where the state had none), the group is alive by the merge's

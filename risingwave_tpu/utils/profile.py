@@ -69,6 +69,9 @@ what is finer sits inside the phases as child spans:
     job: `node`, `xi`, `shards`, `exch` = bucket capacity, `rows_slots` =
     shards x exch, the rows the stage hands its step); `rw:commit.gauges`
     of such a job carries `shard_report` (`FusedJob.shard_report()`)
+  rw:step carries `node`, `i` and what `Node.span_attrs()` adds: the
+    agg step over a pre-combined delta says `recombine` (true behind an
+    exchange, false where it takes the pre-combine's delta as it is)
   rw:compile (worker thread)       rw:ingest.poll | .pack | .h2d (stager)
   rw:pack > rw:ingest.wait (the dispatch thread blocked on the stager)
   rw:sql > rw:sql.fuse_plan

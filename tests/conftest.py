@@ -61,19 +61,25 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(skip)
 
 
-def _loops(jaxpr, found=None):
-    """Every `scan` / `while` equation of a jaxpr, sub-jaxprs included."""
+def _eqns(jaxpr, stack=""):
+    """Every equation of a jaxpr, sub-jaxprs included, each with the
+    named-scope path it sits under (an equation's name stack is relative
+    to the equation that holds its jaxpr)."""
     from jax.extend import core as jcore
-    found = [] if found is None else found
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name in ("scan", "while"):
-            found.append(eqn)
+        at = f"{stack}/{eqn.source_info.name_stack}"
+        yield eqn, at
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
                 sub = getattr(sub, "jaxpr", sub)
                 if isinstance(sub, jcore.Jaxpr):
-                    _loops(sub, found)
-    return found
+                    yield from _eqns(sub, at)
+
+
+def _loops(jaxpr):
+    """Every `scan` / `while` equation of a jaxpr, sub-jaxprs included."""
+    return [eqn for eqn, _at in _eqns(jaxpr)
+            if eqn.primitive.name in ("scan", "while")]
 
 
 def _loops_over(jaxpr, rows):
@@ -88,6 +94,21 @@ def _loops_over(jaxpr, rows):
         if long:
             out.append((eqn.primitive.name, long))
     return out
+
+
+def _prims_under(jaxpr, scope):
+    """The primitives of a jaxpr's equations that sit under the
+    `jax.named_scope` `scope`, sub-jaxprs included."""
+    return {eqn.primitive.name for eqn, at in _eqns(jaxpr)
+            if scope in at.split("/")}
+
+
+@pytest.fixture
+def jaxpr_prims_under():
+    """prims_under(jaxpr, scope) — which primitives a stage of a step
+    program holds (tests/test_device_state.py, tests/test_mesh_bid_agg.py):
+    the stage is the named scope the device trace shows it under."""
+    return _prims_under
 
 
 @pytest.fixture

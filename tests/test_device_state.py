@@ -438,3 +438,135 @@ def test_agg_entries_search_the_state_for_no_key(entry, jaxpr_loops):
     loops, _ = jaxpr_loops
     assert loops(jaxpr.jaxpr) == []
     assert "sort[" in str(jaxpr)    # (the probe does read primitives)
+
+
+# ---------------------------------------------------------------------------
+# reduce once (ISSUE 31): where no exchange stands between a pre-combine and
+# its agg, `epoch_core_combined` takes `precombine_core`'s output as it is.
+# Its plain reference is the form it keeps behind an exchange: `batch_reduce`
+# over the same rows, which must be an identity on them.
+# ---------------------------------------------------------------------------
+
+PT_HOT = 777        # the key of the two hot-key epochs behind the script
+PT_HOT_AT = CS_EPOCHS       # the epoch whose every row is that key
+
+# case -> (call kinds, append_only, delta rows an epoch); `spec.kinds` has
+# 2, 4 and 6 payload columns, as PR 29's cases
+PT_CASES = {
+    "count-2col": (["count_star"], False, 24),
+    "count-sum-4col": (["count_star", "sum"], False, 24),
+    "count-sum-max-6col": (["count_star", "sum", "max"], True, 24),
+    # the delta as long as the state, and longer (a mesh shard's)
+    "count-sum-delta-as-long-as-state": (["count_star", "sum"], False,
+                                         CS_CAP),
+    "count-sum-max-long-delta": (["count_star", "sum", "max"], True, 80),
+}
+
+
+def _pt_epochs(rng, rows, live_keys):
+    """`_cs_epochs`' script (group death, net-zero deltas, a row of sign
+    0, an all-masked epoch, EMPTY_KEY rows, the fill, the overflow and
+    its replay), then: every row one key, and a hot key among others."""
+    yield from _cs_epochs(rng, rows, live_keys)
+    yield PT_HOT_AT, [(1, True, PT_HOT, int(rng.integers(1, 50)))
+                      for _ in range(rows)]
+    yield PT_HOT_AT + 1, [
+        (1, True, PT_HOT if i % 10 else int(rng.integers(0, 24)),
+         int(rng.integers(1, 50))) for i in range(rows)]
+
+
+def _tree_eq(a, b):
+    import jax
+    la, ta = jax.tree_util.tree_flatten(a)
+    lb, tb = jax.tree_util.tree_flatten(b)
+    assert ta == tb
+    for x, y in zip(la, lb):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert (np.asarray(x) == np.asarray(y)).all()
+
+
+@pytest.mark.parametrize("trail", [False, True], ids=["plain", "trail"])
+@pytest.mark.parametrize("case", list(PT_CASES))
+def test_passthrough_equals_recombine_on_a_precombined_delta(case, trail):
+    """Over `precombine_core`'s own output the pass-through form of
+    `epoch_core_combined` equals the `batch_reduce` form leaf for leaf:
+    the new state, `needed`, every entry of the change set (`rows_in`,
+    `in_counts`, `count`, with `trail` the merge's trail too). Both are
+    driven from the same state through the script, the truncated merge
+    (`needed` > capacity) and its replay after `grow_state` included."""
+    import jax
+    from risingwave_tpu.device import grow_state
+    from risingwave_tpu.device.agg_step import (epoch_core_combined,
+                                                precombine_core)
+    call_kinds, append_only, rows = PT_CASES[case]
+    spec = DeviceAggSpec.build(call_kinds, [np.int64] * len(call_kinds),
+                               append_only=append_only)
+    pre = jax.jit(precombine_core, static_argnums=0)
+    step = jax.jit(epoch_core_combined, static_argnums=(0, 6, 7))
+    state = spec.make_state(CS_CAP)
+
+    def live_keys():
+        return set(np.asarray(state.keys)[:int(state.count)].tolist())
+
+    seen = set()
+    for e, rows_e in _pt_epochs(np.random.default_rng(31), rows, live_keys):
+        keys, sign, mask, vals = _cs_pad(rows_e, rows, np.int64)
+        inputs = tuple((vals, jnp.ones(rows, bool)) for _ in call_kinds)
+        ukeys, ucnt, udeltas = pre(spec, keys, sign, mask, inputs)
+        # the contract the pass-through rests on
+        uk = np.asarray(ukeys)
+        n = int((uk != EMPTY_KEY).sum())
+        assert (np.diff(uk[:n]) > 0).all() and (uk[n:] == EMPTY_KEY).all()
+        live = ukeys != EMPTY_KEY       # PrecombineNode's mask (sign 1)
+        raw = np.asarray(mask) & (np.asarray(sign) != 0) \
+            & (np.asarray(keys) != EMPTY_KEY)
+        while True:
+            want = step(spec, state, ukeys, ucnt, udeltas, live, trail,
+                        True)
+            got = step(spec, state, ukeys, ucnt, udeltas, live, trail,
+                       False)
+            _tree_eq(got, want)
+            new, needed, ch = got
+            assert ("merge_trail" in ch) == trail
+            assert int(ch["count"]) == n and int(ch["rows_in"]) == raw.sum()
+            assert (np.asarray(ch["in_counts"])[n:] == 0).all()
+            fits = int(needed) <= state.capacity
+            seen.add((e, "fits" if fits else "truncated"))
+            if fits:
+                break
+            state = grow_state(state, 2 * state.capacity, spec.kinds)
+        if e == PT_HOT_AT:
+            assert n == 1 and int(ch["in_counts"][0]) == rows
+        state = new
+    assert {(CS_OVER_AT, "truncated"), (CS_OVER_AT, "fits"),
+            (6, "fits"), (PT_HOT_AT + 1, "fits")} <= seen
+    assert state.capacity == 2 * CS_CAP
+
+
+@pytest.mark.parametrize("recombine", [False, True],
+                         ids=["one-chip", "behind-an-exchange"])
+def test_reduce_stage_of_the_combined_entry(recombine, jaxpr_prims_under):
+    """Reduce once: with no exchange before it the combined entry's reduce
+    stage (`agg.reduce_delta`, all of it the `passthrough` scope) holds no
+    sort, gather, scatter or segment reduction; behind an exchange it
+    keeps `batch_reduce`, sort and scatters and all."""
+    import jax
+    from risingwave_tpu.device.agg_step import epoch_core_combined
+    spec = DeviceAggSpec.build(["count_star", "sum", "max"], [np.int64] * 3)
+    z = jnp.zeros(64, jnp.int64)
+    jaxpr = jax.make_jaxpr(
+        lambda st: epoch_core_combined(spec, st, z, z, [z] * 6, z == 0,
+                                       recombine=recombine))(
+        spec.make_state(64)).jaxpr
+    stage = jaxpr_prims_under(jaxpr, "agg.reduce_delta")
+    heavy = {p for p in stage if p.startswith(("sort", "scatter", "gather",
+                                               "segment", "cum", "while",
+                                               "scan"))}
+    if recombine:
+        assert {"sort", "scatter", "scatter-add", "scatter-max"} <= heavy
+        assert jaxpr_prims_under(jaxpr, "passthrough") == set()
+    else:
+        assert heavy == set()
+        assert stage == jaxpr_prims_under(jaxpr, "passthrough") != set()
+    # the merge behind it is the same stage either way
+    assert "sort" in jaxpr_prims_under(jaxpr, "agg.merge")

@@ -241,23 +241,19 @@ def test_one_chip_programs_carry_nothing_of_the_mesh(armed):
     assert seen == {"AggNode", "PrecombineNode", "MVKeyedNode"}
 
 
-@pytest.mark.parametrize("shards", [SHARDS, 1])
-def test_agg_step_searches_its_state_for_no_key(armed, shards, jaxpr_loops):
-    """The agg step of the deployment, sharded (`shard_map` over four
-    devices, a received delta of `shards * exch` rows a shard) and on one
-    chip, with the benchmark's defaults armed: no `scan` / `while` of it
-    reads, carries or produces an array of `capacity` rows or more. The
-    change set is read off the merge by position
-    (`sorted_state.merge_changes`), not looked up by key."""
+def _agg_step_jaxpr(shards):
+    """The deployment's agg step as planned at `mesh_shards=shards`
+    (`shard_map` over four devices, a received delta of `shards * exch`
+    rows a shard; or the one-chip step), the benchmark's defaults armed:
+    (node, jaxpr)."""
     import jax
     from risingwave_tpu.device.compile_service import abstract_program_avals
     from risingwave_tpu.device.fused import _jit_step
     from risingwave_tpu.device.shard_exec import sharded_jit_step
     program = _planned(shards).program
     assert (program.mesh is not None) == (shards > 1)
-    idx = next(i for i, n in enumerate(program.nodes)
-               if type(n).__name__ == "AggNode")
-    node = program.nodes[idx]
+    idx, node = next((i, n) for i, n in enumerate(program.nodes)
+                     if type(n).__name__ == "AggNode")
     assert node.combined and node.tier and node.capacity == CAPACITY
     sds = abstract_program_avals(program.nodes, program.epoch_events,
                                  program.mesh)[idx]
@@ -267,5 +263,112 @@ def test_agg_step_searches_its_state_for_no_key(armed, shards, jaxpr_loops):
         *a, node=node, epoch_events=program.epoch_events,
         salt=node._mut_sig()))(*sds).jaxpr
     assert ("shard_map" in str(jaxpr)) == (shards > 1)
+    return node, jaxpr
+
+
+@pytest.mark.parametrize("shards", [SHARDS, 1])
+def test_agg_step_searches_its_state_for_no_key(armed, shards, jaxpr_loops):
+    """The agg step of the deployment, sharded and on one chip: no `scan`
+    / `while` of it reads, carries or produces an array of `capacity` rows
+    or more. The change set is read off the merge by position
+    (`sorted_state.merge_changes`), not looked up by key."""
+    _node, jaxpr = _agg_step_jaxpr(shards)
     _loops, loops_over = jaxpr_loops
     assert loops_over(jaxpr, CAPACITY) == []
+
+
+# ---- reduce once (ISSUE 31): the agg step re-combines its pre-combined
+# delta only behind an exchange; on one chip it takes it as it is ----------
+
+def _agg(job):
+    idx = next(i for i, n in enumerate(job.program.nodes)
+               if type(n).__name__ == "AggNode")
+    return idx, job.program.nodes[idx]
+
+
+def _agg_state_rows(job, idx):
+    """The agg's live state rows, every shard's together, sorted by key:
+    (keys, [payload column...])."""
+    from risingwave_tpu.device.sorted_state import EMPTY_KEY
+    st = job.states[idx]
+    st = getattr(st, "inner", st).main          # the state tier's wrapper
+    keys = np.asarray(st.keys).reshape(-1)
+    live = keys != EMPTY_KEY
+    order = np.argsort(keys[live], kind="stable")
+    return keys[live][order], [np.asarray(v).reshape(-1)[live][order]
+                               for v in st.vals]
+
+
+def _step_spans(spans, idx):
+    return [s for s in spans if s["name"] == "rw:step" and s["i"] == idx]
+
+
+@pytest.mark.parametrize("cadence", sorted(CADENCES))
+def test_partials_from_two_source_shards_are_one_state_row(armed, cadence):
+    """Behind the exchange a key arrives once from each source shard that
+    saw it: the agg step still combines the partials to one state row
+    (`recombine`; the pass-through taken here would merge a delta with a
+    key twice and fail every line below)."""
+    seed = SEEDS[0]
+    _, job, spans = _drive(armed, seed, cadence)
+    chunk, polls, events = CADENCES[cadence]
+    counts = CODE.counts(seed, events, chunk * polls)
+    # some key left two source shards in one epoch
+    assert counts["exchange_rows"] > counts["groups_touched"]
+    idx, node = _agg(job)
+    assert node.combined and node.exch is not None and node.recombine
+    keys, vals = _agg_state_rows(job, idx)
+    want = CODE.reference(seed, events)
+    assert len(np.unique(keys)) == len(keys) == len(want)
+    by_count = sorted(c for _a, c, _s, _m in want)
+    assert sorted(vals[0].tolist()) == by_count        # row_count a group
+    assert vals[0].sum() == counts["bids"]
+    stats = job.program.node_stats(idx, job._stat_totals)
+    assert stats["rows_in"] == counts["bids"]
+    steps = _step_spans(spans, idx)
+    assert steps and all(s["recombine"] is True for s in steps)
+    assert all("recombine" not in s for s in spans
+               if s["name"] == "rw:step" and s["i"] != idx)
+
+
+def test_one_shard_takes_the_delta_as_it_is_and_equals_four(armed):
+    """`mesh_shards=1`: no exchange, so the step passes the pre-combine's
+    delta through (`recombine` false on its spans) — and the MV, the agg's
+    state rows and its row-flow stats equal the four-shard run's."""
+    seed, cadence = SEEDS[0], "divides"
+    rows4, job4, _ = _drive(armed, seed, cadence)
+    rows1, job1, spans1 = _drive(armed, seed, cadence, shards=1)
+    assert job1.program.mesh is None
+    idx, node = _agg(job1)
+    assert node.combined and node.exch is None and not node.recombine
+    steps = _step_spans(spans1, idx)
+    assert steps and all(s["recombine"] is False for s in steps)
+    assert collections.Counter(rows1) == collections.Counter(rows4)
+    k1, v1 = _agg_state_rows(job1, idx)
+    k4, v4 = _agg_state_rows(job4, _agg(job4)[0])
+    assert (k1 == k4).all() and len(v1) == len(v4)
+    for a, b in zip(v1, v4):
+        assert a.dtype == b.dtype and (a == b).all()
+    for stat in ("rows_in", "rows_out"):
+        assert job1.program.node_stats(idx, job1._stat_totals)[stat] \
+            == job4.program.node_stats(_agg(job4)[0],
+                                       job4._stat_totals)[stat]
+
+
+@pytest.mark.parametrize("shards", [SHARDS, 1])
+def test_reduce_stage_of_the_planned_agg_step(armed, shards,
+                                              jaxpr_prims_under):
+    """The deployment's agg step as planned: sharded, its reduce stage is
+    `batch_reduce` (sort and scatters); on one chip it is the pass-through
+    and holds neither — the node reads which off `exch`, no option."""
+    node, jaxpr = _agg_step_jaxpr(shards)
+    assert node.recombine == (shards > 1)
+    stage = jaxpr_prims_under(jaxpr, "agg.reduce_delta")
+    heavy = {p for p in stage
+             if p.startswith(("sort", "scatter", "gather"))}
+    if shards > 1:
+        assert {"sort", "scatter", "scatter-add", "scatter-max"} <= heavy
+        assert not jaxpr_prims_under(jaxpr, "passthrough")
+    else:
+        assert heavy == set()
+        assert stage == jaxpr_prims_under(jaxpr, "passthrough") != set()

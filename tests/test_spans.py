@@ -88,6 +88,8 @@ def test_every_barrier_yields_the_span_tree(run):
                  and s["name"] == "rw:step"]
         assert [s["i"] for s in steps] == list(range(len(job.program.nodes)))
         assert [s["node"] for s in steps] == job.program.node_names
+        # (an agg over raw rows has no pre-combined delta to speak of)
+        assert not any("recombine" in s for s in steps)
         assert all((s["seq"], s["epoch"], s["inst"])
                    == (ep["seq"], ep["epoch"], ep["inst"]) for s in steps)
         lo = [s for s in spans if s["parent"] == kids[0]["id"]]
@@ -203,16 +205,21 @@ def test_a_span_is_named_rw_and_a_stale_one_goes_with_its_parent():
     assert (b["parent"], b["epoch"]) == (None, 8)       # not inside `a`
 
 
-def _agg_step(job):
+def _lower_step(job, i):
     from risingwave_tpu.device.compile_service import abstract_program_avals
-    from risingwave_tpu.device.fused import AggNode, _jit_step
+    from risingwave_tpu.device.fused import _jit_step
     prog = job.program
-    i = next(i for i, n in enumerate(prog.nodes) if isinstance(n, AggNode))
     node = prog.nodes[i]
     sds = abstract_program_avals(prog.nodes, prog.epoch_events)[i]
     return node, _jit_step(node).lower(
         *sds, node=node, epoch_events=prog.epoch_events,
         salt=node._mut_sig())
+
+
+def _agg_step(job):
+    from risingwave_tpu.device.fused import AggNode
+    return _lower_step(job, next(i for i, n in enumerate(job.program.nodes)
+                                 if isinstance(n, AggNode)))
 
 
 def test_module_is_named_after_its_node_and_scopes_are_metadata(
@@ -245,6 +252,43 @@ def test_module_is_named_after_its_node_and_scopes_are_metadata(
     assert fused._STACK_JIT.lower((jnp.int64(1),)).as_text().startswith(
         "module @jit_stats_stack")
     assert fused._named(lambda: 0, "tier_x").__name__ == "tier_x"
+
+
+def test_precombined_agg_step_says_it_reduces_once(monkeypatch):
+    """With the pre-combine armed (the default the conftest pins off) and
+    no exchange, the agg's `rw:step` spans read `recombine=False`, its
+    module's whole reduce stage is the `agg.reduce_delta/passthrough`
+    scope with no sort under it, and the pre-combine's module keeps the
+    one real reduce (`agg.reduce_delta`, a sort)."""
+    from risingwave_tpu.device.fused import AggNode, PrecombineNode
+    monkeypatch.setenv("RW_AGG_PRECOMBINE", "1")
+    first = len(profile.SPANS)
+    _db, job = fused_db(n=N - 64 * CHUNK)       # a stream of its own
+    spans = [s for s in list(profile.SPANS)[first:]
+             if s.get("inst") == job.profiler.instance]
+    nodes = job.program.nodes
+    agg = next(i for i, n in enumerate(nodes) if isinstance(n, AggNode))
+    pre = next(i for i, n in enumerate(nodes)
+               if isinstance(n, PrecombineNode))
+    assert nodes[agg].combined and nodes[agg].exch is None
+    steps = [s for s in spans if s["name"] == "rw:step"]
+    assert steps and {s["i"] for s in steps} == set(range(len(nodes)))
+    for s in steps:
+        if s["i"] == agg:
+            assert s["recombine"] is False
+        else:
+            assert "recombine" not in s
+    _node, lowered = _agg_step(job)
+    hlo = lowered.compile().as_text()
+    reduce_ops = [o for o in re.findall(r'op_name="([^"]*)"', hlo)
+                  if "agg.reduce_delta" in o]
+    assert reduce_ops and all("agg.reduce_delta/passthrough" in o
+                              for o in reduce_ops)
+    assert not any(re.search(r"/(sort|scatter|gather)", o)
+                   for o in reduce_ops)
+    text = _lower_step(job, pre)[1].as_text(debug_info=True)
+    assert re.search(r'agg\.reduce_delta[^"]*sort', text)
+    assert "passthrough" not in text
 
 
 _NAMES_SCRIPT = """

@@ -466,17 +466,20 @@ def _pad(rows, width, n=STEP_ROWS):
     return sign, mask, cols
 
 
-def _agg_delta(rows, combined):
+def _agg_delta(rows, node):
     """[(sign, masked, key, value)] as the node's input delta: raw rows,
-    or the PrecombineNode layout [key, raw rows, *partial deltas] (one
-    partial per raw row; the agg re-combines them)."""
+    or — for a `combined` node — what the PrecombineNode the planner puts
+    before it makes of them ([key, raw rows, *partial deltas], one row a
+    key: with no exchange the agg takes exactly that)."""
     import jax.numpy as jnp
-    from risingwave_tpu.device.fused import Delta
-    if combined:
-        rows = [(1, m, k, 1, s, s, s * v, s) for s, m, k, v in rows]
-    sign, mask, cols = _pad(rows, 6 if combined else 2)
-    return Delta([jnp.asarray(c) for c in cols], jnp.asarray(sign),
-                 jnp.asarray(mask))
+    from risingwave_tpu.device.fused import Delta, PrecombineNode
+    sign, mask, cols = _pad(rows, 2)
+    raw = Delta([jnp.asarray(c) for c in cols], jnp.asarray(sign),
+                jnp.asarray(mask))
+    if not node.combined:
+        return raw
+    pre = PrecombineNode(0, node.group_idx, node.calls, node.pack, node.spec)
+    return pre.apply(None, [raw], None, STEP_ROWS)[1]
 
 
 def _agg_epochs(rng, combined):
@@ -538,7 +541,7 @@ def test_touch_rides_merge_against_dictionary(case):
             if tick == GROW_AT:
                 state = node.cap_resize(state, {"main": 2 * STEP_CAP})
             state, _, stats, _ = _node_step(
-                node, STEP_ROWS, state, [_agg_delta(rows, combined)], None)
+                node, STEP_ROWS, state, [_agg_delta(rows, node)], None)
             named = set()
             for s, m, k, v in rows:
                 if m:
