@@ -2171,6 +2171,11 @@ class FusedProgram:
         # its compile-event label
         self.node_names = [n.stable_name() for n in self.nodes]
         self._labels: Dict[int, str] = {}
+        # rows-wide shape each node's step was handed last epoch (its
+        # input deltas' lanes, all shards'; a source's: the lanes it
+        # made) — static shapes the host loop reads off the handles it
+        # routes, for `FusedJob.flow_report`
+        self.lanes: List[Optional[int]] = [None] * len(self.nodes)
         # vnode-block bounds the exchange routes by: None = the uniform
         # `vnode_block_bounds` layout; a rebalanced job carries the
         # custom bounds chosen at a checkpoint barrier. Routing-only
@@ -2327,6 +2332,10 @@ class FusedProgram:
             new_states[i] = st
             outs.append(out)
             auxes.append(aux)
+            handed = [d for d in (ins if node.inputs else (out,))
+                      if d is not None]
+            self.lanes[i] = sum(d.mask.size for d in handed) \
+                if handed else None
             if exch_need is not None:
                 # the "exch" stat and the "xin" stats after it (appended
                 # to the node's stat_names by enable_exchange) are
@@ -3515,6 +3524,7 @@ class FusedJob:
             if prof is not None:
                 with prof.span("rw:commit.gauges") as gauges:
                     self._export_hbm_gauges()
+                    gauges.set(flow_report=self.flow_report())
                     if self.program.mesh is not None:
                         gauges.set(shard_report=self.shard_report())
         if self.freshness is not None and self._window_ingest is not None:
@@ -3610,13 +3620,9 @@ class FusedJob:
                 keys, cols, nulls = self._tier_merge_mv_rows(
                     keys, cols, nulls)
             gcols_np = _np_unpack(self.pull.agg.pack, keys)
-            out_cols = []
-            for pos, (kind, j) in enumerate(self.pull.out_map):
-                src = gcols_np[j] if kind == "g" else cols[j]
-                null = None if kind == "g" else nulls[j]
-                out_cols.append(_format_col(
-                    self.pull.dtypes[pos], self.pull.decoders[pos],
-                    np.asarray(src), null))
+            pulled = [(gcols_np[j], None) if kind == "g"
+                      else (cols[j], nulls[j])
+                      for kind, j in self.pull.out_map]
             n = len(keys)
         else:
             side = self.states[self.pull.node_idx]
@@ -3629,11 +3635,36 @@ class FusedJob:
                 n = int(side.count)
                 vals = jax.device_get([v[:n] if hasattr(v, "shape") else v
                                        for v in side.vals])
-            out_cols = [_format_col(self.pull.dtypes[i],
-                                    self.pull.decoders[i],
-                                    np.asarray(vals[i]), None)
-                        for i in range(len(self.pull.dtypes))]
+            pulled = [(vals[i], None)
+                      for i in range(len(self.pull.dtypes))]
+        out_cols = self._format_cols(pulled, n)
         return [tuple(c[i] for c in out_cols) for i in range(n)]
+
+    def _format_cols(self, pulled, n: int) -> List[List[Any]]:
+        """Pulled (values, nulls) per MV column -> host Python values.
+        The columns that are not numbers on the host (VARCHAR: a
+        surrogate the generator's pools turn back into the string) go
+        first, inside `rw:commit.mirror.decode` (`rows`, `string_cols`):
+        under the mirror's pull and under a SELECT's, what a string
+        column costs is a span of its own. An all-number MV leaves
+        none."""
+        pull = self.pull
+        strings = [k for k, d in enumerate(pull.decoders)
+                   if d not in (NUM, ("ts",))]
+        out: List[Any] = [None] * len(pulled)
+
+        def fmt(k):
+            out[k] = _format_col(pull.dtypes[k], pull.decoders[k],
+                                 np.asarray(pulled[k][0]), pulled[k][1])
+        if strings:
+            with self.profiler.span("rw:commit.mirror.decode", rows=n,
+                                    string_cols=len(strings)):
+                for k in strings:
+                    fmt(k)
+        for k in range(len(pulled)):
+            if k not in strings:
+                fmt(k)
+        return out
 
     def mv_rows_now(self) -> List[Tuple]:
         """Query serving: sync and pull the CURRENT MV rows (full schema,
@@ -4343,6 +4374,40 @@ class FusedJob:
                               "live": [st[f"live{s}"] for s in range(n)]})
         return {"shards": n, "rebalances": self.rebalances,
                 "exchanges": exchanges, "keyed": keyed}
+
+    def flow_report(self) -> Dict[str, Any]:
+        """What flowed through each node of the job and how full its
+        state is, from the stats the regular syncs already pull (job-
+        lifetime totals, checkpoint-fresh; no device traffic): per node
+        `node`, `i`, `kind`, `rows_in` / `rows_out` (live rows, summed
+        over the job's epochs) and `lanes`, the rows-wide shape its step
+        was handed an epoch (`FusedProgram.lanes`) — `rows_in` over
+        `lanes` x epochs is how much of what a step works over is not
+        padding; for a node with state `live` and `capacity`, the
+        high-water entries and the slots of its fullest keyed slot (per
+        shard on a mesh); for a join `need_pairs`, the most pairs an
+        epoch emitted, and its `pairs` buffer. JSON-able: every
+        checkpoint leaves it on its `rw:commit.gauges` span, where it
+        outlives the job."""
+        nodes = []
+        for i, node in enumerate(self.program.nodes):
+            st = self.program.node_stats(i, self._stat_totals)
+            entry = {"node": self.program.node_names[i], "i": i,
+                     "kind": type(node).__name__,
+                     "rows_in": st.get("rows_in", 0),
+                     "rows_out": st.get("rows_out", 0),
+                     "lanes": self.program.lanes[i]}
+            cur = node.cap_current()
+            if cur:
+                live = node.cap_needs_cum(st)
+                slot = max(live, key=lambda s: live[s] / cur[s])
+                entry.update(live=live[slot], capacity=cur[slot])
+                if "pairs" in cur:
+                    entry.update(need_pairs=st["need_pairs"],
+                                 pairs=cur["pairs"])
+            nodes.append(entry)
+        return {"events": self.counter,
+                "epoch_events": self.program.epoch_events, "nodes": nodes}
 
     def node_skew_ratio(self, i: int) -> Optional[float]:
         """Occupancy skew ratio (max/mean bucket) of node i, or None

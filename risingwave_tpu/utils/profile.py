@@ -64,7 +64,12 @@ what is finer sits inside the phases as child spans:
   rw:pack > rw:event_lo            rw:dispatch > rw:step > rw:compile_wait
   rw:dispatch > rw:stats_fold      rw:device_sync > rw:stats_pull | rw:growth
   rw:commit > rw:commit.mirror > .pull | .diff | .table_commit
-  rw:commit > rw:commit.job_state | rw:commit.gauges
+  rw:commit.mirror.pull > rw:commit.mirror.decode (`rows`,
+    `string_cols`: the MV's VARCHAR columns turned from surrogates into
+    strings; only an MV that has one; the same span under a SELECT's pull)
+  rw:commit > rw:commit.job_state | rw:commit.gauges (carries
+    `flow_report`, `FusedJob.flow_report()`: per node rows in and out,
+    the lanes its step was handed, live entries over capacity)
   rw:dispatch > rw:exchange (one an exchange stage of a mesh-sharded
     job: `node`, `xi`, `shards`, `exch` = bucket capacity, `rows_slots` =
     shards x exch, the rows the stage hands its step); `rw:commit.gauges`

@@ -34,6 +34,7 @@ NAMES = {"rw:" + p for p in profile.PHASES} | {
     "rw:stats_fold", "rw:stats_pull", "rw:compile_wait", "rw:compile",
     "rw:growth", "rw:commit.mirror", "rw:commit.mirror.pull",
     "rw:commit.mirror.diff", "rw:commit.mirror.table_commit",
+    "rw:commit.mirror.decode",
     "rw:commit.job_state", "rw:commit.gauges", "rw:ingest.poll",
     "rw:ingest.pack", "rw:ingest.h2d", "rw:ingest.wait", "rw:sql",
     "rw:sql.fuse_plan"}
