@@ -675,7 +675,15 @@ from .capacity import ladder as _ladder  # noqa: E402  (pre-warm rungs)
 
 
 class SourceNode(Node):
-    """On-device exact Nexmark/datagen events for this epoch's id range."""
+    """On-device exact Nexmark/datagen events for this epoch's id range.
+
+    The source makes its own table's rows: it enumerates the table's
+    event ids at or after `event_lo` over `nexmark_gen.source_lanes`
+    lanes (person 32,768, auction 65,536 and bid 1,048,576 of a
+    1,048,576-event epoch) and masks only the lanes past the window's
+    end (and past `max_events`): the live rows are a dense prefix, in
+    event-id order, and `pk` is the event id. Everything downstream
+    works over those lanes; its `rw:step` span says how many."""
 
     takes_event_lo = True
     stat_names = ("rows_out",)
@@ -713,9 +721,10 @@ class SourceNode(Node):
 
     def apply(self, state, ins, extra, epoch_events):
         import jax.numpy as jnp
-        from .nexmark_gen import gen_table, table_mask
-        ids = extra + jnp.arange(epoch_events, dtype=jnp.int64)
-        mask = table_mask(self.table, ids)
+        from .nexmark_gen import gen_table, own_event_ids, source_lanes
+        ids = own_event_ids(self.table, extra,
+                            source_lanes(self.table, epoch_events))
+        mask = ids < extra + epoch_events
         if self.max_events is not None:
             mask = mask & (ids < self.max_events)
         all_cols = gen_table(self.gencfg, self.table, ids)
@@ -2324,6 +2333,10 @@ class FusedProgram:
                 else:
                     st, out, s, aux = _node_step(node, self.epoch_events,
                                                  states[i], ins, extra)
+                if node.takes_event_lo:
+                    # a device source says the lanes it made (all
+                    # shards'), read off its delta
+                    sp.set(lanes=out.mask.size, of=self.epoch_events)
             if svc is None and prof is not None:
                 kind = prof.pending_compile.pop(i, None)
                 if kind is not None or sp.seconds > COMPILE_THRESHOLD_S:

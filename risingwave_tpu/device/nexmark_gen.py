@@ -150,11 +150,15 @@ def _zipf_ordinal(rand_pick, n_entities, s: float):
 
 
 def gen_table(cfg: GenCfg, table: str, event_ids) -> Dict[str, jnp.ndarray]:
-    """All columns of `table` for these event ids, as int64 arrays.
+    """All columns of `table` for these event ids, as int64 arrays: a
+    pure function of any id array, whatever its order or gaps.
 
-    Every event id gets a row regardless of its kind — callers mask rows
-    with `event_kinds(ids) == kind`. String columns are surrogates (see
-    SURROGATE) decoded host-side by `decode_column`.
+    Every id handed in gets a row regardless of its kind. A caller that
+    hands in the table's OWN ids (`own_event_ids`: the fused source)
+    masks only the ids past its window; one that walks every id of a
+    range (the tests' plain reference) masks the other tables' rows
+    with `table_mask`. String columns are surrogates (see SURROGATE)
+    decoded host-side by `decode_column`.
     """
     with jax.named_scope("source.gen"):    # HLO metadata only
         return _gen_table(cfg, table, event_ids)
@@ -233,6 +237,49 @@ _KIND = {"person": 0, "auction": 1, "bid": 2}
 
 def table_mask(table: str, event_ids):
     return event_kinds(event_ids) == _KIND[table]
+
+
+# table -> (events of it in a block of TOTAL_PROPORTION, offset of its
+# first): the generator's id -> table rule (`event_kinds`) as intervals
+_SHARE = {
+    "person": (PERSON_PROPORTION, 0),
+    "auction": (AUCTION_PROPORTION, PERSON_PROPORTION),
+    "bid": (TOTAL_PROPORTION - PERSON_PROPORTION - AUCTION_PROPORTION,
+            PERSON_PROPORTION + AUCTION_PROPORTION),
+}
+
+
+def own_rows_bound(table: str, epoch_events: int) -> int:
+    """The most rows of `table` any window of `epoch_events` consecutive
+    event ids can hold (a window touches at most events // 50 + 2
+    blocks)."""
+    return (epoch_events // TOTAL_PROPORTION + 2) * _SHARE[table][0]
+
+
+def source_lanes(table: str, epoch_events: int) -> int:
+    """Lanes a device source of `table` makes an epoch: the pow2 bucket
+    of the table's row bound, and never more than the epoch's events
+    (bid at a pow2 cadence: 46 of 50 events are its own). Static:
+    arithmetic on the generator's proportions and the cadence, nothing
+    observed."""
+    from .capacity import bucket
+    return min(epoch_events, bucket(own_rows_bound(table, epoch_events)))
+
+
+def own_event_ids(table: str, event_lo, lanes: int):
+    """The first `lanes` event ids of `table` at or after `event_lo`,
+    ascending (int64): the row of ordinal q is event
+    (q // p) * 50 + o + q % p. `event_lo` is a traced scalar; the lane
+    arithmetic stays 32-bit (lanes + p < 2^31), only the block base is
+    64-bit."""
+    p, o = _SHARE[table]
+    full, rem = jnp.divmod(event_lo, TOTAL_PROPORTION)
+    before = full * p + jnp.clip(rem - o, 0, p)   # own rows below event_lo
+    b_full, b_rem = jnp.divmod(before, p)
+    q, r = jnp.divmod(
+        b_rem.astype(jnp.int32) + jnp.arange(lanes, dtype=jnp.int32), p)
+    return ((b_full + q.astype(jnp.int64)) * TOTAL_PROPORTION
+            + (o + r).astype(jnp.int64))
 
 
 # ---------------------------------------------------------------------------

@@ -76,7 +76,9 @@ what is finer sits inside the phases as child spans:
     of such a job carries `shard_report` (`FusedJob.shard_report()`)
   rw:step carries `node`, `i` and what `Node.span_attrs()` adds: the
     agg step over a pre-combined delta says `recombine` (true behind an
-    exchange, false where it takes the pre-combine's delta as it is)
+    exchange, false where it takes the pre-combine's delta as it is); a
+    device source's step says `lanes` (the lanes of the delta it made,
+    all shards': its own table's rows, pow2) `of` the epoch's events
   rw:compile (worker thread)       rw:ingest.poll | .pack | .h2d (stager)
   rw:pack > rw:ingest.wait (the dispatch thread blocked on the stager)
   rw:sql > rw:sql.fuse_plan

@@ -372,3 +372,74 @@ def test_reduce_stage_of_the_planned_agg_step(armed, shards,
     else:
         assert heavy == set()
         assert stage == jaxpr_prims_under(jaxpr, "passthrough") != set()
+
+
+SELLERS_MV = ("CREATE MATERIALIZED VIEW sellers AS SELECT seller,"
+              " count(*) AS n, max(date_time) AS last FROM auction"
+              " GROUP BY seller")
+
+
+def _drive_sellers(armed, seed, shards):
+    """The auction stream grouped by seller at the "padded" cadence (2,015
+    events an epoch, 504 a shard, the last block 3 short): (rows, job, the
+    job's spans)."""
+    import nexmark_ref_entities as ent
+    from risingwave_tpu.connectors.nexmark import (NexmarkConfig,
+                                                   NexmarkGenerator)
+    from risingwave_tpu.device import fuse_planner
+    chunk, polls, events = CADENCES["padded"]
+    armed.setattr(fuse_planner, "EPOCH_POLLS", polls)
+    db = Database(device=DeviceConfig(capacity=CAPACITY, mesh_shards=shards,
+                                      mv_persist_every=64),
+                  checkpoint_frequency=8)
+    db._nexmark_gen = NexmarkGenerator(NexmarkConfig(seed=seed))
+    db.run(ent.AUCTION_SOURCE_SQL.format(events=events, chunk=chunk))
+    db.run(SELLERS_MV)
+    job = db.catalog.get("sellers").runtime["fused_job"]
+    assert job is not None and job.program.epoch_events == chunk * polls
+    while job.counter < job.max_events or job.committed < job.counter:
+        db.tick()
+    job.sync()
+    rows = sorted(tuple(int(v) for v in r)
+                  for r in db.query("SELECT * FROM sellers"))
+    return rows, job, [s for s in SPANS
+                       if s.get("inst") == job.profiler.instance]
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_an_auction_rooted_group_by_on_four_shards(armed, seed):
+    """A source that makes only its own table's ids, under `shard_map` at a
+    cadence that does not divide by 4: every shard makes the auctions of its
+    504-event block over 256 lanes, the padded tail (ids of the next epoch)
+    is masked, and the MV equals the one-shard run's and the frozen
+    reference's (`nexmark_ref_entities`)."""
+    import nexmark_ref_entities as ent
+    from risingwave_tpu.device.nexmark_gen import source_lanes
+    chunk, polls, events = CADENCES["padded"]
+    epoch = chunk * polls
+    assert epoch % SHARDS and -(-epoch // SHARDS) == 504
+    rows4, job4, spans4 = _drive_sellers(armed, seed, SHARDS)
+    rows1, job1, spans1 = _drive_sellers(armed, seed, 1)
+    assert job4.program.mesh.devices.size == SHARDS \
+        and job1.program.mesh is None
+    assert job4.growth_replays == job1.growth_replays == 0
+    auction = ent.auction_columns(seed, ent.auction_event_ids(0, events))
+    want = {}
+    for seller, ts in zip(auction["seller"].tolist(),
+                          auction["date_time"].tolist()):
+        n, last = want.get(seller, (0, 0))
+        want[seller] = (n + 1, max(last, ts))
+    want = sorted((s, n, last) for s, (n, last) in want.items())
+    assert len(want) > 100 and rows4 == rows1 == want
+    # lanes: what the rule gives a 504-event block, four times; one shard
+    # the epoch's; the flow counters agree on the live rows
+    for job, spans, shards in ((job4, spans4, SHARDS), (job1, spans1, 1)):
+        lanes = shards * source_lanes("auction", -(-epoch // shards))
+        assert lanes == {SHARDS: 1024, 1: 256}[shards] < epoch
+        src = job.flow_report()["nodes"][0]
+        assert src["node"].startswith("chain_source_auction")
+        assert src["lanes"] == lanes
+        assert src["rows_out"] == len(auction["seller"])
+        steps = _step_spans(spans, 0)
+        assert steps and all((s["lanes"], s["of"]) == (lanes, epoch)
+                             for s in steps)

@@ -91,6 +91,10 @@ def test_every_barrier_yields_the_span_tree(run):
         assert [s["node"] for s in steps] == job.program.node_names
         # (an agg over raw rows has no pre-combined delta to speak of)
         assert not any("recombine" in s for s in steps)
+        # the source says the lanes it makes: bid's are the epoch's events
+        assert [(s["lanes"], s["of"]) for s in steps if "lanes" in s] \
+            == [(job.program.epoch_events,) * 2]
+        assert "lanes" in steps[0]
         assert all((s["seq"], s["epoch"], s["inst"])
                    == (ep["seq"], ep["epoch"], ep["inst"]) for s in steps)
         lo = [s for s in spans if s["parent"] == kids[0]["id"]]
@@ -253,6 +257,36 @@ def test_module_is_named_after_its_node_and_scopes_are_metadata(
     assert fused._STACK_JIT.lower((jnp.int64(1),)).as_text().startswith(
         "module @jit_stats_stack")
     assert fused._named(lambda: 0, "tier_x").__name__ == "tier_x"
+
+
+def test_source_step_says_the_lanes_it_makes():
+    """A person source makes one row of fifty events: its `rw:step` span
+    carries `lanes` (the pow2 bucket of the table's rows an epoch, what
+    `flow_report()` reads off the delta it handed on) and `of`, the
+    epoch's events; no other step says either."""
+    from bench import PERSON_SRC
+    first = len(profile.SPANS)
+    db = Database(device=DeviceConfig(capacity=512))
+    db.run(PERSON_SRC.format(n=4 * 64 * 512, c=512))
+    db.run("CREATE MATERIALIZED VIEW by_state AS SELECT state, count(*) AS c"
+           " FROM person GROUP BY state")
+    job = db._fused["by_state"]
+    for _ in range(7):
+        db.tick()
+    job.sync()
+    assert sum(r[1] for r in db.query("SELECT * FROM by_state")) \
+        == 4 * 64 * 512 // 50 + 1
+    epoch = job.program.epoch_events
+    assert epoch == 32_768
+    steps = [s for s in list(profile.SPANS)[first:]
+             if s["name"] == "rw:step"
+             and s.get("inst") == job.profiler.instance]
+    src = [s for s in steps if s["i"] == 0]
+    assert len(src) == 4 and "source_person" in src[0]["node"]
+    # (32,768 // 50 + 2) rows -> 1,024 lanes
+    assert all((s["lanes"], s["of"]) == (1024, epoch) for s in src)
+    assert not any("lanes" in s or "of" in s for s in steps if s["i"] != 0)
+    assert job.flow_report()["nodes"][0]["lanes"] == 1024
 
 
 def test_precombined_agg_step_says_it_reduces_once(monkeypatch):
