@@ -1714,15 +1714,9 @@ class JoinNode(Node):
         (bjk, bpk, bsg, bmk, bvals) = sides[1]
         # per-shard local step under mesh sharding, the whole step on one
         # chip: probe + merge + cross-delta pair netting (join_step)
-        # a join with a condition beyond its keys counts the rows of its
-        # pair slots (join_step.probe, `count`) where the others search
-        # them: the searched form is the slower one for every join, but
-        # the equi-joins' step programs stay the ones their cells were
-        # measured with until a PR re-measures them (PERF.md, PR 34)
         new_a, new_b, njk, npk, nsign, nvals, needed, *trails = \
             local_join_step(a, b, ajk, apk, asg, amk, avals,
-                            bjk, bpk, bsg, bmk, bvals, self.m, self.tier,
-                            self.cond is not None)
+                            bjk, bpk, bsg, bmk, bvals, self.m, self.tier)
         omask = nsign != 0
         ocols = list(nvals)
         if self.cond is not None:

@@ -9,8 +9,12 @@ the per-epoch maintenance is the same sort-merge pattern as the agg state
 
     out  =  dA >< B_old   +   A_new >< dB          (A_new = A_old + dA)
 
-Ragged match output becomes static-shape via a cumsum expansion: pair t maps
-back to its probe row by searchsorted over the running match-count offsets.
+A probe does ONE binary search a probe row (where its key's run starts in
+the side) and none a pair slot: where the run ends is read off the side (a
+reverse prefix minimum over its "last of its run" flags), and the ragged
+match output becomes static-shape via a counted expansion — every probe row
+marks the pair slot its running match count names, a prefix sum of the marks
+maps pair t back to its probe row.
 Inner joins only — outer/semi/anti need degree bookkeeping and stay on the
 exact host path (join.py), the same split the reference draws between its
 fast append-only executors and the general ones.
@@ -143,38 +147,49 @@ def mark_key_runs(jk: jax.Array, queries: jax.Array) -> jax.Array:
     return (jax.lax.associative_scan(jnp.maximum, code) & 1) == 1
 
 
-def probe(side: JoinSide, qjk, qmask, m: int, count: bool = False):
+def probe(side: JoinSide, qjk, qmask, m: int):
     """All matches of each probe key: (probe_row[m], state_idx[m], mask[m],
-    needed_pairs). Ragged -> static via cumsum + searchsorted expansion.
+    needed_pairs). One binary search a probe row, none a pair slot.
 
-    The expansion asks, for every pair slot t, how many of the probe rows'
-    running match counts are <= t. As a search that is log2(rows) gathers a
-    slot; with `count` the same number is counted instead: every row marks
-    the slot its running count names (one scatter a ROW) and a prefix sum
-    spreads the marks — the slots are 0..m-1, so no slot searches."""
+    `range`: `lo`, where a key's run starts in the side, is searched; where
+    it ends is a property of the sorted SIDE, not of the query — one pass
+    over the capacity gives every slot the last slot of its run (a reverse
+    prefix minimum over "last of its run" positions), and `hi` is one gather
+    a probe row. `expand`: pair slot t belongs to the probe row whose running
+    match count is the first above t, i.e. to as many rows as have counts
+    <= t; the slots are 0..m-1, so every row marks the slot its running
+    count names (one scatter a ROW, counts past the buffer dropped) and a
+    prefix sum spreads the marks."""
+    c = side.jk.shape[0]
     qjk = jnp.where(qmask, qjk, EMPTY_KEY)
-    lo = jnp.searchsorted(side.jk, qjk, side="left", method=search_method())
-    hi = jnp.searchsorted(side.jk, qjk, side="right", method=search_method())
-    cnt = jnp.where(qmask & (qjk != EMPTY_KEY), hi - lo, 0)
-    off = running_sum(cnt)
-    total = off[-1]
-    t = jnp.arange(m)
-    if count:
+    with jax.named_scope("range"):
+        lo = jnp.searchsorted(side.jk, qjk, side="left",
+                              method=search_method())
+        last = jnp.concatenate([side.jk[1:] != side.jk[:-1],
+                                jnp.ones((1,), bool)])
+        run_end = jax.lax.associative_scan(
+            jnp.minimum, jnp.where(last, jnp.arange(c, dtype=jnp.int32), c),
+            reverse=True)
+        at = jnp.minimum(lo, c - 1)
+        hi = jnp.where((lo < c) & (side.jk[at] == qjk), run_end[at] + 1, lo)
+        cnt = jnp.where(qmask & (qjk != EMPTY_KEY), hi - lo, 0)
+    with jax.named_scope("expand"):
+        off = running_sum(cnt)
+        total = off[-1]
+        t = jnp.arange(m)
         row = running_sum(jnp.zeros((m,), jnp.int32).at[off].add(
             1, mode="drop")).astype(jnp.int32)
-    else:
-        row = jnp.searchsorted(off, t, side="right", method=search_method())
-    row_c = jnp.clip(row, 0, qjk.shape[0] - 1)
-    prev = jnp.where(row_c > 0, off[row_c - 1], 0)
-    sidx = lo[row_c] + (t - prev)
-    mask = t < total
-    return row_c, jnp.clip(sidx, 0, side.jk.shape[0] - 1), mask, total
+        row_c = jnp.clip(row, 0, qjk.shape[0] - 1)
+        prev = jnp.where(row_c > 0, off[row_c - 1], 0)
+        sidx = lo[row_c] + (t - prev)
+        mask = t < total
+    return row_c, jnp.clip(sidx, 0, c - 1), mask, total
 
 
 def join_core(a: JoinSide, b: JoinSide,
               a_jk, a_pk, a_sign, a_mask, a_vals,
               b_jk, b_pk, b_sign, b_mask, b_vals, m: int,
-              trail: bool = False, count: bool = False):
+              trail: bool = False):
     """One epoch of both sides' rows -> (new states, pair change set).
     Unjitted core, shared by the per-operator engine's step below and the
     fused `JoinNode` (device/fused.py). With `trail` a sixth value holds the
@@ -193,7 +208,7 @@ def join_core(a: JoinSide, b: JoinSide,
                                                        b_mask, b_vals)
     # dA >< B_old
     with jax.named_scope("join.probe"):
-        r1, s1, m1, need1 = probe(b, dajk, dasign != 0, m, count)
+        r1, s1, m1, need1 = probe(b, dajk, dasign != 0, m)
     with jax.named_scope("join.emit"):
         out1 = {
             "sign": jnp.where(m1, dasign[r1], 0),
@@ -210,7 +225,7 @@ def join_core(a: JoinSide, b: JoinSide,
                                                dbvals, trail)
     # A_new >< dB
     with jax.named_scope("join.probe"):
-        r2, s2, m2, need2 = probe(new_a, dbjk, dbsign != 0, m, count)
+        r2, s2, m2, need2 = probe(new_a, dbjk, dbsign != 0, m)
     with jax.named_scope("join.emit"):
         out2 = {
             "sign": jnp.where(m2, dbsign[r2], 0),
@@ -239,7 +254,7 @@ def join_epoch_step(a: JoinSide, b: JoinSide,
 def local_join_step(a: JoinSide, b: JoinSide,
                     a_jk, a_pk, a_sign, a_mask, a_vals,
                     b_jk, b_pk, b_sign, b_mask, b_vals, m: int,
-                    trail: bool = False, count: bool = False):
+                    trail: bool = False):
     """One epoch's LOCAL join step: join_core plus cross-delta pair
     netting (the r02 pair-resurrection fix) over the rows this program
     instance owns. On a single chip that is every row; under mesh
@@ -254,15 +269,16 @@ def local_join_step(a: JoinSide, b: JoinSide,
     with `trail`, join_core's pair of merge trails last."""
     new_a, new_b, o1, o2, needed, *trails = join_core(
         a, b, a_jk, a_pk, a_sign, a_mask, a_vals,
-        b_jk, b_pk, b_sign, b_mask, b_vals, m, trail, count)
+        b_jk, b_pk, b_sign, b_mask, b_vals, m, trail)
     cat = lambda k: jnp.concatenate([o1[k], o2[k]])
     catv = lambda k, i: jnp.concatenate([o1[k][i], o2[k][i]])
-    sign = cat("sign")
-    mask = cat("mask") & (sign != 0)
-    pvals = [catv("a_vals", i) for i in range(len(a_vals))] \
-        + [catv("b_vals", i) for i in range(len(b_vals))]
-    njk, npk, nsign, nvals = batch_reduce_rows(
-        cat("a_pk"), cat("b_pk"), sign, mask, pvals)
+    with jax.named_scope("join.net"):
+        sign = cat("sign")
+        mask = cat("mask") & (sign != 0)
+        pvals = [catv("a_vals", i) for i in range(len(a_vals))] \
+            + [catv("b_vals", i) for i in range(len(b_vals))]
+        njk, npk, nsign, nvals = batch_reduce_rows(
+            cat("a_pk"), cat("b_pk"), sign, mask, pvals)
     return (new_a, new_b, njk, npk, nsign, nvals, needed, *trails)
 
 

@@ -175,7 +175,9 @@ def test_the_rehearsal_of_the_cell_is_correct(armed):
     own = [m["name"] for m, _ in cell.metrics("per_layer")
            if "workloads" in m]
     assert own == ["join_fill_pct", "pairs_fill_pct", "slots_used_pct"]
-    result = run.run_cell(cell, SEEDS[1], 30.0, trace=True, rehearse=True)
+    # the stream is 40 epochs whatever the seconds, which only stop the
+    # admission: 22 s alone here, over 30 s beside five busy test workers
+    result = run.run_cell(cell, SEEDS[1], 120.0, trace=True, rehearse=True)
     assert result["correct"], result["compared"]
     assert result["rehearsal"] and result["metrics"] == {}
     assert result["counts"] == {"events_committed": 40_960, "epochs": 40,
@@ -269,29 +271,92 @@ def test_a_preset_too_small_grows_and_still_matches(armed):
         CODE.reference(seed, events))
 
 
-@pytest.mark.parametrize("m", [16, 64, 512])
-def test_the_counted_expansion_is_the_searched_one(m):
-    """`join_step.probe` with `count` (the form q5's join takes: every probe
-    row marks the pair slot its running match count names, a prefix sum
-    spreads the marks) gives what the search gives on every lane — the
-    lanes past the matches and a buffer the matches overflow included."""
+def _searched_probe(side, qjk, qmask, m):
+    """The plain reference of `join_step.probe`: three binary searches —
+    where a probe row's key starts in the side, where its run ends, and for
+    every pair slot how many running match counts are <= its number."""
+    import jax.numpy as jnp
+    from risingwave_tpu.device.sorted_state import EMPTY_KEY
+    qjk = jnp.where(qmask, qjk, EMPTY_KEY)
+    lo = jnp.searchsorted(side.jk, qjk, side="left")
+    hi = jnp.searchsorted(side.jk, qjk, side="right")
+    off = jnp.cumsum(jnp.where(qmask & (qjk != EMPTY_KEY), hi - lo, 0)
+                     .astype(jnp.int64))
+    t = jnp.arange(m)
+    row = jnp.clip(jnp.searchsorted(off, t, side="right"),
+                   0, qjk.shape[0] - 1)
+    prev = jnp.where(row > 0, off[row - 1], 0)
+    sidx = jnp.clip(lo[row] + (t - prev), 0, side.jk.shape[0] - 1)
+    return row, sidx, t < off[-1], off[-1]
+
+
+def _probe_is_the_searched_one(jk, qjk, qmask, m):
+    """`probe` against the reference on every lane of `row`, `sidx`, `mask`
+    and of `total`; returns `total`."""
     import jax.numpy as jnp
     import numpy as np
     from risingwave_tpu.device.join_step import JoinSide, probe
+    side = JoinSide(jnp.asarray(jk, jnp.int64),
+                    jnp.arange(len(jk), dtype=jnp.int64),
+                    jnp.int32(0), ())      # `count` is not read by a probe
+    qjk, qmask = jnp.asarray(qjk, jnp.int64), jnp.asarray(qmask, bool)
+    searched = _searched_probe(side, qjk, qmask, m)
+    ours = probe(side, qjk, qmask, m)
+    for s, o in zip(searched, ours):
+        assert np.array_equal(np.asarray(s), np.asarray(o))
+    return int(ours[3])
+
+
+@pytest.mark.parametrize("m", [16, 64, 512])
+def test_the_counted_expansion_is_the_searched_one(m):
+    """`join_step.probe` (every probe row marks the pair slot its running
+    match count names, a prefix sum spreads the marks; a key's run ends
+    where the side says it does) gives what three searches give on every
+    lane — the lanes past the matches and a buffer the matches overflow
+    included."""
+    import numpy as np
     from risingwave_tpu.device.sorted_state import EMPTY_KEY
     rng = np.random.default_rng(m)
     overflowed = 0
     for _ in range(8):
         live = int(rng.integers(0, 65))
         jk = np.concatenate([np.sort(rng.integers(0, 12, live)),
-                             np.full(64 - live, EMPTY_KEY)]).astype(np.int64)
-        side = JoinSide(jnp.asarray(jk), jnp.arange(64, dtype=jnp.int64),
-                        jnp.int32(live), ())
-        qjk = jnp.asarray(np.sort(rng.integers(0, 14, 32)).astype(np.int64))
-        qmask = jnp.asarray(rng.random(32) < 0.7)
-        searched = probe(side, qjk, qmask, m)
-        counted = probe(side, qjk, qmask, m, count=True)
-        overflowed += int(searched[3]) > m
-        for s, c in zip(searched, counted):
-            assert np.array_equal(np.asarray(s), np.asarray(c))
+                             np.full(64 - live, EMPTY_KEY)])
+        total = _probe_is_the_searched_one(
+            jk, np.sort(rng.integers(0, 14, 32)), rng.random(32) < 0.7, m)
+        overflowed += total > m
     assert overflowed or m == 512
+
+
+def _range_end_cases():
+    import numpy as np
+    from risingwave_tpu.device.sorted_state import EMPTY_KEY
+    pads = lambda n: np.full(n, EMPTY_KEY)
+    some = np.concatenate([np.repeat([2, 5, 5, 9], [3, 1, 4, 2]), pads(6)])
+    q = np.array([0, 2, 2, 5, 7, 9, 9, 11])
+    yes = np.ones(8, bool)
+    return {   # name: (side keys, query keys, query mask, m, matches)
+        "empty_side": (pads(16), q, yes, 32, 0),
+        "full_side_no_pads": (np.repeat([1, 2, 5, 9], 4), q, yes, 64, 20),
+        "one_key_fills_the_side": (np.full(16, 5), q, yes, 64, 16),
+        "every_query_absent": (some, np.array([0, 1, 3, 4, 6, 7, 8, 10]),
+                               yes, 32, 0),
+        "every_query_masked": (some, q, ~yes, 32, 0),
+        "a_query_is_the_sides_last_key": (
+            np.repeat([1, 2, 5, 9], 4), np.array([9] * 8), yes, 64, 32),
+        "a_query_is_the_last_live_key": (some, np.array([9] * 8), yes, 32,
+                                         16),
+        "a_query_is_the_pad_key": (some, np.concatenate([q[:7], pads(1)]),
+                                   yes, 32, 15),
+        "m_smaller_than_the_matches": (some, q, yes, 8, 15),
+        "a_side_of_one_slot": (np.array([5]), q, yes, 8, 1),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_range_end_cases()))
+def test_a_match_range_ends_where_the_sides_run_ends(case):
+    """The probe reads where a key's run ends off the side (one pass over
+    the capacity, one gather a probe row) where the reference searches:
+    equal on every lane at the edges of the side and of the pair buffer."""
+    jk, qjk, qmask, m, matches = _range_end_cases()[case]
+    assert _probe_is_the_searched_one(jk, qjk, qmask, m) == matches
