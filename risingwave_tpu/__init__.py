@@ -18,6 +18,12 @@ Layer map (mirrors SURVEY.md §1, re-hosted):
   connectors/  L6 sources (nexmark, datagen) and sinks
   meta/        L8 control plane: catalog, DDL, checkpoint coordination
 """
+import time as _time
+
+# the package's first line on the spans' clock: where `rw:boot.import`
+# starts (utils/profile.py `boot_done`, called where the import of
+# `risingwave_tpu.device` ends)
+_T_IMPORT = _time.perf_counter_ns()
 
 __version__ = "0.1.0"
 

@@ -66,8 +66,11 @@ Commands:
                               serving from host memory"
     compile-status [JOB]      per-signature AOT compile state of every
                               fused job (pending / ready / cached /
-                              failed, with capacity bucket and compile
-                              seconds) plus the job's plan-shape hash —
+                              failed, with capacity bucket, compile
+                              seconds, `cache_hit` = the compile manifest
+                              knew the signature, `persistent` = what jax
+                              did: hit / miss / off) plus the job's
+                              plan-shape hash —
                               answers "why is this job still warming
                               up" and proves zero-compile warm starts;
                               --wait SECS lets in-flight background

@@ -72,3 +72,10 @@ from .sorted_state import (  # noqa: E402,F401
     merge,
     merge_changes,
 )
+
+# the process has started: `rw:boot` (OS process start to here) and the
+# listeners that put jax's own compile events on the program's spans
+from .. import _T_IMPORT  # noqa: E402
+from ..utils.profile import boot_done as _boot_done  # noqa: E402
+
+_boot_done(_T_IMPORT)

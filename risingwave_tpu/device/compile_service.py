@@ -32,10 +32,23 @@ Three pillars:
   executable cache itself is shared, so DROP + re-CREATE (or a second
   identically-shaped job) performs zero fresh compiles.
 
-Observability: every finished compile lands in the requesting job's
-profiler (`utils/profile.py`) with `bucket`/`aot`/`cache_hit` labels, and
-`risectl compile-status <job>` reports pending/ready/cached per
-signature. `DeviceConfig.aot_compile=False` restores inline compiles.
+Observability: a compile leaves ONE record, its `rw:compile` span
+(`utils/profile.py`; on the requesting job's profiler, or a span of no job
+where the requester has none; nothing where profiling is off). The span
+says whose compile it was (`node`, `label`, `kind`, `bucket`, `aot`) and
+carries two words that mean different things: `cache_hit` — the compile
+MANIFEST knew the signature's digest (some process compiled it once) — and
+`persistent` — what jax did this time (`hit`: read from the persistent
+cache, `retrieval_s`; `miss`: asked and built, `backend_compile_s`; `off`:
+no cache), taken from jax's own events on the compiling thread
+(`profile.take_compiled`). `cache_hit` with `persistent == "miss"` is
+`lost`: an executable the machine was thought to have. The job profiler's
+labeled compile record (`compile_info`, `epoch_profile.jsonl`, `risectl
+profile`) is written where that span closes; `summary()` counts `built` /
+`loaded` / `lost` beside the manifest's `cache_hits`, and `risectl
+compile-status <job>` reports pending/ready/cached per signature with
+both words. `DeviceConfig.aot_compile=False` restores inline compiles
+(each a `rw:compile.inline` span under its `rw:step`).
 """
 from __future__ import annotations
 
@@ -78,6 +91,16 @@ except (OSError, AttributeError):        # not glibc: nothing to trim
 def _trim_heap(compile_s: float) -> None:
     if _MALLOC_TRIM is not None and compile_s >= TRIM_AFTER_S:
         _MALLOC_TRIM(0)
+
+
+def _code_bytes(compiled) -> Dict[str, int]:
+    """`{"code_bytes": n}` where the executable says how large its
+    generated code is (about what its persistent-cache entry holds)."""
+    try:
+        n = compiled.memory_analysis().generated_code_size_in_bytes
+    except Exception:                    # a backend without the analysis
+        return {}
+    return {"code_bytes": int(n)} if n else {}
 
 
 def _data_shards(mesh) -> int:
@@ -243,8 +266,9 @@ class CompileEntry:
     the entry was already ready or in flight (cached/shared)."""
 
     __slots__ = ("key", "digest", "label", "status", "compiled", "seconds",
-                 "bucket", "kind", "cache_hit", "error", "jobs", "sds",
-                 "node", "epoch_events", "salt", "profiler", "mesh")
+                 "bucket", "kind", "cache_hit", "persistent", "error",
+                 "jobs", "sds", "node", "epoch_events", "salt", "profiler",
+                 "mesh")
 
     def __init__(self, key, digest, label, node, epoch_events, salt, sds,
                  kind, profiler, mesh=None):
@@ -260,7 +284,8 @@ class CompileEntry:
         self.seconds = 0.0
         self.bucket = salt              # the capacity bucket(s) of the trace
         self.kind = kind                # "compile" | "retrace"
-        self.cache_hit = False
+        self.cache_hit = False          # the manifest knew the digest
+        self.persistent: Optional[str] = None  # jax: hit | miss | off
         self.error: Optional[str] = None
         self.jobs: Dict[str, bool] = {}
         self.profiler = profiler
@@ -293,7 +318,10 @@ class CompileService:
         # counters (bench warmup decomposition / compile-status)
         self.compiles_done = 0
         self.compiles_failed = 0
-        self.cache_hits = 0
+        self.cache_hits = 0             # by the manifest's word
+        # by jax's: programs it built, read from the persistent cache,
+        # and built although the manifest knew them
+        self.built = self.loaded = self.lost = 0
         self.compiled_steps = 0
         # steps served by the inline-jit fallback of a FAILED entry
         self.inline_steps = 0
@@ -346,15 +374,17 @@ class CompileService:
     def wait_idle(self, timeout: Optional[float] = None) -> bool:
         """Block until every queued/in-flight compile finished (tests,
         `risectl compile-status --wait`, session teardown)."""
+        from ..utils.profile import span
         deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cv:
-            while self._queue or self._inflight:
-                left = None if deadline is None \
-                    else deadline - time.monotonic()
-                if left is not None and left <= 0:
-                    return False
-                self._cv.wait(0.1 if left is None else min(0.1, left))
-        self._save_manifest()
+        with span("rw:compile_drain"):
+            with self._cv:
+                while self._queue or self._inflight:
+                    left = None if deadline is None \
+                        else deadline - time.monotonic()
+                    if left is not None and left <= 0:
+                        return False
+                    self._cv.wait(0.1 if left is None else min(0.1, left))
+            self._save_manifest()
         return True
 
     def shutdown(self, join: bool = True, timeout: float = 30.0) -> None:
@@ -576,15 +606,22 @@ class CompileService:
             if self.hold is not None:
                 ent_hold = self.hold
                 ent_hold.wait()
-            from ..utils.profile import NULL_PROFILER
+            from ..utils.profile import span, take_compiled
             from .fused import _jit_step
             state_s, ins_s, extra_s = ent.sds
             t0 = time.perf_counter()
+            # the compile's one record: a span of the requester's
+            # profiler (nothing where that is off), of no job where the
+            # requester has none
+            open_span = ent.profiler.span if ent.profiler is not None \
+                else span
             try:
-                with (ent.profiler or NULL_PROFILER).span(
+                with open_span(
                         "rw:compile", node=ent.node.stable_name(),
+                        label=ent.label, kind=ent.kind, aot=True,
                         bucket=repr(ent.bucket), cache_hit=ent.cache_hit,
                         ok=False) as sp:
+                    take_compiled()      # what this thread compiled before
                     if ent.mesh is not None:
                         from .shard_exec import sharded_jit_step
                         step = sharded_jit_step(ent.mesh, ent.node)
@@ -594,7 +631,9 @@ class CompileService:
                         state_s, ins_s, extra_s, node=ent.node,
                         epoch_events=ent.epoch_events, salt=ent.salt)
                     ent.compiled = lowered.compile()
-                    sp.set(ok=True)
+                    did = take_compiled(ent.cache_hit)
+                    ent.persistent = did.get("persistent")
+                    sp.set(ok=True, **did, **_code_bytes(ent.compiled))
             except Exception as e:
                 ent.seconds = time.perf_counter() - t0
                 ent.error = f"{type(e).__name__}: {e}"
@@ -620,6 +659,11 @@ class CompileService:
                 self.compiles_done += 1
                 if ent.cache_hit:
                     self.cache_hits += 1
+                if ent.persistent == "hit":
+                    self.loaded += 1
+                elif ent.persistent is not None:
+                    self.built += 1
+                self.lost += bool(did.get("lost"))
                 rec = {"label": ent.label, "s": round(ent.seconds, 3)}
                 if ent.mesh is not None:
                     from ..parallel.mesh import data_shards
@@ -629,11 +673,6 @@ class CompileService:
             # flush now (cheap, small json): a process that dies mid-run
             # still leaves its mirror manifests readable offline
             self._save_manifest()
-            if ent.profiler is not None and ent.profiler.enabled:
-                # bucket "()" = capacity rides in the avals, not the salt
-                ent.profiler.compile_event(
-                    ent.label, ent.seconds, kind=ent.kind, aot=True,
-                    bucket=repr(ent.bucket), cache_hit=ent.cache_hit)
             _trim_heap(ent.seconds)
         return task
 
@@ -696,7 +735,8 @@ class CompileService:
                  "kind": e.kind, "s": round(e.seconds, 3),
                  "shards": (_data_shards(e.mesh)
                             if e.mesh is not None else 1),
-                 "cache_hit": e.cache_hit, "error": e.error}
+                 "cache_hit": e.cache_hit, "persistent": e.persistent,
+                 "error": e.error}
                 for e in sorted(ents, key=lambda e: e.label)]
 
     def summary(self) -> Dict[str, Any]:
@@ -706,6 +746,8 @@ class CompileService:
         return {"compiles": self.compiles_done,
                 "failed": self.compiles_failed,
                 "cache_hits": self.cache_hits,
+                "built": self.built, "loaded": self.loaded,
+                "lost": self.lost,
                 "pending": pending,
                 "inline_steps": self.inline_steps,
                 "compiled_steps": self.compiled_steps,

@@ -38,7 +38,7 @@ import numpy as np
 from ..core import dtypes as T
 from ..core.dtypes import DataType, TypeKind
 from ..utils.failpoint import FailpointError, declare, failpoint
-from ..utils.profile import COMPILE_THRESHOLD_S, NULL_PROFILER
+from ..utils.profile import NULL_PROFILER
 
 # Fused device-path failure seams (fault-tolerance v3): each hook sits at
 # the point where a real device fault would surface — the async epoch
@@ -2267,19 +2267,16 @@ class FusedProgram:
         """Host loop over per-node jitted steps -> (states', the epoch's
         stat scalars): each call dispatches
         async; only device-array handles flow between nodes. With a live
-        profiler, each step is a `rw:step` span: a step flagged as
-        pending (cold start / post-growth) or blocking past the compile
-        threshold is recorded as a compile/retrace event — dispatch is
-        async, so a blocking step call IS trace+compile time.
+        profiler, each step is a `rw:step` span (`node`, `i`, `label`);
+        a compile jax makes inside it — the service off, or its fallback
+        — is a `rw:compile.inline` span under it and the job's
+        compile/retrace record (utils/profile.py).
 
         `feeds` maps node index -> staged device feed for `takes_feed`
         (host-ingest) nodes; the owning FusedJob's HostIngest stager
         supplies one per dispatched epoch."""
         import jax.numpy as jnp
-        prof = self.profiler
-        if prof is None or not prof.enabled:
-            prof = None
-        spans = prof or NULL_PROFILER
+        spans = self.profiler or NULL_PROFILER
         svc = self.compile_service
         mesh = self.mesh
         outs: List[Optional[Delta]] = []
@@ -2321,18 +2318,19 @@ class FusedProgram:
             else:
                 extra = None
             with spans.span("rw:step", node=self.node_names[i], i=i,
+                            label=self._node_label(i),
                             **node.span_attrs()) as sp:
                 if svc is not None:
                     # compile-service path: ready executables dispatch
                     # with zero trace; a pending one is waited for (the
-                    # service attributes the compile event, labeled,
-                    # when it lands, and the wait to `rw:compile_wait`)
+                    # service's `rw:compile` span is the compile's
+                    # record, the wait a `rw:compile_wait`)
                     kind = (self.profiler.pending_compile.pop(i, None)
                             if self.profiler is not None else None)
                     st, out, s, aux = svc.node_step(
                         node, self.epoch_events, states[i], ins, extra,
                         label=self._node_label(i), job=self.job_name,
-                        profiler=prof, kind=kind, mesh=mesh)
+                        profiler=self.profiler, kind=kind, mesh=mesh)
                 elif mesh is not None:
                     from .shard_exec import sharded_node_step
                     st, out, s, aux = sharded_node_step(
@@ -2345,11 +2343,6 @@ class FusedProgram:
                     # a device source says the lanes it made (all
                     # shards'), read off its delta
                     sp.set(lanes=out.mask.size, of=self.epoch_events)
-            if svc is None and prof is not None:
-                kind = prof.pending_compile.pop(i, None)
-                if kind is not None or sp.seconds > COMPILE_THRESHOLD_S:
-                    prof.compile_event(self._node_label(i), sp.seconds,
-                                       kind=kind or "retrace")
             new_states[i] = st
             outs.append(out)
             auxes.append(aux)
@@ -4132,7 +4125,7 @@ class FusedJob:
             return
         svc.prewarm_program(
             self.program.nodes, self.program.epoch_events, job=self.name,
-            profiler=self.profiler if self.profiler.enabled else None,
+            profiler=self.profiler,
             plan_hash=self.plan_hash, mesh=self.program.mesh,
             labels=[self.program._node_label(i)
                     for i in range(len(self.program.nodes))])
@@ -4172,7 +4165,7 @@ class FusedJob:
             svc.prewarm_program(
                 self.program.nodes, self.program.epoch_events,
                 job=self.name,
-                profiler=self.profiler if self.profiler.enabled else None,
+                profiler=self.profiler,
                 plan_hash=self.plan_hash, caps=caps,
                 mesh=self.program.mesh,
                 labels=[self.program._node_label(i)

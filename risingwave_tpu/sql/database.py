@@ -178,12 +178,18 @@ class Database:
         # per-barrier span tree (inject -> per-job collect -> commit),
         # ring-buffered for rw_barrier_trace and file-logged in the data
         # dir for offline hang diagnosis (risectl trace)
-        from ..utils.profile import spans
+        from ..utils.profile import boot_backend, spans
         from ..utils.trace import BarrierTracer
         # spans of no particular job (`rw:barrier`, `rw:store_commit`,
         # `rw:sql`, utils/profile.py) ride DeviceConfig.profile as the
         # jobs' own do
         self._span = spans(self.device is not None and self.device.profile)
+        if self.device is not None and not self._marker_readonly:
+            # the process's first device Database touches the backend
+            # here, under `rw:boot.backend`, not inside its first CREATE
+            # (an inspection tool's "auto" open touches nothing it need
+            # not)
+            boot_backend(self._span)
         self.tracer = BarrierTracer(data_dir, span=self._span)
         # flight recorder (utils/blackbox.py): point the process-wide
         # telemetry ring's on-disk mirror at this data dir so a crash or

@@ -190,8 +190,8 @@ def export_chrome(data_dir: str) -> Dict[str, Any]:
             events.append(_complete(
                 f"{rec.get('kind', 'compile')} {rec.get('label')}",
                 "compile", ts - dur, dur, f"fused:{job}", "compiles",
-                {k: rec[k] for k in ("bucket", "aot", "cache_hit")
-                 if k in rec}))
+                {k: rec[k] for k in ("bucket", "aot", "cache_hit",
+                                     "persistent") if k in rec}))
 
     # ---- flight recorder ring: control-plane instants ------------------
     # ladder transitions, shed windows, rebalance adoptions, recoveries,

@@ -35,9 +35,36 @@ never aggregate silently.
 
 Non-checkpoint epochs only carry pack+h2d+dispatch (their device work is
 paid for by the next sync — that asymmetry is the async-dispatch design,
-and exactly what the profiler exists to make visible). Compile/retrace
-events are timed separately and labeled by node signature so warmup time
-is decomposable from steady state.
+and exactly what the profiler exists to make visible).
+
+A compile leaves ONE record, its span. jax reports every trace, lowering,
+persistent-cache read and backend compile through `jax.monitoring`, on the
+thread that compiles; one pair of listeners (registered once, when
+`risingwave_tpu.device` is imported) gathers what a thread compiled and
+either the compile service takes it for its open `rw:compile` span
+(`take_compiled`) or, where no `rw:compile` is open on that thread, it
+becomes a `rw:compile.inline` span under the innermost open span (a node
+step jitted inline with the service off, the stats stack / fold, a tier,
+gather or exchange program, an eager primitive). Both carry what jax did:
+`persistent` (`"hit"`: the executable was read from the persistent cache,
+`retrieval_s` says how long that took; `"miss"`: jax asked the cache and
+built the program; `"off"`: jax never asked), `backend_compile_s` (the
+build's seconds — on a hit the load's, jax times the cache read inside it),
+`trace_s`, `lower_s`, `fun_name` (and a `rw:compile` the `programs` it
+took: one). Two words, two meanings:
+`cache_hit` is the compile MANIFEST's (some process compiled this
+signature's digest once), `persistent` is what jax did this time; a
+`rw:compile` with `cache_hit` and `persistent == "miss"` also says
+`lost=True`: an executable this machine was thought to have and jax built
+anew (a build shorter than the least jax writes an entry for is never
+lost: the cache never held it). `COMPILES` counts the same events process-wide, whatever span was or
+was not open. The labeled compile/retrace record of the old readers
+(`compile_info`, `epoch_profile.jsonl`, `summary()["compile_events"]`,
+`rw_epoch_profile`'s EXPLAIN lines, `risectl profile`) is written when such
+a span closes: a `rw:compile` that ended `ok`, or a `rw:compile.inline`
+directly under a `rw:step` (kind `compile` for a node's first, `retrace`
+after a growth or for any other re-trace jax really made — a slow step is
+no longer taken for one).
 
 Records land in a memory ring (the `rw_epoch_profile` system table) AND —
 when a data directory is attached — in `epoch_profile.jsonl`, appended at
@@ -82,9 +109,20 @@ what is finer sits inside the phases as child spans:
     all shards': its own table's rows, pow2) `of` the epoch's events;
     a hop's step says `fanout` (windows a row), an agg's that keeps
     retractable min/max multisets says `minputs` (how many)
-  rw:compile (worker thread)       rw:ingest.poll | .pack | .h2d (stager)
+  rw:compile (worker thread; `node`, `label`, `kind`, `bucket`,
+    `cache_hit`, `ok`, what jax did, `code_bytes` where the executable
+    says)                          rw:ingest.poll | .pack | .h2d (stager)
+  <any open span> > rw:compile.inline (what jax did, `node` where the
+    parent has one)
   rw:pack > rw:ingest.wait (the dispatch thread blocked on the stager)
   rw:sql > rw:sql.fuse_plan
+  rw:boot > rw:boot.start | rw:boot.import (the OS's process start — field
+    22 of /proc/self/stat on this clock — to the end of the import of
+    `risingwave_tpu.device`: `.start` is the interpreter and whatever the
+    caller imported before the package's first line, `.import` jax, x64,
+    the cache's placement and the program's own modules)
+  rw:boot.backend (a device Database's first touch of the backend; short
+    where the caller initialised it) rw:compile_drain (`wait_idle()`)
 
 Overhead when enabled is two clock reads, one annotation and one ring
 append per span, a few dozen spans per barrier; `DeviceConfig.profile=
@@ -109,10 +147,6 @@ _MAX_FILE_BYTES = 4 << 20
 PROFILE_SCHEMA = 2
 PHASES = ("pack", "h2d", "promote_h2d", "dispatch", "exchange",
           "device_sync", "demote_d2h", "commit")
-# a per-node step call slower than this is recorded as a compile/retrace
-# even when the profiler did not expect one (catches shape changes that
-# arrived through a path growth accounting doesn't flag)
-COMPILE_THRESHOLD_S = 0.25
 RING = 512
 
 # ---------------------------------------------------------------------------
@@ -210,14 +244,31 @@ class Span:
             top._ann.__exit__(*exc)
             if top is self:
                 break
+        self._record(stack)
+        return False
+
+    def _record(self, still_open: List["Span"]) -> None:
         t = threading.current_thread()
         SPANS.append({"id": self.id, "parent": self.parent,
                       "name": self.name, "t0": self.t0, "t1": self.t1,
                       "thread": t.ident, "tname": t.name,
                       **self.ids, **self.attrs})
         if self.owner is not None:
-            self.owner._span_closed(self, stack)
-        return False
+            self.owner._span_closed(self, still_open)
+
+    @classmethod
+    def past(cls, name: str, t0: int, t1: int,
+             parent: Optional["Span"] = None, **attrs) -> "Span":
+        """A span of this thread that is known only once it is over (a
+        compile jax reports when it ends, the start of the process):
+        recorded at once, as a child of `parent` with its identifiers
+        and its owner; no annotation, since its start has passed."""
+        sp = cls(name, attrs, owner=parent.owner if parent else None)
+        sp.id, sp.t0, sp.t1 = next(_SPAN_IDS), t0, t1
+        if parent is not None:
+            sp.parent, sp.ids = parent.id, {**parent.ids, **sp.ids}
+        sp._record(_open_stack())
+        return sp
 
 
 class _NullSpan:
@@ -256,6 +307,204 @@ def spans(enabled: bool):
     return span if enabled else null_span
 
 
+# ---------------------------------------------------------------------------
+# what jax says of a compile
+# ---------------------------------------------------------------------------
+
+COMPILE_SPANS = ("rw:compile", "rw:compile.inline")
+# a backend compile of at least this many seconds is a program jax BUILT
+# (a node step, an exchange); below it, an eager primitive or a tiny
+# program (`small`). The benchmark's `setup_compiles` draws the same line.
+BUILD_MIN_S = 1.0
+# jax's own totals for this process, counted from its events whatever span
+# is open: programs built / loaded from the persistent cache (and the
+# seconds of the builds and of the cache reads), builds under
+# BUILD_MIN_S, and executables the manifest promised and jax built anew
+COMPILES: Dict[str, Any] = {"built": 0, "built_s": 0.0, "small": 0,
+                            "small_s": 0.0, "loaded": 0, "loaded_s": 0.0,
+                            "lost": 0}
+_COMPILES_LOCK = threading.Lock()
+_EV_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_EV_HIT = "/jax/compilation_cache/cache_hits"
+_EV_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_EV_BACKEND = "/jax/core/compile/backend_compile_duration"
+_EV_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower"}
+_LISTENING = False
+
+
+def _pending() -> Dict[str, Any]:
+    """What this thread has traced, lowered and asked the cache since its
+    last backend compile."""
+    try:
+        return _OPEN.pending
+    except AttributeError:
+        _OPEN.pending = {"trace": [], "lower": []}
+        return _OPEN.pending
+
+
+def _on_event(event: str, **kwargs) -> None:
+    if event == _EV_ASKED:
+        _pending()["asked"] = True
+    elif event == _EV_HIT:
+        _pending()["hit"] = True
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    stage = _EV_STAGES.get(event)
+    if stage is not None:
+        # a jit traced inside another's trace reports first and lies
+        # inside the outer one's interval: keep the outermost only
+        t1 = time.perf_counter_ns()
+        t0 = t1 - int(duration * 1e9)
+        ivs = _pending()[stage]
+        ivs[:] = [iv for iv in ivs if iv[0] < t0]
+        ivs.append((t0, t1))
+    elif event == _EV_RETRIEVAL:
+        _pending()["retrieval_s"] = duration
+    elif event == _EV_BACKEND:
+        _backend_compiled(duration, str(kwargs.get("fun_name", "")))
+
+
+def _backend_compiled(seconds: float, fun_name: str) -> None:
+    """jax finished one backend compile (or cache read) on this thread:
+    count it, add it to what `take_compiled` hands the compile service
+    and, where no `rw:compile` is open here, make it a
+    `rw:compile.inline` span under the innermost open span."""
+    now = time.perf_counter_ns()
+    pend = _pending()
+    del _OPEN.pending
+    # jax "asks" a cache that has no directory too (the key is made
+    # before the directory is looked for): that is no cache, not a miss
+    import jax
+    persistent = ("hit" if pend.get("hit")
+                  else "miss" if pend.get("asked")
+                  and jax.config.jax_compilation_cache_dir else "off")
+    stack = _open_stack()
+    since = stack[-1].t0 if stack else 0
+    stages = {k: [iv for iv in pend[k] if iv[0] >= since]
+              for k in ("trace", "lower")}
+    info = {"fun_name": fun_name, "persistent": persistent,
+            "backend_compile_s": seconds,
+            "trace_s": sum(b - a for a, b in stages["trace"]) / 1e9,
+            "lower_s": sum(b - a for a, b in stages["lower"]) / 1e9}
+    if persistent == "hit":
+        info["retrieval_s"] = pend.get("retrieval_s", 0.0)
+    with _COMPILES_LOCK:
+        if persistent == "hit":
+            COMPILES["loaded"] += 1
+            COMPILES["loaded_s"] += info["retrieval_s"]
+        else:
+            kind = "built" if seconds >= BUILD_MIN_S else "small"
+            COMPILES[kind] += 1
+            COMPILES[kind + "_s"] += seconds
+    if stack and not any(sp.name == "rw:compile" for sp in stack):
+        parent = stack[-1]
+        t0 = min([now - int(seconds * 1e9)]
+                 + [a for ivs in stages.values() for a, _b in ivs])
+        if "node" in parent.attrs:
+            info["node"] = parent.attrs["node"]
+        Span.past("rw:compile.inline", max(t0, parent.t0), now, parent,
+                  **info)
+        return
+    # for `take_compiled`. Several programs before one take: the sums,
+    # and the last one's words
+    done = getattr(_OPEN, "compiled", None) or {"programs": 0}
+    for k in ("backend_compile_s", "trace_s", "lower_s", "retrieval_s"):
+        if k in done:
+            info[k] = info.get(k, 0.0) + done[k]
+    info["programs"] = done["programs"] + 1
+    _OPEN.compiled = info
+
+
+def take_compiled(cache_hit: bool = False) -> Dict[str, Any]:
+    """What jax compiled on this thread since the last take (`{}` where
+    nothing, or the listeners are not registered): the attributes of the
+    compile service's `rw:compile` span. `cache_hit` is the manifest's
+    word for the signature; where jax asked the cache and built the
+    program all the same, the executable was lost — unless the build was
+    shorter than the least jax writes an entry for: such a program was
+    never in the cache."""
+    info, _OPEN.compiled = getattr(_OPEN, "compiled", None) or {}, None
+    import jax
+    if cache_hit and info.get("persistent") == "miss" \
+            and info["backend_compile_s"] >= \
+            jax.config.jax_persistent_cache_min_compile_time_secs:
+        info["lost"] = True
+        with _COMPILES_LOCK:
+            COMPILES["lost"] += 1
+    return info
+
+
+def listen() -> None:
+    """Register the one pair of jax.monitoring listeners (idempotent).
+    They run at compile time only: a steady-state epoch never enters
+    them."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    _LISTENING = True
+    from jax import monitoring
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+# ---------------------------------------------------------------------------
+# the seconds before the first statement
+# ---------------------------------------------------------------------------
+
+_BOOTED = False
+_BACKEND_TOUCHED = False
+
+
+def _process_start_ns() -> Optional[int]:
+    """The OS's start of this process on the `perf_counter_ns` clock:
+    field 22 of /proc/self/stat (clock ticks after boot) against the
+    boot clock now. `None` where the OS does not say."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age_s = (time.clock_gettime(time.CLOCK_BOOTTIME)
+                 - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.perf_counter_ns() - int(age_s * 1e9)
+
+
+def boot_done(t_import: int) -> None:
+    """The end of the import of `risingwave_tpu.device` (its last line
+    calls this; `t_import` is the package's first line): record
+    `rw:boot` and its two parts, once a process, and start listening to
+    jax's compile events."""
+    global _BOOTED
+    if _BOOTED:
+        return
+    _BOOTED = True
+    listen()
+    t1 = time.perf_counter_ns()
+    t_proc = _process_start_ns()
+    t_proc = t_import if t_proc is None else min(t_proc, t_import)
+    boot = Span.past("rw:boot", t_proc, t1)
+    if t_proc < t_import:
+        Span.past("rw:boot.start", t_proc, t_import, boot)
+    Span.past("rw:boot.import", t_import, t1, boot)
+
+
+def boot_backend(span_fn) -> None:
+    """A device Database's first touch of the backend, once a process,
+    under `span_fn` (the Database's `span` or `null_span`). Short where
+    the caller initialised the backend already: the seconds before it are
+    then the caller's, and no span of the program covers them."""
+    global _BACKEND_TOUCHED
+    if _BACKEND_TOUCHED:
+        return
+    _BACKEND_TOUCHED = True
+    import jax
+    with span_fn("rw:boot.backend") as sp:
+        devs = jax.devices()
+        sp.set(platform=devs[0].platform, devices=len(devs))
+
+
 class JobProfiler:
     """Per-FusedJob epoch profiler. All methods are cheap no-ops when
     `enabled` is False; callers guard their own perf_counter reads on
@@ -283,10 +532,11 @@ class JobProfiler:
         self._cur: Optional[Dict[str, Any]] = None
         self.epochs = 0
         self.totals = {p: 0.0 for p in PHASES}
-        # node index -> reason ("compile" | "retrace") whose NEXT step
-        # call is expected to trace+compile (cold start, or capacity
-        # growth re-traced the node); filled by FusedJob, consumed by
-        # FusedProgram.epoch
+        # node index -> why its NEXT compile happens ("compile": cold
+        # start; "retrace": capacity growth re-traced the node); filled
+        # by FusedJob, read where that compile is requested
+        # (FusedProgram.epoch, for the service) or recorded (an inline
+        # compile's span closing under the node's `rw:step`)
         self.pending_compile: Dict[int, str] = {}
 
     # ---- wiring ----------------------------------------------------------
@@ -311,6 +561,9 @@ class JobProfiler:
         out of the outer one (`rw:exchange` in `rw:dispatch`); one deeper
         inside (the steps of a growth replay under `rw:device_sync`)
         feeds nothing, its time is the outer phase's."""
+        if sp.name in COMPILE_SPANS:
+            self._compile_closed(sp, still_open)
+            return
         phase = sp.name[len(SPAN_PREFIX):]
         if phase not in self.totals:
             return
@@ -378,15 +631,40 @@ class JobProfiler:
             pass             # the flight recorder must never fail an epoch
 
     # ---- compile / retrace events ---------------------------------------
+    def _compile_closed(self, sp: Span, still_open: List[Span]) -> None:
+        """A compile's span closed: write the labeled record the old
+        readers read. A `rw:compile` of the service that ended `ok`
+        carries its label and kind; a `rw:compile.inline` is a node's
+        compile only directly under that node's `rw:step`, which names
+        it (`label`, `i`)."""
+        a = sp.attrs
+        if sp.name == "rw:compile":
+            if not a.get("ok"):
+                return
+            label, kind = a["label"], a["kind"]
+        else:
+            step = next((o for o in still_open if o.id == sp.parent), None)
+            if step is None or step.name != "rw:step":
+                return
+            label = step.attrs["label"]
+            kind = self.pending_compile.pop(step.attrs["i"], "retrace")
+        self.compile_event(label, sp.seconds, kind=kind,
+                           bucket=a.get("bucket"), aot=a.get("aot", False),
+                           cache_hit=a.get("cache_hit", False),
+                           persistent=a.get("persistent"))
+
     def compile_event(self, label: str, seconds: float,
                       kind: str = "compile", bucket: Optional[str] = None,
-                      aot: bool = False, cache_hit: bool = False) -> None:
-        """Record one compile/retrace. `bucket` names the capacity bucket
-        the trace was shaped for, `aot` marks background (compile-service)
-        compiles vs inline ones, `cache_hit` marks executables served
-        from the persistent cache/manifest — together they decompose
-        warmup into named, attributable compiles. Thread-safe: the
-        compile service reports from its worker threads."""
+                      aot: bool = False, cache_hit: bool = False,
+                      persistent: Optional[str] = None) -> None:
+        """Record one compile/retrace; called where its span closes
+        (`_compile_closed`). `bucket` names the capacity bucket the
+        trace was shaped for, `aot` marks background (compile-service)
+        compiles vs inline ones, `cache_hit` signatures the compile
+        manifest knew, `persistent` what jax did (`hit` / `miss` /
+        `off`) — together they decompose warmup into named, attributable
+        compiles. Thread-safe: the compile service's spans close on its
+        worker threads."""
         rec = {"ev": "compile", "job": self.job, "label": label,
                "kind": kind, "s": seconds, "ts": time.time()}
         if bucket is not None:
@@ -395,6 +673,8 @@ class JobProfiler:
             rec["aot"] = True
         if cache_hit:
             rec["cache_hit"] = True
+        if persistent is not None:
+            rec["persistent"] = persistent
         with self._ev_lock:
             self.compile_info.append(rec)
             self._buf.append(rec)
@@ -581,6 +861,8 @@ def format_record(rec: Dict[str, Any]) -> Optional[str]:
         tags = "".join(
             f" {t}" for t in ("aot", "cache_hit") if rec.get(t))
         b = f" bucket={rec['bucket']}" if "bucket" in rec else ""
+        if "persistent" in rec:
+            tags += f" persistent={rec['persistent']}"
         return (f"[{rec.get('job')}] {rec.get('kind', 'compile')} "
                 f"{rec.get('label')} {rec.get('s', 0):.2f}s{b}{tags}")
     return None
@@ -618,7 +900,8 @@ def summarize_file(path: str, job: Optional[str] = None,
             elif rec.get("ev") == "compile":
                 agg["compiles"].append(
                     {k: rec[k] for k in ("label", "kind", "s", "bucket",
-                                         "aot", "cache_hit") if k in rec})
+                                         "aot", "cache_hit", "persistent")
+                     if k in rec})
     out = {}
     for j, agg in jobs.items():
         slow = sorted(agg.pop("_all"), key=lambda r: -r["wall_ms"])[:top]

@@ -229,6 +229,15 @@ def test_compile_events_labeled():
         idx, name, sig = rec["label"].split(":")
         assert name == job.program.node_names[int(idx)] and len(sig) == 8
         assert "bucket" in rec
+        assert rec["persistent"] == "off"    # jax's word: no cache here
+    # each record is the close of the compile's one span: same label,
+    # same seconds, on the worker that compiled
+    from risingwave_tpu.utils.profile import SPANS
+    spans = {s["label"]: s for s in SPANS if s["name"] == "rw:compile"
+             and s.get("inst") == job.profiler.instance}
+    assert {r["label"]: r["s"] for r in evs} \
+        == {k: (s["t1"] - s["t0"]) / 1e9 for k, s in spans.items()}
+    assert all(s["ok"] and s["kind"] == "compile" for s in spans.values())
     assert db.query("SELECT * FROM q4")
 
 
@@ -365,6 +374,10 @@ def test_ctl_compile_status(tmp_path, capsys, oracle):
     states = {r["state"] for r in rep["signatures"]}
     assert states <= {"ready", "cached"}, states
     assert rep["counts"]["pending"] == 0
+    # two words a row: the manifest's and jax's (no persistent cache in
+    # tier-1: whatever the manifest knew, jax built in this process)
+    assert all(isinstance(r["cache_hit"], bool) and r["persistent"] == "off"
+               for r in rep["signatures"])
     # unknown job: explicit failure
     with pytest.raises(SystemExit):
         ctl.main(["compile-status", "nope", "--data-dir", d])
@@ -546,7 +559,8 @@ def test_service_summary_counters():
 
 def test_aot_off_restores_inline_compiles(oracle):
     """DeviceConfig.aot_compile=False keeps the pre-ISSUE-6 lifecycle:
-    no service attached, inline compile events on the epoch loop."""
+    no service attached, inline compile events on the epoch loop (what
+    jax compiled inside a step; a growth's re-trace is a `retrace`)."""
     db = Database(device=DeviceConfig(capacity=64, aot_compile=False))
     db.run(BID_SRC.format(n=N, c=CHUNK))
     db.run(Q4.format(name="q4"))
@@ -555,4 +569,22 @@ def test_aot_off_restores_inline_compiles(oracle):
     assert job.program.compile_service is None
     drive(db)
     assert sorted(db.query("SELECT * FROM q4")) == oracle
-    assert job.profiler.compiles, "inline path must record its compiles"
+    # a plan of its own (another source signature, other aggregates), so
+    # that jax compiles whatever the process compiled before: every
+    # node's first compile, and the grown nodes' re-trace after a growth
+    db2 = Database(device=DeviceConfig(capacity=64, aot_compile=False))
+    db2.run(BID_SRC.format(n=N - 160, c=CHUNK))
+    db2.run("CREATE MATERIALIZED VIEW q4 AS SELECT auction, sum(price) AS s,"
+            " sum(bidder) AS t, max(bidder) AS m, count(*) AS c FROM bid"
+            " GROUP BY auction")
+    job = db2._fused["q4"]
+    drive(db2, n=N - 160)
+    assert job.growth_replays >= 1
+    compiles = job.profiler.compiles
+    assert compiles, "inline path must record its compiles"
+    firsts = [lab for lab, k, _s in compiles if k == "compile"]
+    assert sorted(firsts) == [job.program._node_label(i)
+                              for i in range(len(job.program.nodes))]
+    again = {lab for lab, k, _s in compiles if k == "retrace"}
+    assert again and again < set(firsts)
+    assert all("aot" not in r for r in job.profiler.compile_info)

@@ -36,18 +36,18 @@ def drive(db, n=N, chunk=CHUNK):
         db.tick()
 
 
-def _fused_db(data_dir=None, profile=True):
+def _fused_db(data_dir=None, profile=True, n=N):
     # aot_compile=False pins the INLINE compile lifecycle these tests
-    # assert (synchronous compile events on the epoch loop); the AOT
-    # service's async event contract is covered by
-    # tests/test_compile_service.py
+    # assert (synchronous compile events on the epoch loop: each a
+    # `rw:compile.inline` span under its `rw:step`); the AOT service's
+    # async event contract is covered by tests/test_compile_service.py
     db = Database(device=DeviceConfig(capacity=512, profile=profile,
                                       aot_compile=False),
                   data_dir=data_dir)
-    db.run(BID_SRC.format(n=N, c=CHUNK))
+    db.run(BID_SRC.format(n=n, c=CHUNK))
     db.run(Q4)
     assert (db.catalog.get("q4").runtime or {}).get("fused_job") is not None
-    drive(db)
+    drive(db, n)
     db._fused["q4"].sync()
     return db
 
@@ -58,7 +58,9 @@ def _fused_db(data_dir=None, profile=True):
 
 
 def test_epoch_profile_rows_and_phase_sums(tmp_path):
-    db = _fused_db(str(tmp_path / "d"))
+    # (a stream no other test of the process has compiled: a compile
+    # record is a compile jax really made, not a first step)
+    db = _fused_db(str(tmp_path / "d"), n=N - 96)
     rows = db.query("SELECT * FROM rw_epoch_profile")
     assert rows, "a fused run must produce epoch profile rows"
     for job, seq, events, shards, hp, h2d, pro, disp, exch, sync, dem, \
@@ -94,11 +96,21 @@ def test_epoch_profile_rows_and_phase_sums(tmp_path):
     # warmup is decomposable: the cold compiles were recorded and labeled
     assert prof.compiles, "cold per-node compiles must be recorded"
     kinds = {k for _l, k, _s in prof.compiles}
-    assert "compile" in kinds
+    assert kinds == {"compile"}            # no growth: nothing re-traced
     names = db._fused["q4"].program.node_names
     for label, _k, _s in prof.compiles:
         idx, name, sig = label.split(":")
         assert name == names[int(idx)] and len(sig) == 8
+    # each is the close of a `rw:compile.inline` span under that node's
+    # step: its seconds are jax's trace + lowering + backend compile, not
+    # the wall of a step that was slow
+    inline = [s for s in spans if s["name"] == "rw:compile.inline"
+              and s["parent"] in {t["id"] for t in spans
+                                  if t["name"] == "rw:step"}]
+    assert [(s["t1"] - s["t0"]) / 1e9 for s in inline] \
+        == [sec for _l, _k, sec in prof.compiles]
+    assert all(r.get("persistent") == "off" and "aot" not in r
+               for r in prof.compile_info)
 
 
 def test_fused_node_stats_table(tmp_path):

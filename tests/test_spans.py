@@ -32,6 +32,8 @@ Q4 = ("CREATE MATERIALIZED VIEW q4 AS SELECT auction, count(*) AS c,"
 NAMES = {"rw:" + p for p in profile.PHASES} | {
     "rw:barrier", "rw:store_commit", "rw:epoch", "rw:event_lo", "rw:step",
     "rw:stats_fold", "rw:stats_pull", "rw:compile_wait", "rw:compile",
+    "rw:compile.inline", "rw:compile_drain", "rw:boot", "rw:boot.start",
+    "rw:boot.import", "rw:boot.backend",
     "rw:growth", "rw:commit.mirror", "rw:commit.mirror.pull",
     "rw:commit.mirror.diff", "rw:commit.mirror.table_commit",
     "rw:commit.mirror.decode",
@@ -179,16 +181,28 @@ def _compiles_done():
 
 
 def test_profile_off_records_nothing():
+    from jax._src import monitoring
     _compiles_done()
-    before = (len(profile.SPANS), profile.SPANS[-1]["id"]
-              if profile.SPANS else None)
+
+    def ring():
+        # (the drain of `_compiles_done` is this test's own span)
+        mine = [s for s in profile.SPANS if s["name"] != "rw:compile_drain"]
+        return len(mine), mine[-1]["id"] if mine else None
+
+    before, listeners = ring(), len(monitoring.get_event_listeners())
+    compiled = sum(profile.COMPILES[k] for k in ("built", "small", "loaded"))
     db, job = fused_db(n=N - 64, profile=False)
     assert len(db.query("SELECT * FROM q4")) > 0
     _compiles_done()
     assert job.profiler.span("rw:anything") is profile.NULL_SPAN
     assert db._span is profile.null_span
-    assert (len(profile.SPANS), profile.SPANS[-1]["id"]
-            if profile.SPANS else None) == before
+    assert ring() == before
+    # jax compiled this stream's source all the same, and was counted:
+    # by the one pair of listeners, nothing registered per job or call
+    assert sum(profile.COMPILES[k]
+               for k in ("built", "small", "loaded")) > compiled
+    assert len(monitoring.get_event_listeners()) == listeners
+    assert job.profiler.compiles == []
     assert job.profiler.summary()["phase_s"] == {p: 0.0
                                                  for p in profile.PHASES}
     with profile.NULL_SPAN as sp:         # one shared object, no state
@@ -208,6 +222,312 @@ def test_a_span_is_named_rw_and_a_stale_one_goes_with_its_parent():
     a, b = list(profile.SPANS)[first:]
     assert (a["id"], a["parent"], a["epoch"]) == (outer.id, None, 7)
     assert (b["parent"], b["epoch"]) == (None, 8)       # not inside `a`
+
+
+# ---- what jax did for a compile, on the span of that compile ---------------
+
+JAX_DID = {"fun_name", "persistent", "backend_compile_s", "trace_s",
+           "lower_s"}
+
+
+def _compile_spans(since):
+    return [s for s in list(profile.SPANS)[since:]
+            if s["name"] in profile.COMPILE_SPANS]
+
+
+def _counted():
+    return {k: profile.COMPILES[k] for k in ("built", "small", "loaded",
+                                             "lost")}
+
+
+def test_a_service_compile_is_one_span_and_its_close_the_record(tmp_path):
+    """A compile of the service is ONE `rw:compile` span that says what jax
+    did, and the job's labeled compile record is that span, closed: the
+    old keys, plus `persistent`; in memory, in the file, in `summary()`."""
+    import json
+    _compiles_done()
+    first = len(profile.SPANS)
+    db = Database(device=DeviceConfig(capacity=512, compile_buckets=0),
+                  data_dir=str(tmp_path))
+    db.run(BID_SRC.format(n=N - 128, c=CHUNK))   # a source not yet compiled
+    db.run(Q4)
+    job = db._fused["q4"]
+    for _ in range(4):
+        db.tick()
+    job.sync()
+    _compiles_done()
+    spans = [s for s in _compile_spans(first) if s["name"] == "rw:compile"
+             and s.get("inst") == job.profiler.instance]
+    assert spans and all(s["ok"] and s["aot"] for s in spans)
+    for s in spans:
+        assert JAX_DID <= set(s) and s["programs"] == 1
+        assert s["persistent"] == "off"          # tier-1 places no cache
+        assert s["backend_compile_s"] > 0 and s["trace_s"] > 0 \
+            and s["lower_s"] > 0
+        assert s["fun_name"] == f"jit(step_{s['node']})"
+        assert s["backend_compile_s"] + s["trace_s"] + s["lower_s"] \
+            <= (s["t1"] - s["t0"]) / 1e9
+        assert "lost" not in s and s["tname"].startswith("rw-aot-")
+    recs = list(job.profiler.compile_info)
+    assert sorted(r["label"] for r in recs) == sorted(s["label"]
+                                                      for s in spans)
+    by_label = {s["label"]: s for s in spans}
+    for r in recs:
+        s = by_label[r["label"]]
+        assert set(r) >= {"ev", "job", "label", "kind", "s", "ts", "bucket",
+                          "aot", "persistent"}
+        assert (r["ev"], r["job"], r["kind"], r["aot"]) \
+            == ("compile", "q4", s["kind"], True)
+        assert r["s"] == (s["t1"] - s["t0"]) / 1e9    # the span's clock
+        assert (r["bucket"], r["persistent"]) == (s["bucket"], "off")
+    assert [e["label"] for e in job.profiler.summary()["compile_events"]] \
+        == [r["label"] for r in recs]
+    job.profiler.flush()
+    with open(os.path.join(str(tmp_path), profile.PROFILE_FILE)) as f:
+        filed = [r for r in map(json.loads, f) if r["ev"] == "compile"]
+    assert filed == recs
+    from risingwave_tpu.device.compile_service import get_service
+    rows = get_service().status("q4")
+    assert rows and {r["persistent"] for r in rows
+                     if r["state"] == "ready"} == {"off"}
+
+
+@pytest.fixture
+def placed_cache(tmp_path):
+    """jax's persistent cache placed in a directory of the test's, every
+    compile written to it; taken away again afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs)
+    _compiles_done()
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    cc.reset_cache()
+    yield str(tmp_path)
+    jax.config.update("jax_compilation_cache_dir", was[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", was[1])
+    cc.reset_cache()
+
+
+def _source_step(n):
+    import jax.numpy as jnp
+    db = Database(device=DeviceConfig(capacity=512, aot_compile=False))
+    db.run(BID_SRC.format(n=n, c=CHUNK))
+    db.run(Q4)
+    job = db._fused["q4"]
+    return (job.program.nodes[0], job.program.epoch_events, job.states[0],
+            (), jnp.int64(0))
+
+
+@pytest.mark.parametrize("entry_kept", [True, False],
+                         ids=["hit", "lost"])
+def test_persistent_says_what_jax_did_with_the_cache(placed_cache,
+                                                     entry_kept):
+    """The same program through three services that share a cache
+    directory, as three processes would: jax builds it and writes the entry
+    (`miss`); a second service finds the digest in the manifest
+    (`cache_hit`) and jax reads the entry (`hit`, `retrieval_s`); with the
+    entry deleted and the manifest kept, `cache_hit` still and `miss`
+    again: `lost`. jax's own counter moves with each."""
+    from risingwave_tpu.device import fused
+    from risingwave_tpu.device.compile_service import (MANIFEST_FILE,
+                                                       CompileService)
+    args = _source_step(N - 192 - 64 * entry_kept)
+
+    def compile_in_a_new_service():
+        # a fresh jit of the same text: what this process has traced and
+        # compiled for the node is another process's to this one
+        fused._JIT_STEPS.pop(args[0].stable_name())
+        first, was = len(profile.SPANS), _counted()
+        svc = CompileService(workers=1)
+        svc.node_step(*args, label="0:source")       # no profiler
+        assert svc.wait_idle(60)
+        svc.shutdown()
+        (span,) = [s for s in _compile_spans(first)
+                   if s["name"] == "rw:compile"]
+        assert "job" not in span and span["label"] == "0:source"
+        moved = {k: v - was[k] for k, v in _counted().items() if v != was[k]}
+        return span, svc.summary(), moved
+
+    span, summary, moved = compile_in_a_new_service()
+    assert (span["cache_hit"], span["persistent"]) == (False, "miss")
+    assert "retrieval_s" not in span and "lost" not in span
+    assert (summary["built"], summary["loaded"], summary["lost"],
+            summary["cache_hits"]) == (1, 0, 0, 0)
+    assert moved == {"small": 1}       # a build of under a second
+    entries = [f for f in os.listdir(placed_cache) if f != MANIFEST_FILE]
+    assert entries and MANIFEST_FILE in os.listdir(placed_cache)
+    if not entry_kept:
+        for f in entries:
+            os.remove(os.path.join(placed_cache, f))
+    span, summary, moved = compile_in_a_new_service()
+    assert span["cache_hit"] is True        # the manifest's word, kept
+    if entry_kept:
+        assert span["persistent"] == "hit" and span["retrieval_s"] > 0
+        assert "lost" not in span
+        assert (summary["built"], summary["loaded"], summary["lost"],
+                summary["cache_hits"]) == (0, 1, 0, 1)
+        assert moved == {"loaded": 1}
+    else:
+        assert span["persistent"] == "miss" and span["lost"] is True
+        assert (summary["built"], summary["loaded"], summary["lost"],
+                summary["cache_hits"]) == (1, 0, 1, 1)
+        assert moved == {"small": 1, "lost": 1}
+
+
+def test_a_build_too_short_for_the_cache_is_never_lost():
+    """jax writes no entry for a build shorter than
+    `jax_persistent_cache_min_compile_time_secs`: the manifest knows such
+    a program and the cache never held it, so a miss of it is no loss."""
+    import jax
+    least = jax.config.jax_persistent_cache_min_compile_time_secs
+    assert least > 0
+    was = profile.COMPILES["lost"]
+    for seconds, lost in ((least / 2, False), (least * 2, True)):
+        profile._OPEN.compiled = {"persistent": "miss", "programs": 1,
+                                  "backend_compile_s": seconds}
+        assert ("lost" in profile.take_compiled(True)) is lost
+    profile._OPEN.compiled = {"persistent": "hit", "programs": 1,
+                              "backend_compile_s": least * 2}
+    assert "lost" not in profile.take_compiled(True)
+    assert profile.COMPILES["lost"] == was + 1
+
+
+def test_a_jit_outside_the_service_leaves_one_inline_span():
+    """A jit called where no `rw:compile` is open — the stats fold, a
+    tier or gather program, an eager primitive — leaves exactly one
+    `rw:compile.inline` under the span that is open on that thread; under
+    an open `rw:compile` it leaves none (the service takes it); with no
+    span open, none either. jax's counter counts all three."""
+    import jax
+    import jax.numpy as jnp
+    x = jnp.arange(8)
+    jax.block_until_ready(x + 1)            # eager programs compiled here
+    first, was = len(profile.SPANS), _counted()
+    with profile.span("rw:stats_fold", job="j", inst=0, seq=3) as outer:
+        jax.jit(lambda v: v * 5 + 2)(x)
+    with profile.span("rw:compile", node="n"):
+        profile.take_compiled()      # what this thread compiled before
+        jax.jit(lambda v: v * 6 + 2)(x)
+        taken = profile.take_compiled()
+        assert profile.take_compiled() == {}       # taken once
+    jax.jit(lambda v: v * 7 + 2)(x)
+    (inline,) = _compile_spans(first)[:1]
+    names = [s["name"] for s in list(profile.SPANS)[first:]]
+    assert names == ["rw:compile.inline", "rw:stats_fold", "rw:compile"]
+    assert inline["parent"] == outer.id and JAX_DID <= set(inline)
+    assert (inline["job"], inline["seq"]) == ("j", 3)     # its parent's
+    assert outer.t0 <= inline["t0"] <= inline["t1"] <= outer.t1
+    assert inline["fun_name"] == "jit(<lambda>)" \
+        and inline["persistent"] == "off"
+    assert (inline["t1"] - inline["t0"]) / 1e9 \
+        >= inline["backend_compile_s"] > 0
+    assert taken["programs"] == 1 and taken["fun_name"] == "jit(<lambda>)"
+    moved = {k: v - was[k] for k, v in _counted().items()}
+    assert moved["built"] + moved["small"] == 3 and moved["loaded"] == 0
+
+
+def test_jaxs_counter_equals_the_spans_sums(run):
+    """Every backend compile jax reported while the job ran is in exactly
+    one compile span: `COMPILES` moved by the spans' programs and their
+    backend seconds."""
+    import jax
+    import jax.numpy as jnp
+    _compiles_done()
+    first, was = len(profile.SPANS), dict(profile.COMPILES)
+    with profile.span("rw:sql", kind="select"):
+        for k in (11, 12, 13):
+            jax.jit(lambda v, _k=k: v * _k - 1)(jnp.arange(4))
+    db, job = fused_db(n=N - 320)
+    _compiles_done()
+    spans = _compile_spans(first)
+    assert {s["name"] for s in spans} == set(profile.COMPILE_SPANS)
+    now = profile.COMPILES
+    assert now["loaded"] == was["loaded"] and now["lost"] == was["lost"]
+    assert sum(s.get("programs", 1) for s in spans) \
+        == now["built"] + now["small"] - was["built"] - was["small"]
+    assert sum(s["backend_compile_s"] for s in spans) == pytest.approx(
+        now["built_s"] + now["small_s"] - was["built_s"] - was["small_s"])
+
+
+def test_an_inline_step_compile_is_the_jobs_record_under_its_step():
+    """Service off: what jax compiled inside a node's `rw:step` is a
+    `rw:compile.inline` span under it, and that span's close the job's
+    record — labeled from the step span, `compile` for the node's first,
+    no `aot`; a step that compiled nothing leaves no record however long
+    it took."""
+    first = len(profile.SPANS)
+    db, job = fused_db(n=N - 384, aot_compile=False)
+    spans = list(profile.SPANS)[first:]
+    by_id = {s["id"]: s for s in spans}
+    inline = [s for s in spans if s["name"] == "rw:compile.inline"
+              and by_id.get(s["parent"], {}).get("name") == "rw:step"]
+    assert inline and all(s["inst"] == job.profiler.instance
+                          for s in inline)
+    recs = list(job.profiler.compile_info)
+    assert [r["label"] for r in recs] == [by_id[s["parent"]]["label"]
+                                          for s in inline]
+    for r, s in zip(recs, inline):
+        step = by_id[s["parent"]]
+        assert s["node"] == step["node"]
+        assert step["label"] == job.program._node_label(step["i"])
+        assert (r["kind"], r["persistent"]) == ("compile", "off")
+        assert "aot" not in r and r["s"] == (s["t1"] - s["t0"]) / 1e9
+    # one record a node that compiled, none for the steps after
+    assert len({r["label"] for r in recs}) == len(recs)
+    steps = [s for s in spans if s["name"] == "rw:step"]
+    assert len(steps) > len(recs)
+
+
+def test_the_drain_and_the_boot_are_spans(monkeypatch):
+    from risingwave_tpu.device.compile_service import get_service
+    first = len(profile.SPANS)
+    assert get_service().wait_idle(60)
+    assert [s["name"] for s in list(profile.SPANS)[first:]] \
+        == ["rw:compile_drain"]
+    # the import of risingwave_tpu.device recorded the boot (this
+    # process's ring may have turned over since: record it again)
+    import time
+
+    import risingwave_tpu
+    assert profile._BOOTED and profile._LISTENING
+    monkeypatch.setattr(profile, "_BOOTED", False)
+    first, now = len(profile.SPANS), time.perf_counter_ns()
+    profile.boot_done(risingwave_tpu._T_IMPORT)
+    profile.boot_done(risingwave_tpu._T_IMPORT)          # once a process
+    boot, start, imp = list(profile.SPANS)[first:]
+    assert [s["name"] for s in (boot, start, imp)] \
+        == ["rw:boot", "rw:boot.start", "rw:boot.import"]
+    assert start["parent"] == imp["parent"] == boot["id"]
+    assert boot["t0"] == start["t0"] < start["t1"] == imp["t0"] \
+        == risingwave_tpu._T_IMPORT < imp["t1"] == boot["t1"]
+    assert now <= boot["t1"]
+    # the OS's word for the start of this process: before the package's
+    # first line, and not by much more than the interpreter's own start
+    # plus whatever the test runner imported first
+    assert 0 < (start["t1"] - start["t0"]) / 1e9 < 600
+    monkeypatch.setattr(profile, "_process_start_ns", lambda: None)
+    monkeypatch.setattr(profile, "_BOOTED", False)
+    first = len(profile.SPANS)
+    profile.boot_done(risingwave_tpu._T_IMPORT)
+    assert [s["name"] for s in list(profile.SPANS)[first:]] \
+        == ["rw:boot", "rw:boot.import"]                 # failing that
+
+
+def test_the_first_device_database_touches_the_backend(monkeypatch):
+    monkeypatch.setattr(profile, "_BACKEND_TOUCHED", False)
+    first = len(profile.SPANS)
+    Database(device=DeviceConfig(capacity=512, profile=False))
+    assert len(profile.SPANS) == first and profile._BACKEND_TOUCHED
+    monkeypatch.setattr(profile, "_BACKEND_TOUCHED", False)
+    Database(device="off")                          # no device: no touch
+    assert not profile._BACKEND_TOUCHED
+    Database(device=DeviceConfig(capacity=512))
+    Database(device=DeviceConfig(capacity=512))     # once a process
+    (span,) = list(profile.SPANS)[first:]
+    assert span["name"] == "rw:boot.backend" and span["parent"] is None
+    assert (span["platform"], span["devices"]) == ("cpu", 8)
 
 
 def _lower_step(job, i):
@@ -385,9 +705,22 @@ def _ring():
                     "tname": f"t{thread}", **kw})
         return out[-1]["id"]
 
-    add("rw:sql", 0, 30, kind="create_source")
-    add("rw:sql", 30, 100, kind="create_mv")
-    add("rw:compile", 35, 300, thread=2, job="mv", inst=1, node="agg")
+    boot = add("rw:boot", -600, -100)          # the process began at -600
+    add("rw:boot.start", -600, -550, boot)                  # leaf: 50
+    add("rw:boot.import", -550, -100, boot)                 # leaf: 450
+    add("rw:boot.backend", -80, -60, platform="cpu")        # leaf: 20
+    add("rw:sql", 0, 30, kind="create_source")              # leaf: 30
+    q = add("rw:sql", 30, 100, kind="create_mv")
+    # what jax did (seconds, whatever the ring's scale): an eager
+    # primitive under the CREATE, a program built though the manifest
+    # knew it, one read from the persistent cache
+    add("rw:compile.inline", 40, 60, q, fun_name="jit(iota)",   # leaf: 20
+        persistent="miss", backend_compile_s=0.01)
+    add("rw:compile", 35, 300, thread=2, job="mv", inst=1, node="agg",
+        cache_hit=True, persistent="miss", backend_compile_s=1.5, lost=True)
+    add("rw:compile", 300, 330, thread=2, job="mv", inst=1, node="mv",
+        cache_hit=True, persistent="hit", backend_compile_s=0.3,
+        retrieval_s=0.25)
     for t0, t1, wait in ((100, 400, (120, 320)), (400, 900, (450, 500))):
         b = add("rw:barrier", t0, t1, epoch=t0)
         e = add("rw:epoch", t0 + 5, t1 - 5, b, job="mv", inst=1, epoch=t0)
@@ -419,8 +752,15 @@ def _ring():
     return out
 
 
-# leaves on thread 1 inside [2000, 4100): 90+800+100+380+100+200+100+100
+# leaves on thread 1 inside [2000, 4100): 90+800+100+380+100+200+100+100;
+# inside [-600, 2000): 50+450+20+30+20, the waits' 250, the later SQL's 150
 READINGS = {
+    "setup_boot_s": (0.6, None),
+    "setup_compiles": (1, None),
+    "setup_compile_s": (1.5, None),
+    "setup_cache_load_s": (0.25, None),
+    "setup_cache_lost": (1, None),
+    "setup_span_coverage_pct": (100.0 * 970 / 2600, None),
     "commit_mirror_ms_per_ckpt": (150.0, 0.0),   # 300 ms over 2 checkpoints
     "growth_replay_ms": (400.0, 0.0),
     "host_span_coverage_pct": (100.0 * 1870 / 2100, None),
@@ -430,6 +770,12 @@ READINGS = {
 }
 # what each reading is of: with those spans gone, the second value
 GONE = {
+    "setup_boot_s": lambda s: s["name"] == "rw:boot",
+    "setup_compiles": lambda s: "persistent" in s,     # a program that
+    "setup_compile_s": lambda s: "persistent" in s,    # does not say
+    "setup_cache_load_s": lambda s: "persistent" in s,
+    "setup_cache_lost": lambda s: "persistent" in s,
+    "setup_span_coverage_pct": lambda s: s["name"] == "rw:boot",
     "commit_mirror_ms_per_ckpt": lambda s: s["name"] == "rw:commit.mirror",
     "growth_replay_ms": lambda s: s["name"] == "rw:growth",
     "host_span_coverage_pct": lambda s: s["name"] == "rw:barrier",
