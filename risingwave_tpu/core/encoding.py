@@ -175,6 +175,20 @@ def encode_key_matrix(cols: Sequence, dtypes: Sequence[DataType],
     return mat
 
 
+def key_is_fixed_width(dtypes: Sequence[DataType]) -> bool:
+    """Whether `encode_key_matrix` can encode these key columns at all
+    (a NULL among the values still sends a batch down the per-row path)."""
+    return bool(dtypes) and all(dt.kind in _FIXED_KEY_WIDTH for dt in dtypes)
+
+
+def key_bytes_list(mat) -> List[bytes]:
+    """The rows of an (n, W) uint8 key matrix as `bytes`, each exactly W
+    long (a void view: numpy's `S` strips trailing NULs on the way out)."""
+    import numpy as np
+    mat = np.ascontiguousarray(mat)
+    return mat.view(f"V{mat.shape[1]}").ravel().tolist() if len(mat) else []
+
+
 # ---------------------------------------------------------------------------
 # Value encoding (compact, non-ordered) — checkpoint row payloads
 # ---------------------------------------------------------------------------

@@ -30,7 +30,6 @@ non-device readers (system catalogs, risectl) see committed data.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from decimal import Decimal
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -39,6 +38,7 @@ from ..core import dtypes as T
 from ..core.dtypes import DataType, TypeKind
 from ..utils.failpoint import FailpointError, declare, failpoint
 from ..utils.profile import NULL_PROFILER
+from .mv_mirror import MirrorImage, MVColumns, mirror_batch
 
 # Fused device-path failure seams (fault-tolerance v3): each hook sits at
 # the point where a real device fault would surface — the async epoch
@@ -2627,7 +2627,8 @@ class FusedJob:
                 self._zero_stats, NamedSharding(program.mesh, P()))
         self.stats_acc = self._zero_stats
         self._step = program.step_fn()
-        self._persisted: Dict[Tuple, Tuple] = {}
+        # the MV as the state table last took it (mv_mirror.MirrorImage)
+        self._persisted = MirrorImage()
         # last device-pulled stats vector (sync) + job-lifetime totals
         # (sum slots accumulate, max slots high-water — _accum_totals):
         # the rw_fused_node_stats / node_report substrate
@@ -3600,6 +3601,18 @@ class FusedJob:
             self.pull.node_idx, vec).get("needed", 0)
 
     def _pull_rows(self) -> List[Tuple]:
+        """The MV's rows as tuples: the columnar pull, each column
+        formatted whole, rows from `zip`."""
+        return self._pull_cols().rows()
+
+    def _pull_cols(self) -> MVColumns:
+        """The MV as host columns (per MV column the values in the
+        device's own domain and the null mask). The columns that are not
+        numbers on the host (VARCHAR: a surrogate the generator's pools
+        turn back into the string) are decoded here, inside
+        `rw:commit.mirror.decode` (`rows`, `string_cols`): under the
+        mirror's pull and under a SELECT's, what a string column costs is
+        a span of its own. An all-number MV leaves none."""
         import jax
         mesh = self.program.mesh
         if mesh is None:
@@ -3651,34 +3664,12 @@ class FusedJob:
                                        for v in side.vals])
             pulled = [(vals[i], None)
                       for i in range(len(self.pull.dtypes))]
-        out_cols = self._format_cols(pulled, n)
-        return [tuple(c[i] for c in out_cols) for i in range(n)]
-
-    def _format_cols(self, pulled, n: int) -> List[List[Any]]:
-        """Pulled (values, nulls) per MV column -> host Python values.
-        The columns that are not numbers on the host (VARCHAR: a
-        surrogate the generator's pools turn back into the string) go
-        first, inside `rw:commit.mirror.decode` (`rows`, `string_cols`):
-        under the mirror's pull and under a SELECT's, what a string
-        column costs is a span of its own. An all-number MV leaves
-        none."""
-        pull = self.pull
-        strings = [k for k, d in enumerate(pull.decoders)
-                   if d not in (NUM, ("ts",))]
-        out: List[Any] = [None] * len(pulled)
-
-        def fmt(k):
-            out[k] = _format_col(pull.dtypes[k], pull.decoders[k],
-                                 np.asarray(pulled[k][0]), pulled[k][1])
-        if strings:
+        cols = MVColumns(self.pull.dtypes, self.pull.decoders, pulled, n)
+        if cols.strings:
             with self.profiler.span("rw:commit.mirror.decode", rows=n,
-                                    string_cols=len(strings)):
-                for k in strings:
-                    fmt(k)
-        for k in range(len(pulled)):
-            if k not in strings:
-                fmt(k)
-        return out
+                                    string_cols=len(cols.strings)):
+                cols.decode()
+        return cols
 
     def mv_rows_now(self) -> List[Tuple]:
         """Query serving: sync and pull the CURRENT MV rows (full schema,
@@ -3722,20 +3713,20 @@ class FusedJob:
         if self.mv_state_table is None:
             return
         span = self.profiler.span
+        table = self.mv_state_table
         with span("rw:commit.mirror") as mirror:
             with span("rw:commit.mirror.pull"):
-                rows = {r: None for r in self._pull_rows()}
-            mirror.set(rows=len(rows))
+                cols = self._pull_cols()
             with span("rw:commit.mirror.diff"):
-                for r in self._persisted:
-                    if r not in rows:
-                        self.mv_state_table.delete(r)
-                for r in rows:
-                    if r not in self._persisted:
-                        self.mv_state_table.insert(r)
-                self._persisted = rows
+                # a row that changed is put under the key it had: what
+                # delete(old) then insert(new) left
+                self._persisted, keys, rows, counts = mirror_batch(
+                    self._persisted, cols, table)
+                table.write_batch(keys, rows,
+                                  ascending=not counts["deleted"])
+            mirror.set(**counts)
             with span("rw:commit.mirror.table_commit"):
-                self.mv_state_table.commit(epoch)
+                table.commit(epoch)
 
     # ---- recovery -------------------------------------------------------
     def recover(self) -> None:
@@ -3809,8 +3800,12 @@ class FusedJob:
         self._promo_need = {}
         self.committed = target
         if self.mv_state_table is not None:
-            self._persisted = {tuple(r): None
-                               for r in self.mv_state_table.iter_all()}
+            # the table's keys stand, its values are not read back: the
+            # next mirror puts every row again and tombstones every key
+            # the pull lacks, through the same diff
+            table = self.mv_state_table
+            self._persisted = MirrorImage.of_keys(
+                [table.key_of(r) for r in table.iter_all()])
         self._last_persist = -1     # mirror may be stale: refresh next ckpt
 
     # ---- skew-routing policy (vnode rebalance + hot-key replication) ----
@@ -4476,25 +4471,4 @@ def _np_unpack(pack: PackPlan, keys: np.ndarray) -> List[np.ndarray]:
         v = (keys >> shift) & ((1 << f.bits) - 1)
         out.append(v * f.stride + f.offset)
         shift += f.bits
-    return out
-
-
-def _format_col(dtype: DataType, decoder: Tuple, vals: np.ndarray,
-                nulls: Optional[np.ndarray]) -> List[Any]:
-    """Device int64/f64 column -> host Python values matching the host
-    executors' state-table representation exactly."""
-    from .nexmark_gen import decode_column
-    if decoder not in (("num",), ("ts",)):
-        dec = decode_column(decoder, vals.astype(np.int64))
-        out = list(dec)
-    elif dtype.kind == TypeKind.DECIMAL:
-        out = [Decimal(int(v)) for v in vals]
-    elif dtype.kind in (TypeKind.FLOAT32, TypeKind.FLOAT64):
-        out = [float(v) for v in vals]
-    elif dtype.kind == TypeKind.BOOLEAN:
-        out = [bool(v) for v in vals]
-    else:
-        out = [int(v) for v in vals]
-    if nulls is not None:
-        out = [None if nulls[i] else out[i] for i in range(len(out))]
     return out

@@ -13,7 +13,7 @@ import struct
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 from ..core.dtypes import DataType
-from ..core.encoding import encode_key
+from ..core.encoding import encode_key, key_bytes_list
 from ..core.vnode import VNODE_COUNT, vnode_of_row
 from .store import StateStore
 
@@ -52,6 +52,10 @@ class StateTable:
         self.vnodes = set(vnodes) if vnodes is not None else None
         # mem-table: key -> (row|None). None = delete tombstone.
         self.mem: Dict[bytes, Optional[Tuple]] = {}
+        # the mem-table's insertion order is ascending key order (one
+        # ascending `write_batch` into an empty mem-table): `commit` then
+        # hands it on as it is
+        self._mem_ascending = False
         self._pending_batch: List[Tuple[bytes, Optional[Tuple]]] = []
 
     # ---- key construction ----
@@ -72,53 +76,74 @@ class StateTable:
             vnode = vnode_of_row([pk[j] for j in dist_in_pk], self.vnode_count)
         return struct.pack(">H", vnode) + encode_key(pk, self.pk_dtypes, self.order_desc)
 
-    # ---- writes (buffered) ----
-    def insert(self, row: Sequence[Any]) -> None:
-        self.mem[self.key_of(row)] = tuple(row)
-
-    def delete(self, row: Sequence[Any]) -> None:
-        self.mem[self.key_of(row)] = None
-
-    def write_chunk(self, chunk) -> None:
-        """Bulk mem-table apply of a StreamChunk (insert-like ops upsert,
-        delete-like ops tombstone), in chunk order. Key encoding is
-        vectorized when the pk columns are fixed-width and null-free
-        (`encode_key_matrix`); otherwise falls back to the per-row path.
-        The Materialize hot path at scale — per-row `key_of` would dominate
-        an epoch with 10^5 changed rows."""
+    def key_matrix(self, cols) -> Optional[np.ndarray]:
+        """Keys of whole columns: an (n, 2 + W) uint8 matrix whose rows are
+        byte-for-byte `key_of` of the rows — the vnode prefix
+        (`compute_vnodes`) before the memcomparable pk
+        (`encode_key_matrix`). `cols[i]` is the table's column `i` as a
+        `Column`; only the pk and distribution-key columns are read. None
+        when a pk column is not fixed-width or holds a NULL: those rows
+        take the per-row `key_of`."""
         import numpy as np
-        from ..core.chunk import _sign_of_ops
         from ..core.encoding import encode_key_matrix
         from ..core.vnode import compute_vnodes
-        chunk = chunk.compact()
-        n = chunk.capacity
-        if n == 0:
-            return
-        cols = chunk.columns
-        rows = chunk.data_chunk().rows()
-        ins = (_sign_of_ops(chunk.ops) > 0).tolist()
         mat = encode_key_matrix([cols[i] for i in self.pk_indices],
                                 self.pk_dtypes, self.order_desc)
         if mat is None:
-            for row, i in zip(rows, range(n)):
-                self.mem[self.key_of(row)] = row if ins[i] else None
-            return
+            return None
+        n = len(mat)
         vn = compute_vnodes([cols[i] for i in self.dist_key_indices], n,
                             self.vnode_count)
         full = np.empty((n, 2 + mat.shape[1]), np.uint8)
         full[:, :2] = vn.astype(">u2").view(np.uint8).reshape(n, 2)
         full[:, 2:] = mat
-        buf = full.tobytes()
-        w = full.shape[1]
-        mem = self.mem
-        for i, row in enumerate(rows):
-            mem[buf[i * w:(i + 1) * w]] = row if ins[i] else None
+        return full
+
+    # ---- writes (buffered) ----
+    def insert(self, row: Sequence[Any]) -> None:
+        self.mem[self.key_of(row)] = tuple(row)
+        self._mem_ascending = False
+
+    def delete(self, row: Sequence[Any]) -> None:
+        self.mem[self.key_of(row)] = None
+        self._mem_ascending = False
+
+    def write_batch(self, keys: Sequence[bytes],
+                    rows: Sequence[Optional[Tuple]],
+                    ascending: bool = False) -> None:
+        """Bulk mem-table apply of encoded keys beside their rows (None =
+        delete tombstone), in order: what `insert` / `delete` of each row
+        leave, in one `dict.update`. `ascending`: the caller's word that
+        the keys are strictly ascending — into an empty mem-table that
+        makes `commit`'s batch sorted as it stands."""
+        self._mem_ascending = ascending and not self.mem
+        self.mem.update(zip(keys, rows))
+
+    def write_chunk(self, chunk) -> None:
+        """Bulk mem-table apply of a StreamChunk (insert-like ops upsert,
+        delete-like ops tombstone), in chunk order. Key encoding is
+        vectorized when the pk columns are fixed-width and null-free
+        (`key_matrix`); otherwise falls back to the per-row path.
+        The Materialize hot path at scale — per-row `key_of` would dominate
+        an epoch with 10^5 changed rows."""
+        from ..core.chunk import _sign_of_ops
+        chunk = chunk.compact()
+        if chunk.capacity == 0:
+            return
+        rows = chunk.data_chunk().rows()
+        ins = (_sign_of_ops(chunk.ops) > 0).tolist()
+        mat = self.key_matrix(chunk.columns)
+        keys = (key_bytes_list(mat) if mat is not None
+                else [self.key_of(row) for row in rows])
+        self.write_batch(keys, [row if i else None
+                                for row, i in zip(rows, ins)])
 
     def update(self, old_row: Sequence[Any], new_row: Sequence[Any]) -> None:
         ko, kn = self.key_of(old_row), self.key_of(new_row)
         if ko != kn:
             self.mem[ko] = None
         self.mem[kn] = tuple(new_row)
+        self._mem_ascending = False
 
     # ---- reads (read-your-writes through the mem-table) ----
     def get_by_pk(self, pk: Sequence[Any]) -> Optional[Tuple]:
@@ -173,9 +198,11 @@ class StateTable:
     def commit(self, epoch: int) -> None:
         """Flush the mem-table at a barrier (`state_table.rs:1013`)."""
         if self.mem:
-            batch = sorted(self.mem.items())
+            batch = (list(self.mem.items()) if self._mem_ascending
+                     else sorted(self.mem.items()))
             self.store.ingest_batch(self.table_id, batch, epoch)
             self.mem.clear()
+            self._mem_ascending = False
 
     def update_vnodes(self, vnodes: Optional[Sequence[int]]) -> None:
         """Rescale: adopt a new vnode ownership bitmap

@@ -24,9 +24,10 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 class KeyedTable:
     """One table: dict + lazily-rebuilt sorted key view.
 
-    Writes are O(1) dict ops; the sorted view rebuilds once per scan after
-    changes (ordered scans are rare next to writes — an epoch can upsert
-    10^5 keys, and per-write `insort` would make the batch quadratic)."""
+    Writes are dict ops, a batch at a time; the sorted view rebuilds once
+    per scan after changes (ordered scans are rare next to writes — an
+    epoch can upsert 10^5 keys, and per-write `insort` would make the
+    batch quadratic)."""
 
     __slots__ = ("data", "_sorted", "_dirty")
 
@@ -35,13 +36,18 @@ class KeyedTable:
         self._sorted: List[bytes] = []
         self._dirty = False
 
-    def put(self, key: bytes, value: Tuple) -> None:
-        if key not in self.data:
-            self._dirty = True
-        self.data[key] = value
-
-    def delete(self, key: bytes) -> None:
-        if self.data.pop(key, None) is not None:
+    def apply(self, batch: Sequence[Tuple[bytes, Optional[Tuple]]]) -> None:
+        """A whole batch of (key, row | None = delete), in order — the
+        last pair of a key stands —, with one `dict.update` for the puts,
+        one `pop` a tombstone and one dirty mark."""
+        data = self.data
+        before = len(data)
+        dels = [k for k, row in batch if row is None]
+        data.update(batch)
+        for k in dels:
+            if data.get(k) is None:
+                data.pop(k, None)
+        if dels or len(data) != before:
             self._dirty = True
 
     def get(self, key: bytes) -> Optional[Tuple]:
@@ -113,12 +119,7 @@ class MemoryStateStore(StateStore):
         return self._table(table_id).iter_range(start, end)
 
     def ingest_batch(self, table_id, batch, epoch):
-        t = self._table(table_id)
-        for key, row in batch:
-            if row is None:
-                t.delete(key)
-            else:
-                t.put(key, row)
+        self._table(table_id).apply(batch)
 
     def commit_epoch(self, epoch):
         self.committed_epoch = max(self.committed_epoch, epoch)

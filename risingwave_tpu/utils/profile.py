@@ -91,6 +91,10 @@ what is finer sits inside the phases as child spans:
   rw:pack > rw:event_lo            rw:dispatch > rw:step > rw:compile_wait
   rw:dispatch > rw:stats_fold      rw:device_sync > rw:stats_pull | rw:growth
   rw:commit > rw:commit.mirror > .pull | .diff | .table_commit
+    (`rows` the MV holds; `inserted`, `updated`, `deleted`: the keys only
+    the new image has, the keys of both whose row changed, the keys only
+    the last image had — what the state table was handed;
+    `keys_vectorised`: the keys came from one matrix, not `key_of` a row)
   rw:commit.mirror.pull > rw:commit.mirror.decode (`rows`,
     `string_cols`: the MV's VARCHAR columns turned from surrogates into
     strings; only an MV that has one; the same span under a SELECT's pull)

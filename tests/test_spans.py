@@ -109,7 +109,14 @@ def test_every_barrier_yields_the_span_tree(run):
                and by_id[s["parent"]]["name"] == "rw:device_sync"
                for s in spans)
     mirror = [s for s in spans if s["name"] == "rw:commit.mirror"]
+    # the image is the MV as columns; a mirror says what it wrote
     assert mirror and mirror[-1]["rows"] == len(job._persisted)
+    for m in mirror:
+        assert {"rows", "inserted", "updated", "deleted",
+                "keys_vectorised"} <= set(m)
+        assert m["inserted"] + m["updated"] <= m["rows"]
+        assert m["keys_vectorised"] is True and m["deleted"] == 0
+    assert sum(m["inserted"] for m in mirror) == len(job._persisted)
     assert by_id[mirror[-1]["parent"]]["name"] == "rw:commit"
     sql = [s for s in spans if s["name"] == "rw:sql"]
     assert [s["kind"] for s in sql] == ["create_source", "create_mv",
