@@ -1358,16 +1358,16 @@ class AggNode(Node):
         from, so a surviving group that the epoch's delta names — also
         one whose delta nets to nothing — reads this tick, an untouched
         one its old stamp by position, a dead or empty slot 0; then
-        (tres, tcold). Two gathers over the capacity, no search by key,
-        no extra program, no sync."""
+        (tres, tcold). One gather over the capacity (the stamp at
+        `trail.last`), no search by key, no extra program, no sync."""
         import jax
         import jax.numpy as jnp
-        from .sorted_state import EMPTY_KEY, merged_src
+        from .sorted_state import EMPTY_KEY
         from .tiering import TIER_TTL, TieredState
         touch, tick = tstate.touch, tstate.tick
         with jax.named_scope("tier.touch"):
             c = touch.shape[0]
-            src = merged_src(trail, last=True)
+            src = trail.last
             live = new_state.main.keys != EMPTY_KEY
             ntouch = jnp.where(
                 live, jnp.where(src >= c, tick,
@@ -1755,18 +1755,18 @@ class JoinNode(Node):
         # stamp — demotion/promotion move whole jk groups so probe
         # results never see a partial build side). An arriving delta on
         # EITHER input touches the jk on BOTH sides. The stamps ride each
-        # side's merge by position (its `MergeTrail`); the epoch's
+        # side's merge by position (its `SideTrail`); the epoch's
         # touched keys are searched INTO the side — one query per delta
         # row, none per slot — and mark their runs.
         import jax
         from .join_step import mark_key_runs
-        from .sorted_state import EMPTY_KEY, merged_src
+        from .sorted_state import EMPTY_KEY
         from .tiering import TIER_TTL, TieredState
         tick = tstate.tick
 
         def side_touch(old_touch, new_side, trail, tkeys):
             c = old_touch.shape[0]
-            src = merged_src(trail, last=False)
+            src = trail.sort_perm[trail.compact_perm]
             carried = jnp.where(src < c,
                                 old_touch[jnp.minimum(src, c - 1)], 0)
             hit = mark_key_runs(new_side.jk, tkeys)

@@ -84,6 +84,17 @@ def batch_reduce_rows(jk, pk, signs, mask, vals):
     return ujk, upk, usign, uvals
 
 
+class SideTrail(NamedTuple):
+    """How `merge_side` moved a side's rows: its two permutations, kept
+    apart (`sorted_state.MergeTrail` is the agg's and the MV's, composed).
+    `sort_perm[compact_perm]` is the input row — into concat(side rows,
+    delta rows) — of the first row of each output slot's run: where the
+    tier's stamps come from (`fused.JoinNode`); an empty slot reads
+    garbage."""
+    sort_perm: jax.Array        # int32 (n,) sorted position -> input row
+    compact_perm: jax.Array     # int32 (C,) output slot -> sorted position
+
+
 def merge_side(side: JoinSide, djk, dpk, dsign, dvals,
                return_trail: bool = False) -> Tuple:
     """Apply unique (jk,pk) deltas: +1 insert/upsert, -1 delete, 0 no-op.
@@ -95,9 +106,8 @@ def merge_side(side: JoinSide, djk, dpk, dsign, dvals,
     compact away alone (pres_m == 0).
 
     Returns (new_side, needed); with `return_trail` also the merge's
-    `MergeTrail` (sorted_state.merge has the contract), without it the
-    traced program is the one it always was."""
-    from .sorted_state import MergeTrail, compact_rows, sort_cols
+    `SideTrail`, without it the traced program is the one it always was."""
+    from .sorted_state import compact_rows, sort_cols
     c = side.jk.shape[0]
     jk = jnp.concatenate([side.jk, djk])
     pk = jnp.concatenate([side.pk, dpk])
@@ -124,7 +134,7 @@ def merge_side(side: JoinSide, djk, dpk, dsign, dvals,
     new = JoinSide(out[0], out[1], jnp.minimum(needed, c),
                    tuple(out[2:2 + len(vals_m)]))
     if return_trail:
-        return new, needed, MergeTrail(sperm[0], same_next, out[-1])
+        return new, needed, SideTrail(sperm[0], out[-1])
     return new, needed
 
 
@@ -193,7 +203,7 @@ def join_core(a: JoinSide, b: JoinSide,
     """One epoch of both sides' rows -> (new states, pair change set).
     Unjitted core, shared by the per-operator engine's step below and the
     fused `JoinNode` (device/fused.py). With `trail` a sixth value holds the
-    two sides' `MergeTrail`s (merge_side).
+    two sides' `SideTrail`s (merge_side).
 
     Pair change set: for each emitted pair, sign = producing delta's sign
     (+1 insert pair, -1 retract pair); payloads gathered from both sides,

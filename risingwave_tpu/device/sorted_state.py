@@ -13,10 +13,11 @@ jax arrays with static shapes, so an epoch apply is one jitted XLA program:
 
 Whatever has to follow rows through a merge does so by position, never by
 searching for the keys again: `merge(..., return_trail=True)` also says
-how the rows moved (`MergeTrail`). `merged_src` of it names the input row
-each output slot came from, so a column that lives BESIDE the state (the
-state tier's touch stamps, `device/tiering.py`) is re-aligned with two
-gathers; `merge_changes` reads it from the delta's side — which state row
+how the rows moved (`MergeTrail`). Its `first` / `last` name the input rows
+each output slot's run came from — the merge itself moves every payload
+column through them, once — so a column that lives BESIDE the state (the
+state tier's touch stamps, `device/tiering.py`) is re-aligned with one
+gather; `merge_changes` reads it from the delta's side — which state row
 each delta key met and what the pair combined to — which is the agg
 step's whole change set.
 
@@ -240,28 +241,80 @@ def compact_rows(alive: jax.Array, keys: Sequence[jax.Array],
     return out + (idx,) if return_perm else out
 
 
+def _words(cols: Sequence[jax.Array]) -> jax.Array:
+    """Columns as the rows of one int32 matrix (words, n) — a 64-bit
+    column is two words (low, high), a narrower one one word — so that one
+    gather along its second axis moves every column at once: on the chip a
+    gather costs by the index, hardly by the words (PR 38: 2^20 indices
+    into 2^21 rows take 17.4 ms for one int64 column, 25.6 ms for twelve
+    words). `_unwords` gives the columns back bit for bit."""
+    out = []
+    for c in cols:
+        dt = jnp.dtype(c.dtype)
+        if dt == jnp.bool_:
+            out.append(c.astype(jnp.int32))
+        elif dt.itemsize == 8:
+            x = c if dt == jnp.int64 else \
+                jax.lax.bitcast_convert_type(c, jnp.int64)
+            lo = (x & 0xFFFFFFFF).astype(jnp.uint32)
+            out += [jax.lax.bitcast_convert_type(lo, jnp.int32),
+                    (x >> 32).astype(jnp.int32)]
+        elif dt.itemsize == 4:
+            out.append(c if dt == jnp.int32 else
+                       jax.lax.bitcast_convert_type(c, jnp.int32))
+        else:
+            narrow = jnp.int8 if dt.itemsize == 1 else jnp.int16
+            out.append(jax.lax.bitcast_convert_type(c, narrow)
+                       .astype(jnp.int32))
+    return jnp.stack(out)
+
+
+def _unwords(w: jax.Array, dtypes: Sequence) -> Tuple[jax.Array, ...]:
+    """The columns `_words` packed, with these dtypes, from its rows."""
+    cols, i = [], 0
+    for dt in map(jnp.dtype, dtypes):
+        if dt == jnp.bool_:
+            cols.append(w[i] != 0)
+        elif dt.itemsize == 8:
+            lo = jax.lax.bitcast_convert_type(w[i], jnp.uint32)
+            x = (w[i + 1].astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+            cols.append(x if dt == jnp.int64 else
+                        jax.lax.bitcast_convert_type(x, dt))
+            i += 1
+        elif dt.itemsize == 4:
+            cols.append(w[i] if dt == jnp.int32 else
+                        jax.lax.bitcast_convert_type(w[i], dt))
+        else:
+            narrow = jnp.int8 if dt.itemsize == 1 else jnp.int16
+            cols.append(jax.lax.bitcast_convert_type(w[i].astype(narrow),
+                                                     dt))
+        i += 1
+    return tuple(cols)
+
+
+def _take(cols: Sequence[jax.Array], idx: jax.Array) -> Tuple[jax.Array, ...]:
+    """`tuple(c[idx] for c in cols)` as one gather of their words."""
+    return _unwords(_words(cols)[:, idx], [c.dtype for c in cols])
+
+
 class MergeTrail(NamedTuple):
-    """How a sort-combine-compact merge moved its rows (`merge`,
-    `join_step.merge_side` with `return_trail`): what `merged_src` turns
-    into one source index per output slot, and `merge_changes` into the
-    state row each delta key met."""
+    """How `merge(..., return_trail=True)` moved its rows. Indices are
+    input rows: into concat(state rows, delta rows), so one < capacity is
+    that state row and one >= capacity the delta row `index - capacity`.
+
+    `first` / `last` are the two permutations already composed: for every
+    output slot, the input row of the first and of the last row of the
+    key's run — the state row where the key had one, the delta row where
+    the delta has one; equal for a run of one. A column kept beside the
+    state (the tier's touch stamps) follows its rows with one gather
+    through either. They are defined in LIVE slots only (an empty slot
+    reads garbage: gate on the new state's key). `sort_perm` and
+    `same_next` are the sort's own, n = capacity + delta rows wide: what
+    `merge_changes` reads each delta key's partner off."""
+    first: jax.Array            # int32 (C,) output slot -> its run's first row
+    last: jax.Array             # int32 (C,) output slot -> its run's last row
     sort_perm: jax.Array        # int32 (n,) sorted position -> input row
     same_next: jax.Array        # bool (n,) the next sorted row has this key
-    compact_perm: jax.Array     # int32 (C,) output slot -> sorted position
-
-
-def merged_src(trail: MergeTrail, last: bool) -> jax.Array:
-    """Which input row a LIVE output slot of a merge came from: int32
-    index into concat(state rows, delta rows) of one row of the slot's
-    run — the `last` (the delta row where the key has one) or the first
-    (the state row where the key had one). One int32 gather through the
-    compaction's permutation; an empty slot reads garbage (gate on the
-    new state's key)."""
-    sp = trail.sort_perm
-    if last:
-        sp = jnp.where(trail.same_next,
-                       jnp.concatenate([sp[1:], sp[-1:]]), sp)
-    return sp[trail.compact_perm]
 
 
 def merge_changes(state: SortedState, new_state: SortedState,
@@ -277,13 +330,17 @@ def merge_changes(state: SortedState, new_state: SortedState,
 
     The old side: the stable sort put a key's state row directly before
     its delta row, so in sorted space a delta row's partner is the input
-    row of the position before it (`met`, -1 where the state had none).
-    What brings that back to delta order is one two-operand sort over "is
-    a delta row" (`compact_rows` with the answer as its one column — NOT
-    a scatter): the delta is key-sorted with its pads last (what
-    `batch_reduce` leaves: `agg_step.precombine_core`'s output contract)
-    and the merge's sort is stable, so the j-th delta row in sorted order
-    IS delta row j. Then one gather a payload column.
+    row of the position before it (`met`, -1 where the state had none;
+    read off the trail's `sort_perm` and `same_next`). The trail's
+    `first` / `last` cannot say it: they name the output slots, and a
+    group that died or a truncated merge's tail has none. What brings
+    `met` back to delta order is one two-operand sort over "is a delta
+    row" (`compact_rows` with the answer as its one column — NOT a
+    scatter, and nothing gathered): the delta is key-sorted with its pads
+    last (what `batch_reduce` leaves: `agg_step.precombine_core`'s output
+    contract) and the merge's sort is stable, so the j-th delta row in
+    sorted order IS delta row j. Then one gather of the state's words
+    (`_take`): every payload column at once.
     The new side needs no gather at all: a key's run is its state row and
     its delta row, so the merged payload is their `_combine` (the delta's
     own value where the state had none), the group is alive by the merge's
@@ -299,8 +356,7 @@ def merge_changes(state: SortedState, new_state: SortedState,
         (met,) = compact_rows(sp >= c, [met], [], b, [-1])
         real = dkeys != EMPTY_KEY       # a pad's neighbour is another pad
         old_found = (met >= 0) & real
-        row = jnp.clip(met, 0, c - 1)
-        old_vals = tuple(v[row] for v in state.vals)
+        old_vals = _take(state.vals, jnp.clip(met, 0, c - 1))
         dvals = [dv.astype(ov.dtype) for dv, ov in zip(dvals, old_vals)]
         new_vals = tuple(jnp.where(old_found, _combine(k, ov, dv), dv)
                          for k, ov, dv in zip(kinds, old_vals, dvals))
@@ -323,25 +379,90 @@ def merge(state: SortedState, dkeys: jax.Array,
     (row_count) hits 0 are compacted away — group death (`hash_agg.rs`
     emits DELETE and drops state when count reaches 0).
 
+    Each payload column moves once, from its input row straight into its
+    output slot: one stable sort of the keys (carrying `dead_col`, the one
+    column whose merged value decides which runs live, and the row ranks)
+    gives the sort's permutation; one sort of the alive runs to the front
+    carries the input rows of each run's first and last row (the two
+    permutations composed, `MergeTrail.first` / `.last`); then the key
+    and every column are read at `last` — a REPLACE column's value —
+    and, where any column combines, at `first`, each a single gather of
+    all their words (`_words`). Nothing is gathered into sorted order.
+    Under `cheap_compile()` off and with no trail the one variadic sort of
+    every column (`_merge_variadic`) is taken instead.
+
     Returns (new_state, needed) — `needed` > capacity means the merge was
     truncated and must be retried on a grown state. With `return_trail` a
-    third value, the `MergeTrail`, says how the rows moved: `merged_src` of
-    it is the input row of every live output slot (an index < capacity is
-    that state row, one >= capacity the delta row `index - capacity`), so
-    a column kept beside the state rides the merge by position, and
+    third value, the `MergeTrail`, says how the rows moved: a column kept
+    beside the state rides the merge through its `first` or `last`, and
     `merge_changes` reads each delta key's old and new payloads off it (the
-    agg step's change set). Without it the traced program is the one it
-    always was (the MV apply, `ops/`, `parallel/`).
+    agg step's change set).
     """
+    if not (cheap_compile() or return_trail):
+        return _merge_variadic(state, dkeys, dvals, kinds, drop_dead,
+                               dead_col)
     c = state.capacity
+    cols = [jnp.concatenate([sv, dv.astype(sv.dtype)])
+            for sv, dv in zip(state.vals, dvals)]
     # named scopes are HLO metadata only: they put these stages' device
     # time under a name in a profiler trace and change no instruction
+    with jax.named_scope("merge.sort"):
+        keys_in = jnp.concatenate([state.keys, dkeys])
+        n = keys_in.shape[0]
+        rank = jnp.arange(n, dtype=jnp.int32)
+        carried = [cols[dead_col]] if drop_dead else []
+        keys, *dead, perm = jax.lax.sort([keys_in] + carried + [rank],
+                                         num_keys=1, is_stable=True)
+    same_next = jnp.concatenate([keys[:-1] == keys[1:], jnp.zeros((1,), bool)])
+    same_prev = jnp.concatenate([jnp.zeros((1,), bool), keys[1:] == keys[:-1]])
+    alive = ~same_prev & (keys != EMPTY_KEY)
+    if drop_dead:
+        nxt = jnp.concatenate([dead[0][1:], dead[0][-1:]])
+        alive &= jnp.where(same_next, _combine(kinds[dead_col], dead[0], nxt),
+                           dead[0]) != 0
+    needed = jnp.sum(alive).astype(jnp.int32)
+    with jax.named_scope("merge.compact"):
+        perm_last = jnp.where(same_next,
+                              jnp.concatenate([perm[1:], perm[-1:]]), perm)
+        front = jnp.where(alive, 0, n).astype(jnp.int32) + rank
+        _, first, last = jax.lax.sort([front, perm, perm_last], num_keys=1,
+                                      is_stable=False)
+        first, last = first[:c], last[:c]
+    with jax.named_scope("merge.gather"):
+        # a run's key and values: its last row's for REPLACE, else its
+        # first row's combined with its last's where it has two (state
+        # row first); the keys ride along in rows a TPU tile pads anyway
+        # (the bid agg's words are 14 of 16, the MV's 12)
+        words = _words([keys_in] + cols)
+        dtypes = [keys_in.dtype] + [col.dtype for col in cols]
+        okeys, *at_last = _unwords(words[:, last], dtypes)
+        at_first = _unwords(words[:, first], dtypes)[1:] \
+            if any(k != ReduceKind.REPLACE for k in kinds) else at_last
+        two = first != last
+        live = jnp.arange(c) < needed
+        okeys = jnp.where(live, okeys, EMPTY_KEY)
+        vals = tuple(
+            jnp.where(live, b if k == ReduceKind.REPLACE else
+                      jnp.where(two, _combine(k, a, b), a),
+                      _neutral(k, b.dtype))
+            for a, b, k in zip(at_first, at_last, kinds))
+    new = SortedState(okeys, jnp.minimum(needed, c), vals)
+    if return_trail:
+        return new, needed, MergeTrail(first, last, perm, same_next)
+    return new, needed
+
+
+def _merge_variadic(state: SortedState, dkeys: jax.Array,
+                    dvals: Sequence[jax.Array], kinds: Sequence[ReduceKind],
+                    drop_dead: bool, dead_col: int) -> Tuple:
+    """`merge` as one variadic sort of every column, a neighbour combine
+    and one variadic compaction (`RW_TPU_CHEAP_COMPILE=0`, ROADMAP D3)."""
+    c = state.capacity
     with jax.named_scope("merge.sort"):
         keys = jnp.concatenate([state.keys, dkeys])
         vals = [jnp.concatenate([sv, dv.astype(sv.dtype)])
                 for sv, dv in zip(state.vals, dvals)]
-        (keys,), vals, *sperm = sort_cols([keys], vals,
-                                          return_perm=return_trail)
+        (keys,), vals = sort_cols([keys], vals)
     same_next = jnp.concatenate([keys[:-1] == keys[1:], jnp.zeros((1,), bool)])
     same_prev = jnp.concatenate([jnp.zeros((1,), bool), keys[1:] == keys[:-1]])
     merged = []
@@ -355,13 +476,8 @@ def merge(state: SortedState, dkeys: jax.Array,
     with jax.named_scope("merge.compact"):
         out = compact_rows(alive, [keys], merged, c,
                            [EMPTY_KEY] + [_neutral(k, v.dtype)
-                                          for v, k in zip(merged, kinds)],
-                           return_perm=return_trail)
-    new = SortedState(out[0], jnp.minimum(needed, c),
-                      tuple(out[1:1 + len(merged)]))
-    if return_trail:
-        return new, needed, MergeTrail(sperm[0], same_next, out[-1])
-    return new, needed
+                                          for v, k in zip(merged, kinds)])
+    return SortedState(out[0], jnp.minimum(needed, c), tuple(out[1:])), needed
 
 
 def lookup(state: SortedState, qkeys: jax.Array
