@@ -39,16 +39,38 @@ def note(msg):
     print(f"[{time.strftime('%H:%M:%S')}] {msg}", file=sys.stderr, flush=True)
 
 
+def _numbers(layer):
+    """The whole numbers a file sets at its top level and under `device`:
+    its sizes (a flag such as `host_ingest` is not one)."""
+    out = {k for k, v in layer.items()
+           if isinstance(v, int) and not isinstance(v, bool)}
+    return out | {"device." + k for k, v in layer.get("device", {}).items()
+                  if isinstance(v, int) and not isinstance(v, bool)}
+
+
 def sizes(cell, rehearse):
     """Event count, chunk size, DeviceConfig fields: the files' real sizes,
-    or their `rehearse` blocks."""
+    or their `rehearse` blocks. Fields are laid over each other in the order
+    configuration, its `rehearse`, traffic, its `rehearse`, so a traffic
+    file's `events` or device field wins over the configuration's. A
+    traffic file names a rehearsal size for every number it sets: its real
+    sizes never reach a rehearsal."""
     cfg, tr = cell.config, cell.traffic
+    layers = [cfg, tr]
     if rehearse:
-        cfg, tr = {**cfg, **cfg["rehearse"]}, {**tr, **tr["rehearse"]}
-    return {"events": cfg["events"], "chunk": tr["chunk_size"],
-            "epoch_events": tr["epoch_events"],
-            "device": {**cfg["device"], **cell.traffic["device"]},
-            "checkpoint_frequency": cell.config["checkpoint_frequency"]}
+        small = tr["rehearse"]
+        unsized = sorted(_numbers(tr) - _numbers(small))
+        if unsized:
+            raise SystemExit(f"traffic {tr['name']} sets {unsized} but its "
+                             "rehearse block does not")
+        layers = [cfg, cfg["rehearse"], tr, small]
+    merged, device = {}, {}
+    for layer in layers:
+        merged.update(layer)
+        device.update(layer.get("device", {}))
+    return {"events": merged["events"], "chunk": merged["chunk_size"],
+            "epoch_events": merged["epoch_events"], "device": device,
+            "checkpoint_frequency": cfg["checkpoint_frequency"]}
 
 
 def create(cell, sz, seed):

@@ -45,7 +45,8 @@ def test_new_files_are_picked_up(tmp_path):
     readers = dict((m["name"], r) for m, r in cell.metrics("per_layer"))
     assert readers["dummy_metric"].read({"x": 21}) == 42
     # metrics that list other cells are not this cell's; unlisted ones are
-    assert "h2d_mb_per_s" not in readers and "peak_hbm_mb" in readers
+    assert "ingest_pack_ms_per_epoch" not in readers
+    assert "peak_hbm_mb" in readers
     # an old cell does not see the new metric
     old = discover.Cell("bid-agg.device", root=root, bench_dir=bench)
     assert "dummy_metric" not in [m["name"]
